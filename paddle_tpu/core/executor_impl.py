@@ -28,10 +28,11 @@ from . import sanitizer as _san
 from .lod import LoDTensor
 from .lowering import LoweringContext, run_ops, run_op
 from .registry import get_op_info
+from .place import placed_on
 from .scope import Scope
 from .types import proto_to_np_dtype, VarKind
 
-from .flags import FLAGS
+from .flags import FLAGS, ensure_compile_cache
 
 from paddle_tpu.observability import metrics as _obs_metrics
 from paddle_tpu.observability import numerics as _num
@@ -647,6 +648,7 @@ class ExecutorCore:
         self.mesh = mesh
         self.dp_axis = dp_axis
         self._cache = {}
+        ensure_compile_cache()
 
     # ------------------------------------------------------------------
     def _maybe_verify(self, program):
@@ -1079,14 +1081,20 @@ class ExecutorCore:
             watched = _num.select_watched(program, block, ops,
                                           persist_outs, fetch_list)
 
+        # the device kernels dispatch for (kernels/dispatch.py): the
+        # mesh's devices under SPMD, else the place's
+        target = (self.mesh.devices.flat[0] if self.mesh is not None
+                  else self.place.jax_device())
+
         def fn(inputs, seed, counter):
             env = dict(zip(input_names, inputs))
             rng = jax.random.fold_in(jax.random.PRNGKey(seed), counter)
             ctx = LoweringContext(program, block_id, env, rng, mode)
             ctx.block = block
             ctx.mesh = self.mesh
-            for op in ops:
-                run_op(ctx, op)
+            with placed_on(target):
+                for op in ops:
+                    run_op(ctx, op)
             fetches = tuple(env.get(n) for n in fetch_list)
             persists = tuple(env[n] for n in persist_outs)
             if watched:
@@ -1153,11 +1161,9 @@ class ExecutorCore:
 
         if (pin is not None and pin.platform == "tpu" and FLAGS.auto_layout
                 and input_names):
-            entry = self._build_auto_layout(
+            return self._build_auto_layout(
                 fn_flat, jit_kwargs, input_names, persist_outs, fetch_list,
                 block, feed, scope, pin, watched)
-            if entry is not None:
-                return entry
 
         jflat = jax.jit(fn_flat, **jit_kwargs)
 
@@ -1186,55 +1192,48 @@ class ExecutorCore:
         kept for models whose parameters do want non-default layouts.
         device_put into the chosen Format is a one-time cost (a no-op
         once the scope holds the formatted buffer)."""
-        try:
-            from jax.experimental.layout import Format, Layout
-        except ImportError:
-            return None
-        try:
-            fmt = Format(Layout.AUTO)
-            specs = []
-            for name in input_names:
-                val = feed.get(name)
-                if val is None:
-                    val = scope.find_var(name)
-                if not hasattr(val, "dtype"):
-                    vd = block.find_var_recursive(name)
-                    val = np.asarray(val, dtype=proto_to_np_dtype(vd.dtype)
-                                     if vd is not None else None)
-                specs.append(jax.ShapeDtypeStruct(np.shape(val), val.dtype))
-            specs += [jax.ShapeDtypeStruct((), np.uint32)] * 2
-            kw = dict(jit_kwargs)
-            # feeds keep default layouts (host arrays stream in each step);
-            # persistables get AUTO
-            feed_only = {n for n in input_names
-                         if _in_feed_only(n, feed, scope)}
-            kw["in_shardings"] = tuple(
-                (None if n in feed_only else fmt) for n in input_names
-            ) + (None, None)
-            # fetches need AUTO too: donated AUTO inputs with a
-            # default-layout output subtree is rejected by jax ("Input
-            # layout being donated was AUTO while output layout was
-            # None"); host reads convert on transfer regardless
-            kw["out_shardings"] = ((fmt, fmt, fmt) if watched
-                                   else (fmt, fmt))  # (+ health)
-            with _matmul_precision_ctx(), jax.default_device(dev):
-                compiled = jax.jit(fn_flat, **kw).lower(*specs).compile()
-            in_fmts = compiled.input_formats[0]
-            input_shardings = [
-                (None if n in feed_only else in_fmts[i])
-                for i, n in enumerate(input_names)]
+        from jax.experimental.layout import Format, Layout
 
-            def jfn(inputs, seed, counter):
-                with jax.default_device(dev):
-                    return compiled(*inputs, seed, counter)
+        fmt = Format(Layout.AUTO)
+        specs = []
+        for name in input_names:
+            val = feed.get(name)
+            if val is None:
+                val = scope.find_var(name)
+            if not hasattr(val, "dtype"):
+                vd = block.find_var_recursive(name)
+                val = np.asarray(val, dtype=proto_to_np_dtype(vd.dtype)
+                                 if vd is not None else None)
+            specs.append(jax.ShapeDtypeStruct(np.shape(val), val.dtype))
+        specs += [jax.ShapeDtypeStruct((), np.uint32)] * 2
+        kw = dict(jit_kwargs)
+        # feeds keep default layouts (host arrays stream in each step);
+        # persistables get AUTO
+        feed_only = {n for n in input_names
+                     if _in_feed_only(n, feed, scope)}
+        kw["in_shardings"] = tuple(
+            (None if n in feed_only else fmt) for n in input_names
+        ) + (None, None)
+        # fetches need AUTO too: donated AUTO inputs with a
+        # default-layout output subtree is rejected by jax ("Input
+        # layout being donated was AUTO while output layout was
+        # None"); host reads convert on transfer regardless
+        kw["out_shardings"] = ((fmt, fmt, fmt) if watched
+                               else (fmt, fmt))  # (+ health)
+        with _matmul_precision_ctx(), jax.default_device(dev):
+            compiled = jax.jit(fn_flat, **kw).lower(*specs).compile()
+        in_fmts = compiled.input_formats[0]
+        input_shardings = [
+            (None if n in feed_only else in_fmts[i])
+            for i, n in enumerate(input_names)]
 
-            return _CacheEntry(jfn, input_names, persist_outs,
-                               tuple(fetch_list), input_shardings,
-                               watched=watched)
-        except Exception as e:  # any version/platform mismatch: plain jit
-            warnings.warn("auto_layout compile failed (%s); falling back "
-                          "to default layouts" % e)
-            return None
+        def jfn(inputs, seed, counter):
+            with jax.default_device(dev):
+                return compiled(*inputs, seed, counter)
+
+        return _CacheEntry(jfn, input_names, persist_outs,
+                           tuple(fetch_list), input_shardings,
+                           watched=watched)
 
     def _run_interpreted(self, program, block, scope, feed, fetch_list, mode):
         dev = self.place.jax_device()

@@ -10,7 +10,8 @@ from __future__ import annotations
 
 import os
 
-__all__ = ["FLAGS", "define_flag"]
+__all__ = ["FLAGS", "define_flag", "apply_xla_flags",
+           "ensure_compile_cache"]
 
 
 def _parse(raw, default):
@@ -94,6 +95,37 @@ def apply_xla_flags():
     if missing:
         os.environ["XLA_FLAGS"] = (cur + " " + " ".join(missing)).strip()
     return tokens
+
+
+# <checkout>/.jax_cache: fixed, because the directory is part of the
+# cache key — one built from a temp dir, a pid or a time never hits
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def ensure_compile_cache():
+    """Turn on jax's persistent compilation cache where it can be found
+    again: the directory JAX_COMPILATION_CACHE_DIR names when that is
+    set (jax reads it itself — nothing is set here), else
+    COMPILE_CACHE_DIR.  Every executable is kept, however quick its
+    compile: the serving ladders are dozens of few-second compiles.
+    Called wherever the framework first touches jax (ExecutorCore,
+    GenerativeEngine, the entry scripts); returns the directory.
+
+    A CPU run — one started with JAX_PLATFORMS=cpu: the tests, the CPU
+    tools — keeps jax's default (no cache unless the variable asks for
+    one): compiles are cheap there, and XLA:CPU's loader logs a
+    spurious machine-feature error on every cache hit."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        if jax.config.jax_platforms == "cpu":
+            return None
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    # (the entry-size threshold already defaults to 0 in this jax)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return jax.config.jax_compilation_cache_dir
 
 
 # core runtime flags (reference analogs cited above)

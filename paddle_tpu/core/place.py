@@ -6,6 +6,9 @@ reference user code runs unchanged.  A Place resolves to a jax.Device.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+
 import jax
 
 
@@ -42,10 +45,10 @@ def _devices_for(kind):
             return jax.local_devices(backend="cpu")
         except RuntimeError:
             return []
-    # "accelerator": whatever the default backend exposes, minus pure-host
-    devs = jax.local_devices()
-    accel = [d for d in devs if d.platform != "cpu"]
-    return accel or devs  # fall back to CPU so tests run anywhere
+    # "accelerator": whatever the default backend exposes, minus
+    # pure-host.  No accelerator means no devices — a TPUPlace never
+    # resolves to a host CPU (a CPU run says CPUPlace)
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 class CPUPlace(Place):
@@ -62,3 +65,44 @@ CUDAPlace = TPUPlace
 
 def is_accelerator_available():
     return any(d.platform != "cpu" for d in jax.devices())
+
+
+def default_place():
+    """The Place of jax's default device: TPUPlace on a process that
+    holds a chip, CPUPlace on one started with JAX_PLATFORMS=cpu."""
+    return TPUPlace() if is_accelerator_available() else CPUPlace()
+
+
+# ---------------------------------------------------------------------------
+# Placement of a computation while it is traced.  Tracers carry no
+# device, so whoever places the computation — the executor from its
+# Place or mesh, the serving engine from its device — declares it with
+# ``placed_on(device)`` inside the traced function, and device-dependent
+# lowerings (kernels/dispatch.py) ask ``target_platform()``.
+# ---------------------------------------------------------------------------
+
+_PLATFORM = contextvars.ContextVar("paddle_tpu_traced_platform",
+                                   default=None)
+
+
+@contextlib.contextmanager
+def placed_on(device):
+    """Declare the device the computation traced inside runs on."""
+    token = _PLATFORM.set(device.platform)
+    try:
+        yield
+    finally:
+        _PLATFORM.reset(token)
+
+
+def target_platform():
+    """Platform ('tpu'/'cpu'/...) of the computation being traced.  One
+    nobody placed goes where jax sends it: ``jax.default_device`` when
+    set, else the default backend."""
+    platform = _PLATFORM.get()
+    if platform is not None:
+        return platform
+    dev = jax.config.jax_default_device
+    if dev is not None:
+        return getattr(dev, "platform", dev)   # a Device or a platform name
+    return jax.devices()[0].platform

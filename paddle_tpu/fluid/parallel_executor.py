@@ -100,8 +100,11 @@ class ParallelExecutor:
         self._exec_strategy = exec_strategy or ExecutionStrategy()
 
         if use_tpu:
-            devices = [d for d in jax.devices() if d.platform != "cpu"] \
-                or jax.devices()
+            devices = [d for d in jax.devices() if d.platform != "cpu"]
+            if not devices:
+                raise RuntimeError(
+                    "ParallelExecutor(use_tpu=True) on a process with no "
+                    "accelerator (a CPU run says use_tpu=False)")
             place = TPUPlace()
         else:
             devices = jax.devices("cpu")

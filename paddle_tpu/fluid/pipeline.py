@@ -305,6 +305,7 @@ class PipelineProgram:
         import jax
 
         from paddle_tpu.core.lowering import LoweringContext, run_op
+        from paddle_tpu.core.place import placed_on
 
         program_desc = self.program.desc
         ops = list(st.ops)
@@ -317,8 +318,9 @@ class PipelineProgram:
             # must not repeat their masks across microbatches or steps
             key = jax.random.fold_in(jax.random.PRNGKey(0), rng_counter)
             ctx = LoweringContext(program_desc, 0, env, key, "train")
-            for op in ops:
-                run_op(ctx, op)
+            with placed_on(st.device):
+                for op in ops:
+                    run_op(ctx, op)
             return {n: env[n] for n in out_names}
 
         # placement follows the stage's device_put inputs (params and

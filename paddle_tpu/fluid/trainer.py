@@ -12,7 +12,7 @@ import os
 import numpy as np
 
 from paddle_tpu.core.executor_impl import PreparedShapeMismatch
-from paddle_tpu.core.place import CPUPlace, TPUPlace
+from paddle_tpu.core.place import default_place
 from paddle_tpu.core.scope import Scope
 from paddle_tpu.observability import numerics as _num
 from paddle_tpu.observability.trace import TRACER as _TRC
@@ -69,15 +69,7 @@ class CheckpointConfig:
 def check_and_get_place(place):
     """Default to the TPU when one is attached (reference
     trainer.py:check_and_get_place defaults to CUDAPlace(0))."""
-    if place is not None:
-        return place
-    try:
-        import jax
-        if any(d.platform != "cpu" for d in jax.devices()):
-            return TPUPlace()
-    except Exception:
-        pass
-    return CPUPlace()
+    return place if place is not None else default_place()
 
 
 class Trainer:

@@ -34,6 +34,8 @@ from jax.experimental import pallas as pl
 
 from paddle_tpu.observability.trace import traced as _traced
 
+from .dispatch import take_pallas
+
 __all__ = ["conv2d_nhwc", "fused_conv_bn_act_reference"]
 
 # Per-image VMEM budget for (padded input + weights + f32 accumulator +
@@ -128,8 +130,6 @@ def conv2d_nhwc(x, w, strides=(1, 1), paddings=(0, 0), *, stats=False,
     same-shape add; ``act``: '' | 'relu'.  Falls back to the
     identical-math XLA path off-TPU / over-budget / odd configs.
     """
-    from .flash_attention import target_platform
-
     n, h, wd, ci = x.shape
     kh, kw, wci, co = w.shape
     sh, sw = _pair(strides)
@@ -139,12 +139,14 @@ def conv2d_nhwc(x, w, strides=(1, 1), paddings=(0, 0), *, stats=False,
     wo = (wd + 2 * pw - kw) // sw + 1
     hp, wp = h + 2 * ph, wd + 2 * pw
 
-    on_tpu = target_platform() == "tpu"
+    # stride 1 only: the kernel's strided tap windows lower to a
+    # vector.extract_strided_slice Mosaic rejects ("strides confined to
+    # [1, 2)"), so ResNet's stem and downsample stages take the XLA conv
     usable = (wci == ci and ho >= 1 and wo >= 1
-              and (on_tpu or interpret)
+              and (sh, sw) == (1, 1)
               and _vmem_bytes(hp, wp, ci, kh, kw, co, ho, wo,
                               x.dtype) <= VMEM_BUDGET_BYTES)
-    if force_xla or not usable:
+    if not take_pallas("conv2d_nhwc", usable, force_xla, interpret):
         acc = conv_nhwc_xla(x, w, (sh, sw), (ph, pw))       # f32
         yf = acc
         if affine is not None:

@@ -23,8 +23,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability.trace import traced as _traced
+
+from .dispatch import take_pallas
 
 __all__ = ["flash_attention", "flash_attention_fwd_lse",
            "flash_attention_bwd", "paged_attention",
@@ -32,28 +35,6 @@ __all__ = ["flash_attention", "flash_attention_fwd_lse",
            "chunk_finalize"]
 
 NEG_INF = -1e30
-
-
-def _compiler_params(**kwargs):
-    """jax renamed TPUCompilerParams -> CompilerParams across the
-    versions this repo meets; resolve whichever this jax ships."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
-def target_platform():
-    """Platform the computation will actually run on: the executor pins
-    non-mesh runs with jax.default_device (visible in config even during
-    tracing); plain jax.devices()[0] would report the attached TPU even
-    for CPU-pinned programs."""
-    dev = jax.config.jax_default_device
-    if dev is not None:
-        return dev.platform
-    return jax.devices()[0].platform
-
 
 def _attention_xla(q, k, v, scale, causal):
     s = jnp.einsum("bhtd,bhsd->bhts", q, k) * scale
@@ -162,7 +143,7 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=None,
     ``block_q_dkv``/``block_k_dkv`` override the dK/dV kernel alone —
     its transpose-free [bk, bq] tile orientation (``_dkv_kernel``) has a
     different optimum than dQ's, so tools/flash_tune.py sweeps them
-    independently (VERDICT r5 weak #2).
+    independently.
 
     Tile arguments left as None resolve through the persistent autotune
     cache (paddle_tpu/tuning, written by flash_tune.py) and fall back to
@@ -184,12 +165,10 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=None,
         block_q_dkv = cfg.get("block_q_dkv")
     if block_k_dkv is None:
         block_k_dkv = cfg.get("block_k_dkv")
-    on_tpu = target_platform() == "tpu"
-
     block_q = _fit_block(block_q, t)
     block_k = _fit_block(block_k, tk)
     usable = (t % block_q == 0 and tk % block_k == 0)
-    if force_xla or not usable or not (on_tpu or interpret):
+    if not take_pallas("flash_attention", usable, force_xla, interpret):
         return _attention_xla(q, k, v, scale, causal)
     return _flash_diff(q, k, v, scale, causal, block_q, block_k,
                        block_q_bwd, block_k_bwd, block_q_dkv,
@@ -227,10 +206,10 @@ def _flash_bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
     # intermediates (p, ds + operand tiles) live in VMEM per grid step —
     # at 1024x1024 that flirts with the ~16MB/core budget at d=128, so
     # cap the backward Q tile at 512 while K/V tiles follow the forward:
-    # xplane-measured at the secondary-bench shape (B16 H8 T2048 D128),
-    # (512, 1024) runs the dq+dkv pair 10% faster than the round-2
-    # (512, 512) caps; K-tile streaming amortizes better than square
-    # tiles (PROFILE_r05.md).
+    # xplane-measured at B16 H8 T2048 D128 (2026-07, before PR 1, on a
+    # configuration that no longer exists; not re-measured), (512, 1024)
+    # ran the dq+dkv pair 10% faster than (512, 512) caps; K-tile
+    # streaming amortizes better than square tiles.
     bq = _fit_block(block_q_bwd or min(block_q, 512), q.shape[2])
     bk = _fit_block(block_k_bwd or block_k, k.shape[2])
     # _fit_block stops halving at 8 even when 8 doesn't divide (e.g.
@@ -311,9 +290,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     directly as pT [bk, bq] (scores from k @ q.T), so every contraction
     is a plain a@b / a@b.T MXU dot — the earlier p.T @ do / ds.T @ q
     forms contracted dim-0 of both operands, which Mosaic serves with
-    an extra in-VMEM transpose (measured: the dkv kernel ran at 52%
-    executed-MXU vs the structurally-identical dq kernel's 71%,
-    PROFILE_r05.md)."""
+    an extra in-VMEM transpose (measured 2026-07, before PR 1: that
+    dkv kernel ran at 52% executed-MXU vs the structurally-identical dq
+    kernel's 71%; this rewrite compiles and trains on the v5e since
+    PR 21, its own MXU share is not measured yet — ROADMAP S4)."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
 
@@ -361,8 +341,6 @@ def _bwd_operands(q, k, v, do, lse, delta):
 @_traced("pallas.flash_bwd_dq")
 def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, block_q,
                   block_k, interpret):
-    from jax.experimental.pallas import tpu as pltpu
-
     b, h, t, d = q.shape
     tk = k.shape[2]
     n_k = tk // block_k
@@ -384,7 +362,7 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, block_q,
                                lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, deltaf)
@@ -394,8 +372,6 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, block_q,
 @_traced("pallas.flash_bwd_dkv")
 def _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, block_q,
                    block_k, interpret):
-    from jax.experimental.pallas import tpu as pltpu
-
     b, h, t, d = q.shape
     tk = k.shape[2]
     n_q = t // block_q
@@ -421,7 +397,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, block_q,
                    jax.ShapeDtypeStruct((b * h, tk, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, deltaf)
@@ -429,8 +405,6 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, block_q,
 
 
 def _flash_pallas(q, k, v, scale, causal, block_q, block_k, interpret):
-    from jax.experimental.pallas import tpu as pltpu
-
     b, h, t, d = q.shape
     tk = k.shape[2]            # K/V may be longer/shorter than Q
     qf = q.reshape(b * h, t, d)
@@ -461,7 +435,7 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k, interpret):
         scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
@@ -519,23 +493,24 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0].astype(jnp.float32) * scale       # [H, D]
         k = k_ref[0].astype(jnp.float32)               # [bs, H, D]
         v = v_ref[0].astype(jnp.float32)
-        s = jnp.einsum("hd,shd->hs", q, k)             # [H, bs]
+        # per-head mat-vec as multiply + lane reduce: Mosaic's dot has no
+        # head-batched [H,D] x [bs,H,D] form, and one page is a handful
+        # of vregs — decode attention is bound by the page DMA, not this
+        s = (k * q[None]).sum(axis=-1, keepdims=True)  # [bs, H, 1]
         pos = ki * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+            jnp.int32, s.shape, 0)
         s = jnp.where(pos < ctx, s, NEG_INF)
-        m_prev = m_ref[...][:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                            # [H, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=0))
+        p = jnp.exp(s - m_new[None])                   # [bs, H, 1]
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = (l_ref[...][:, 0] * alpha + p.sum(axis=1))[:, None]
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + \
-            jnp.einsum("hs,shd->hd", p, v)
-        m_ref[...] = m_new[:, None]
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=0)
+        acc_ref[...] = acc_ref[...] * alpha + (p * v).sum(axis=0)
+        m_ref[...] = m_new
 
     @pl.when(ki == n_b - 1)
     def _done():
-        l = l_ref[...][:, 0]
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 @_traced("pallas.paged_attention",
@@ -566,12 +541,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         scale = 1.0 / np.sqrt(d)
     block_tables = block_tables.astype(jnp.int32)
     context_lens = context_lens.astype(jnp.int32)
-    on_tpu = target_platform() == "tpu"
-    if force_xla or not (on_tpu or interpret):
+    if not take_pallas("paged_attention", True, force_xla, interpret):
         return _paged_attention_xla(q, k_pages, v_pages, block_tables,
                                     context_lens, scale)
-    from jax.experimental.pallas import tpu as pltpu
-
     kernel = functools.partial(_paged_kernel, scale=scale,
                                block_size=bs, n_b=nb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -593,20 +565,14 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                         pltpu.VMEM((h, 1), jnp.float32),
                         pltpu.VMEM((h, d), jnp.float32)],
     )
-    kwargs = {}
-    if not interpret:
-        # compiler_params are Mosaic-only; the interpreter rejects them
-        # on some jax versions (matmul_fused._pallas_call's rule)
-        kwargs["compiler_params"] = _compiler_params(
-            dimension_semantics=("parallel", "arbitrary"))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(block_tables, context_lens, q, k_pages, v_pages)
-    return out
 
 
 def flash_attention_fwd_lse(q, k, v, scale=None, causal=False,
@@ -632,8 +598,8 @@ def flash_attention_fwd_lse(q, k, v, scale=None, causal=False,
     block_q = _fit_block(block_q, t)
     block_k = _fit_block(block_k, tk)
     usable = (t % block_q == 0 and tk % block_k == 0)
-    on_tpu = target_platform() == "tpu"
-    if force_xla or not usable or not (on_tpu or interpret):
+    if not take_pallas("flash_attention_fwd_lse", usable, force_xla,
+                       interpret):
         s = jnp.einsum("bhtd,bhsd->bhts", q.astype(jnp.float32),
                        k.astype(jnp.float32)) * scale
         if causal:
@@ -666,8 +632,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, scale=None, causal=False,
     block_q = _fit_block(block_q, t)
     block_k = _fit_block(block_k, tk)
     usable = (t % block_q == 0 and tk % block_k == 0)
-    on_tpu = target_platform() == "tpu"
-    if force_xla or not usable or not (on_tpu or interpret):
+    if not take_pallas("flash_attention_bwd", usable, force_xla,
+                       interpret):
         qf = q.astype(jnp.float32)
         kf = k.astype(jnp.float32)
         vf = v.astype(jnp.float32)
@@ -843,8 +809,6 @@ def _chunk_kernel(q_ref, k_ref, v_ref, m_in, l_in, acc_in, m_out,
 
 def _chunk_pallas(q, k, v, m, l, acc, scale, causal, block_q, block_k,
                   interpret, k_offset=0):
-    from jax.experimental.pallas import tpu as pltpu
-
     b, h, t, d = q.shape
     tk = k.shape[2]
     n_k = tk // block_k
@@ -872,7 +836,7 @@ def _chunk_pallas(q, k, v, m, l, acc, scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, mf, lf, af)
@@ -910,8 +874,8 @@ def flash_attention_chunk(q, k, v, m, l, acc, scale=None, causal=False,
         scale = 1.0 / np.sqrt(q.shape[-1])
     block_q, block_k = resolve_chunk_blocks(q.shape, k.shape[2],
                                             q.dtype, block_q, block_k)
-    on_tpu = target_platform() == "tpu"
-    if force_xla or not (on_tpu or interpret):
+    if not take_pallas("flash_attention_chunk", True, force_xla,
+                       interpret):
         return _chunk_update_xla(q, k, v, m, l, acc, scale, causal,
                                  block_k, k_offset=int(k_offset))
     return _chunk_pallas(q, k, v, m, l, acc, scale, causal, block_q,
@@ -991,9 +955,10 @@ def flash_attention_chunk_bwd(q, k, v, do, lse, delta, scale=None,
     block_q, block_k = resolve_chunk_blocks(q.shape, k.shape[2],
                                             q.dtype, block_q, block_k,
                                             cfg=cfg)
-    on_tpu = target_platform() == "tpu"
-    if force_xla or not (on_tpu or interpret) \
-            or (causal and k_offset):
+    # the flash backward kernels' masks do not express a causal
+    # off-diagonal offset: that case is a shape fall-back like any other
+    if not take_pallas("flash_attention_chunk_bwd",
+                       not (causal and k_offset), force_xla, interpret):
         return _chunk_bwd_xla(q, k, v, do, lse, delta, scale, causal,
                               block_k, k_offset=int(k_offset))
     t, tk = q.shape[2], k.shape[2]

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .dispatch import take_pallas
+
 __all__ = ["fused_softmax_cross_entropy"]
 
 
@@ -63,16 +65,14 @@ def fused_softmax_cross_entropy(logits, labels, block_n=256,
     otherwise."""
     n, c = logits.shape
     labels = labels.reshape(-1).astype(jnp.int32)
-    from .flash_attention import target_platform
-
-    on_tpu = target_platform() == "tpu"
     # the logits block is [block_n, C] in VMEM: cap it at ~4MB so the
     # scoped-vmem limit (16MB incl. double buffering) is never hit
     cap = max(8, (4 << 20) // (4 * c))
     block_n = min(block_n, n, cap - cap % 8 or 8)
     block_c = min(block_c, c)
-    if force_xla or n % block_n != 0 or c % block_c != 0 or \
-            not (on_tpu or interpret):
+    usable = n % block_n == 0 and c % block_c == 0
+    if not take_pallas("fused_softmax_cross_entropy", usable, force_xla,
+                       interpret):
         return _xla_path(logits, labels)
     kernel = functools.partial(_ce_kernel, block_c=block_c,
                                n_classes=c)

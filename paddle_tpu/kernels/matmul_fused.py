@@ -31,8 +31,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.observability.trace import traced as _traced
+
+from .dispatch import take_pallas
 
 __all__ = ["matmul_epilogue", "add_ln", "matmul_epilogue_reference",
            "add_ln_reference", "plan_matmul", "plan_add_ln", "apply_act",
@@ -169,18 +172,16 @@ def matmul_epilogue(x2, w, bias=None, residual=None, act="", *,
     budget, or non-tiling shapes take the identical-math XLA path.
     """
     from paddle_tpu import tuning
-    from .flash_attention import target_platform
 
     m, k = x2.shape
     k2, n = w.shape
     assert k == k2, (x2.shape, w.shape)
     out_dtype = out_dtype or x2.dtype
-    on_tpu = target_platform() == "tpu"
     if config is None:
         config = tuning.lookup("matmul_fused", (m, k, n),
                                jnp.dtype(x2.dtype).name)
     bm, bn, bk, usable = plan_matmul(m, k, n, x2.dtype, config)
-    if force_xla or not usable or not (on_tpu or interpret):
+    if not take_pallas("matmul_epilogue", usable, force_xla, interpret):
         y, pre = matmul_epilogue_reference(x2, w, bias, residual, act,
                                            out_dtype)
         return (y, pre.astype(out_dtype)) if save_preact else y
@@ -216,8 +217,8 @@ def matmul_epilogue(x2, w, bias=None, residual=None, act="", *,
         in_specs=in_specs,
         out_specs=out_specs if save_preact else out_specs[0],
         out_shape=out_shape if save_preact else out_shape[0],
-        scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -317,14 +318,12 @@ def matmul_int8_dequant(x2, wq, scales, chunk, bias=None, residual=None,
     both paths answer the same floats, so serving parity tests run on
     CPU transfer to the kernel."""
     from paddle_tpu import tuning
-    from .flash_attention import target_platform
 
     m, k = x2.shape
     k2, n = wq.shape
     assert k == k2, (x2.shape, wq.shape)
     assert k % int(chunk) == 0, (k, chunk)
     out_dtype = out_dtype or x2.dtype
-    on_tpu = target_platform() == "tpu"
     if config is None:
         config = tuning.lookup("matmul_int8", (m, k, n),
                                jnp.dtype(x2.dtype).name)
@@ -334,7 +333,8 @@ def matmul_int8_dequant(x2, wq, scales, chunk, bias=None, residual=None,
     usable = usable and (int(chunk) % bk == 0 or bk % int(chunk) == 0)
     if bk > int(chunk):
         usable = False
-    if force_xla or not usable or not (on_tpu or interpret):
+    if not take_pallas("matmul_int8_dequant", usable, force_xla,
+                       interpret):
         w = dequantize_weight(jnp.asarray(wq), jnp.asarray(scales),
                               int(chunk))
         y, _ = matmul_epilogue_reference(
@@ -348,12 +348,15 @@ def matmul_int8_dequant(x2, wq, scales, chunk, bias=None, residual=None,
     kernel = functools.partial(
         _matmul_int8_kernel, nk=nk, act=act, with_bias=with_bias,
         with_residual=with_residual)
+    # scales travel [nc, 1, N] with the chunk axis squeezed: a (1, bn)
+    # block of the [nc, N] array breaks Mosaic's last-two-dims rule
+    # whenever nc > 1 (K spanning several quantization chunks)
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((1, bn), lambda i, j, kk: (kk // per, j)),
+        pl.BlockSpec((None, 1, bn), lambda i, j, kk: (kk // per, 0, j)),
     ]
-    operands = [x2, wq, scales]
+    operands = [x2, wq, scales.reshape(k // int(chunk), 1, n)]
     if with_bias:
         in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
         operands.append(bias.astype(jnp.float32).reshape(1, n))
@@ -366,8 +369,8 @@ def matmul_int8_dequant(x2, wq, scales, chunk, bias=None, residual=None,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -412,7 +415,11 @@ def _add_ln_kernel(*refs, eps, with_scale, with_bias):
     var = jnp.mean(jnp.square(sf - mean), axis=1, keepdims=True)
     mean = mean.astype(s.dtype)
     var = var.astype(s.dtype)
-    yn = (s - mean) * jax.lax.rsqrt(var + eps)
+    # rsqrt itself in f32, rounded back: v5e has no bf16 transcendental
+    # unit (libtpu aborts the process on a bf16 vector rsqrt), and this
+    # is what XLA's own bf16 rsqrt in ln_from_sum lowers to
+    inv = jax.lax.rsqrt((var + eps).astype(jnp.float32)).astype(s.dtype)
+    yn = (s - mean) * inv
     if with_scale:
         yn = yn * s_ref[...][0][None, :].astype(s.dtype)
     if with_bias:
@@ -457,15 +464,13 @@ def add_ln(x2, y2, scale=None, bias=None, eps=1e-5, *, config=None,
     """LayerNorm(x + y) over [M, D] rows, sum and statistics from one
     VMEM tile.  Returns (out, sum, mean, var); mean/var are [M]."""
     from paddle_tpu import tuning
-    from .flash_attention import target_platform
 
     m, d = x2.shape
-    on_tpu = target_platform() == "tpu"
     if config is None:
         config = tuning.lookup("add_ln", (m, d),
                                jnp.dtype(x2.dtype).name)
     bm, usable = plan_add_ln(m, d, x2.dtype, config)
-    if force_xla or not usable or not (on_tpu or interpret):
+    if not take_pallas("add_ln", usable, force_xla, interpret):
         return add_ln_reference(x2, y2, scale, bias, eps)
 
     with_scale = scale is not None
@@ -498,27 +503,7 @@ def add_ln(x2, y2, scale=None, bias=None, eps=1e-5, *, config=None,
     return out, sm, mean[:, 0], var[:, 0]
 
 
-# ---------------------------------------------------------------------------
-# shared pallas plumbing
-# ---------------------------------------------------------------------------
-
-def _compiler_params(**kwargs):
-    from .flash_attention import _compiler_params as cp
-
-    return cp(**kwargs)
-
-
-def _vmem_scratch(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
 def _pallas_call(kernel, **kwargs):
     """Indirection the autotune tests hook to observe the grid/block
     specs an entry actually lowered with."""
-    if kwargs.get("interpret"):
-        # compiler_params are Mosaic-only; the interpreter rejects them
-        # on some jax versions
-        kwargs.pop("compiler_params", None)
     return pl.pallas_call(kernel, **kwargs)

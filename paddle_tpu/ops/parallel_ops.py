@@ -31,6 +31,24 @@ def _axis_or_none(mesh, name):
                     and dict(mesh.shape)[name] > 1) else None
 
 
+def _per_shard(ctx, attrs, fn):
+    """``fn`` run per (batch, head) shard of the mesh.  A Mosaic kernel
+    is an opaque custom call GSPMD cannot partition (jax refuses it
+    under a mesh: "wrap the call in a shard_map"); attention is
+    independent per batch row and head, so the dense kernels shard_map
+    over the axes those two leading dims are sharded on (the ring
+    path's batch/head axes) — every operand and result is [B, H, ...]."""
+    b_ax = _axis_or_none(ctx.mesh, attrs.get("batch_axis", "dp"))
+    h_ax = _axis_or_none(ctx.mesh, attrs.get("head_axis", "tp"))
+    if b_ax is None and h_ax is None:
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(b_ax, h_ax)
+    return jax.shard_map(fn, mesh=ctx.mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
+
+
 @register_op("ring_attention", no_vjp_outputs=("LSE",))
 def _ring_attention_lower(ctx, ins, attrs, op=None):
     """Scaled-dot-product attention, sequence-parallel when compiled under
@@ -65,23 +83,24 @@ def _ring_attention_lower(ctx, ins, attrs, op=None):
         return {"Out": ring_attention(
             q, k, v, ctx.mesh, axis_name=sp_axis, causal=causal,
             scale=scale, **axes)}
-    # dense (single-chip) path: the Pallas flash kernel on TPU (1.7x
-    # XLA at T=8192, measured), same-math XLA fallback elsewhere.
-    # Under a mesh the mesh's devices decide the platform (the default-
-    # device pin is absent and devices()[0] may be an unrelated TPU).
+    # dense path: the Pallas flash kernel on a TPU-placed program (the
+    # executor declares the place's or the mesh's device), same-math
+    # XLA elsewhere
     from paddle_tpu.kernels import flash_attention
     from paddle_tpu.kernels.flash_attention import flash_attention_fwd_lse
-    not_tpu = (ctx.mesh is not None and
-               ctx.mesh.devices.flat[0].platform != "tpu")
     if op is not None and op.outputs.get("LSE"):
         # residual form: lse rides as an op output so the grad op runs
         # the flash backward directly instead of re-executing the
         # forward inside its vjp (see ring_attention_grad)
-        out, lse = flash_attention_fwd_lse(
-            q, k, v, scale=scale, causal=causal, force_xla=not_tpu)
+        out, lse = _per_shard(
+            ctx, attrs,
+            lambda q, k, v: flash_attention_fwd_lse(
+                q, k, v, scale=scale, causal=causal))(q, k, v)
         return {"Out": out, "LSE": lse}
-    return {"Out": flash_attention(q, k, v, scale=scale, causal=causal,
-                                   force_xla=not_tpu)}
+    return {"Out": _per_shard(
+        ctx, attrs,
+        lambda q, k, v: flash_attention(q, k, v, scale=scale,
+                                        causal=causal))(q, k, v)}
 
 
 @register_op("moe_ffn")
@@ -145,10 +164,12 @@ def _ring_attention_grad_lower(ctx, ins, attrs, op=None):
             head_axis=_axis_or_none(ctx.mesh,
                                     attrs.get("head_axis", "tp")))
         return {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
-    not_tpu = (ctx.mesh is not None and
-               ctx.mesh.devices.flat[0].platform != "tpu")
-    dq, dk, dv = flash_attention_bwd(
-        ins["Q"], ins["K"], ins["V"], ins["Out"], lse, ins["Out@GRAD"],
-        scale=attrs["scale"] if "scale" in attrs else None,
-        causal=bool(attrs.get("causal", True)), force_xla=not_tpu)
+    scale = attrs["scale"] if "scale" in attrs else None
+    causal = bool(attrs.get("causal", True))
+    dq, dk, dv = _per_shard(
+        ctx, attrs,
+        lambda q, k, v, out, lse, do: flash_attention_bwd(
+            q, k, v, out, lse, do, scale=scale, causal=causal))(
+                ins["Q"], ins["K"], ins["V"], ins["Out"], lse,
+                ins["Out@GRAD"])
     return {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}

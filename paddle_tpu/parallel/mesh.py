@@ -15,22 +15,13 @@ __all__ = ["make_mesh", "auto_mesh_axes"]
 
 def make_mesh(axes, devices=None):
     """axes: dict axis-name -> size (insertion order = mesh order).
-    devices: flat device list (default: all; CPU fallback when the default
-    platform has too few)."""
+    devices: flat device list (default: every device of the default
+    platform).  Too few devices is an error, never a quiet move to
+    another platform."""
     sizes = list(axes.values())
     n = int(np.prod(sizes))
     if devices is None:
         devices = jax.devices()
-        if len(devices) < n:
-            cpus = jax.devices("cpu")
-            if len(cpus) >= n and devices and devices[0].platform != "cpu":
-                import warnings
-                warnings.warn(
-                    "mesh %r needs %d devices but the default platform (%s) "
-                    "has %d — falling back to %d host-CPU devices; the SPMD "
-                    "program will run on CPU" % (axes, n, devices[0].platform,
-                                                 len(devices), len(cpus)))
-            devices = cpus
     if len(devices) < n:
         raise ValueError("mesh %r needs %d devices, have %d"
                          % (axes, n, len(devices)))

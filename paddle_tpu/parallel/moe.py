@@ -134,8 +134,7 @@ def moe_ffn(x, router_w, w1, w2, mesh, axis_name="ep", dp_axis=None,
     # shard reconstructs the full [T, D] output after the reverse
     # all_to_all, so the result is replicated — but the vma type system
     # cannot infer that through the collectives; the check is disabled.
-    from ._compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(xspec, P(None, None), espec, espec),
-        out_specs=xspec)(x, router_w, w1, w2)
+        out_specs=xspec, check_vma=False)(x, router_w, w1, w2)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ._compat import pcast_varying, shard_map
-
 __all__ = ["pipeline_apply"]
 
 
@@ -30,8 +28,10 @@ def _pipeline_shard(stage_params, x, axis_name, stage_fn):
     m = x.shape[0]
     ev = jax.eval_shape(stage_fn, params, x[0])
     # carries start as constants; mark them device-varying for the scan
-    state = pcast_varying(jnp.zeros(ev.shape, ev.dtype), axis_name)
-    out = pcast_varying(jnp.zeros((m,) + ev.shape, ev.dtype), axis_name)
+    state = lax.pcast(jnp.zeros(ev.shape, ev.dtype), (axis_name,),
+                      to="varying")
+    out = lax.pcast(jnp.zeros((m,) + ev.shape, ev.dtype), (axis_name,),
+                    to="varying")
     perm = [(s, (s + 1) % p) for s in range(p)]
 
     def tick(carry, t):
@@ -76,5 +76,6 @@ def pipeline_apply(stage_params, microbatches, mesh, stage_fn,
     in_specs = (jax.tree.map(leaf_spec, stage_params), data_spec)
     fn = functools.partial(_pipeline_shard, axis_name=axis_name,
                            stage_fn=stage_fn)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=data_spec)(stage_params, microbatches)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=data_spec, check_vma=False)(
+                             stage_params, microbatches)

@@ -446,7 +446,7 @@ class CostModel:
     # -- construction from the repo's recorded data -----------------------
     @classmethod
     def from_repo(cls, tsdb_dir=None):
-        """Ingest whatever measurements this rig has recorded: the
+        """Ingest whatever measurements this host has recorded: the
         autotune cache (always consulted; empty without
         FLAGS_autotune_cache_dir), TSDB strategy step history, ledger
         peaks.  Missing stores degrade to the roofline, never raise."""

@@ -64,7 +64,16 @@ def _load_native():
                                  ctypes.POINTER(ctypes.c_char_p)]
         lib.rio_scanner_close.argtypes = [ctypes.c_void_p]
         _lib = lib
-    except Exception:
+    except Exception as e:
+        # the pure-Python codec below reads and writes the same format,
+        # only slower: say so once rather than degrade quietly (an
+        # input-pipeline measurement must know which codec it timed)
+        import warnings
+        detail = getattr(e, "stderr", b"") or b""
+        warnings.warn(
+            "recordio: native codec unavailable (%s: %s %s) — using the "
+            "pure-Python codec" % (type(e).__name__, e,
+                                   detail.decode(errors="replace")[-300:]))
         _lib = None
     return _lib
 

@@ -176,11 +176,14 @@ class ModelEngine:
     def __init__(self, model_dir, place=None, max_batch=None, warm=None,
                  name=""):
         import paddle_tpu.fluid as fluid
+        from paddle_tpu.core.place import default_place
         from paddle_tpu.inference.aot import load_aot
 
         self.name = name or model_dir
         self.model_dir = model_dir
-        self.place = place if place is not None else fluid.CPUPlace()
+        # the server's place, else the default device — the generate
+        # plane's rule (generative.GenerativeEngine)
+        self.place = place if place is not None else default_place()
         self.scope = fluid.Scope()
         self.max_batch = int(max_batch or FLAGS.serve_max_batch)
         if self.max_batch < 1:

@@ -553,11 +553,16 @@ class GenerativeEngine:
     def _init_device_state(self, params, n_blocks, place, warm):
         import jax
 
+        from paddle_tpu.core.flags import ensure_compile_cache
+        from paddle_tpu.core.place import default_place
+
+        ensure_compile_cache()
         cfg = self.config
-        if place is not None:
-            self._dev = place.jax_device()
-        else:
-            self._dev = jax.devices()[0]
+        # the server's place, else the default device — the predict
+        # plane's rule (engine.ModelEngine), so both planes of one
+        # InferenceServer sit on the same chip
+        self._dev = (place if place is not None
+                     else default_place()).jax_device()
         # params staged once; projection/MLP weights per-chunk int8
         # under quant='int8' (compress.quantize_symmetric via
         # matmul_fused.quantize_weight), logit layer fp32
@@ -635,16 +640,30 @@ class GenerativeEngine:
 
     def _mm(self, p, name, x):
         """x @ W with the tenant's quantization gate: int8 weights run
-        the epilogue-dequant kernel (XLA-identical fallback off-TPU)."""
+        the epilogue-dequant kernel (XLA-identical fallback off-TPU).
+
+        Rows are padded to a multiple of 8 (one f32 sublane tile — free
+        on the chip) so a row's result does not depend on the batch
+        bucket it rode in: XLA answers a ONE-row f32 dot exactly on the
+        vector unit but rounds the operands of a many-row dot to bf16
+        for the MXU (measured on the v5e: 0.03 absolute on O(10) values,
+        enough to flip greedy tokens between the batch-1 bucket and the
+        rest), and the int8 kernel only tiles from 8 rows up."""
+        import jax.numpy as jnp
+
         from paddle_tpu.kernels.matmul_fused import matmul_int8_dequant
 
+        m = x.shape[0]
+        pad = -m % 8
+        if pad:
+            x = jnp.pad(x, ((0, pad), (0, 0)))
         w = p[name]
         if isinstance(w, tuple):
             q, s = w
-            return matmul_int8_dequant(x, q, s, self._chunks[name])
-        import jax.numpy as jnp
-
-        return jnp.dot(x, w)
+            y = matmul_int8_dequant(x, q, s, self._chunks[name])
+        else:
+            y = jnp.dot(x, w)
+        return y[:m] if pad else y
 
     def _block_fwd(self, p, l, h, attend):
         """One transformer block: pre-LN attention (via ``attend``,
@@ -674,13 +693,39 @@ class GenerativeEngine:
 
     # -- compiled steps -------------------------------------------------
 
+    def _aot(self, name, step, *specs):
+        """AOT-compile ``step(params, k_pages, v_pages, *args)`` for
+        THIS engine's device (not whatever jax's default is), pages
+        donated; ``specs`` are the ``(shape, dtype)`` of the trailing
+        args.  ``name`` is the executable's stable name in a trace."""
+        import jax
+        from jax.sharding import SingleDeviceSharding
+
+        from paddle_tpu.core.place import placed_on
+
+        on_dev = SingleDeviceSharding(self._dev)
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=on_dev)
+
+        def placed(*args):
+            with placed_on(self._dev):
+                return step(*args)
+
+        placed.__name__ = name
+        pspec = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), self._params)
+        return jax.jit(placed, donate_argnums=(1, 2)).lower(
+            pspec, sds(self._kp.shape, self._kp.dtype),
+            sds(self._vp.shape, self._vp.dtype),
+            *[sds(shape, dtype) for shape, dtype in specs]).compile()
+
     def _compile_decode(self, key, with_logits=False):
         """AOT-compile one decode iteration at bucket ``(B, NB)``: one
         token per sequence in, K/V written through the block table,
         paged attention over each sequence's pages, greedy next token
         out.  Pages are donated — the returned buffers REPLACE the
         engine's, nothing round-trips the host."""
-        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import paged_attention
@@ -710,23 +755,17 @@ class GenerativeEngine:
             for l in range(cfg.n_layers):
                 h = self._block_fwd(p, l, h, attend)
             h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-            logits = jnp.dot(h, p["lm_head"])              # [B, V] f32
+            logits = self._mm(p, "lm_head", h)             # [B, V] f32
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             if with_logits:
                 return nxt, logits, kp, vp
             return nxt, kp, vp
 
-        jitted = jax.jit(step, donate_argnums=(1, 2))
-        sds = jax.ShapeDtypeStruct
-        pspec = jax.tree_util.tree_map(
-            lambda a: sds(a.shape, a.dtype), self._params)
-        return jitted.lower(
-            pspec,
-            sds(self._kp.shape, self._kp.dtype),
-            sds(self._vp.shape, self._vp.dtype),
-            sds((bb, nbb), jnp.int32),
-            sds((bb,), jnp.int32),
-            sds((bb,), jnp.int32)).compile()
+        return self._aot(
+            "serve_decode", step,
+            ((bb, nbb), jnp.int32),
+            ((bb,), jnp.int32),
+            ((bb,), jnp.int32))
 
     def _compile_prefill(self, key):
         """AOT-compile one prefill at sequence bucket ``(S,)``: the
@@ -735,7 +774,6 @@ class GenerativeEngine:
         into the sequence's blocks (pad positions redirect to the
         reserved scratch block), greedy first token from the last real
         position."""
-        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import flash_attention
@@ -778,17 +816,11 @@ class GenerativeEngine:
             nxt = jnp.argmax(logits).astype(jnp.int32)
             return nxt, kp, vp
 
-        jitted = jax.jit(step, donate_argnums=(1, 2))
-        sds = jax.ShapeDtypeStruct
-        pspec = jax.tree_util.tree_map(
-            lambda a: sds(a.shape, a.dtype), self._params)
-        return jitted.lower(
-            pspec,
-            sds(self._kp.shape, self._kp.dtype),
-            sds(self._vp.shape, self._vp.dtype),
-            sds((s_len,), jnp.int32),
-            sds((), jnp.int32),
-            sds((n_ids,), jnp.int32)).compile()
+        return self._aot(
+            "serve_prefill", step,
+            ((s_len,), jnp.int32),
+            ((), jnp.int32),
+            ((n_ids,), jnp.int32))
 
     def _compile_prefill_cached(self, key):
         """AOT-compile a SUFFIX prefill at suffix bucket ``(S,)``
@@ -801,7 +833,6 @@ class GenerativeEngine:
         the page gather).  Greedy first token from the last real row.
         Compute scales with the suffix, not the prompt — the entire
         point of the cache."""
-        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import paged_attention
@@ -839,18 +870,12 @@ class GenerativeEngine:
             nxt = jnp.argmax(logits).astype(jnp.int32)
             return nxt, kp, vp
 
-        jitted = jax.jit(step, donate_argnums=(1, 2))
-        sds = jax.ShapeDtypeStruct
-        pspec = jax.tree_util.tree_map(
-            lambda a: sds(a.shape, a.dtype), self._params)
-        return jitted.lower(
-            pspec,
-            sds(self._kp.shape, self._kp.dtype),
-            sds(self._vp.shape, self._vp.dtype),
-            sds((s_len,), jnp.int32),
-            sds((), jnp.int32),
-            sds((), jnp.int32),
-            sds((n_ids,), jnp.int32)).compile()
+        return self._aot(
+            "serve_prefill_cached", step,
+            ((s_len,), jnp.int32),
+            ((), jnp.int32),
+            ((), jnp.int32),
+            ((n_ids,), jnp.int32))
 
     def _compile_propose(self, key):
         """AOT-compile a FUSED k-step greedy decode at bucket
@@ -862,7 +887,6 @@ class GenerativeEngine:
         small draft's step cost.  K/V for the k positions lands in the
         pages exactly as k separate decode steps would have written
         it."""
-        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import paged_attention
@@ -897,22 +921,16 @@ class GenerativeEngine:
                     h2 = self._block_fwd(p, l, h2, attend)
                 h2 = self._layer_norm(h2, p["lnf.scale"],
                                       p["lnf.bias"])
-                logits = jnp.dot(h2, p["lm_head"])
+                logits = self._mm(p, "lm_head", h2)
                 cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 props.append(cur)
             return jnp.stack(props, axis=1), kp, vp      # [B, k]
 
-        jitted = jax.jit(step, donate_argnums=(1, 2))
-        sds = jax.ShapeDtypeStruct
-        pspec = jax.tree_util.tree_map(
-            lambda a: sds(a.shape, a.dtype), self._params)
-        return jitted.lower(
-            pspec,
-            sds(self._kp.shape, self._kp.dtype),
-            sds(self._vp.shape, self._vp.dtype),
-            sds((bb, nbb), jnp.int32),
-            sds((bb,), jnp.int32),
-            sds((bb,), jnp.int32)).compile()
+        return self._aot(
+            "serve_propose", step,
+            ((bb, nbb), jnp.int32),
+            ((bb,), jnp.int32),
+            ((bb,), jnp.int32))
 
     def _compile_verify(self, key, with_logits=False):
         """AOT-compile one speculative VERIFY at bucket
@@ -927,7 +945,6 @@ class GenerativeEngine:
         mismatch write K/V the host will roll back over — attention
         never reads past ``lens``, so stale writes are dead until
         overwritten."""
-        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import paged_attention
@@ -961,24 +978,18 @@ class GenerativeEngine:
             for l in range(cfg.n_layers):
                 h = self._block_fwd(p, l, h, attend)
             h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-            logits = jnp.dot(h, p["lm_head"])              # [R, V]
+            logits = self._mm(p, "lm_head", h)             # [R, V]
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             nxt = nxt.reshape(bb, k1)
             if with_logits:
                 return nxt, logits.reshape(bb, k1, cfg.vocab), kp, vp
             return nxt, kp, vp
 
-        jitted = jax.jit(step, donate_argnums=(1, 2))
-        sds = jax.ShapeDtypeStruct
-        pspec = jax.tree_util.tree_map(
-            lambda a: sds(a.shape, a.dtype), self._params)
-        return jitted.lower(
-            pspec,
-            sds(self._kp.shape, self._kp.dtype),
-            sds(self._vp.shape, self._vp.dtype),
-            sds((bb, nbb), jnp.int32),
-            sds((bb,), jnp.int32),
-            sds((bb, k1), jnp.int32)).compile()
+        return self._aot(
+            "serve_verify", step,
+            ((bb, nbb), jnp.int32),
+            ((bb,), jnp.int32),
+            ((bb, k1), jnp.int32))
 
     # -- dispatch -------------------------------------------------------
 
@@ -1427,11 +1438,21 @@ class GenerativeEngine:
         if seq_blocks:
             self.pool.free(seq_blocks)
 
-    def close(self):
+    @property
+    def device(self):
+        """The jax device this tenant's params, pages and compiled
+        steps live on."""
+        return self._dev
+
+    def drain_compiles(self):
+        """Join every in-flight background bucket compile."""
         for cache in (self._decode, self._decode_logits, self._prefill,
                       self._prefill_cached, self._verify,
                       self._verify_logits, self._propose):
             cache.drain()
+
+    def close(self):
+        self.drain_compiles()
         if self.draft is not None:
             self.draft.close()
         self.pool.close()

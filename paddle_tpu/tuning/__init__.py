@@ -53,13 +53,11 @@ def cache_path():
 
 
 def default_backend():
-    """Platform the computation will run on ('tpu'/'cpu'/...), matching
-    the kernels' own platform pick (flash_attention.target_platform)."""
-    try:
-        from paddle_tpu.kernels.flash_attention import target_platform
-        return target_platform()
-    except Exception:
-        return "cpu"
+    """Platform the computation will run on ('tpu'/'cpu'/...): the
+    kernels' own platform pick."""
+    from paddle_tpu.core.place import target_platform
+
+    return target_platform()
 
 
 def make_key(kernel, shape, dtype, backend):

@@ -316,10 +316,16 @@ def op_profile(path, plane_re=r"/device:", line_name="XLA Ops"):
     return sorted(rows.values(), key=lambda r: -r["time_ps"])
 
 
-def category_profile(path, peak_tflops=197.0, peak_gbps=819.0, **kwargs):
+def category_profile(path, device_kind, **kwargs):
     """Aggregate ``op_profile`` rows by hlo_category with achieved
-    TFLOP/s and GB/s against the given chip peaks (defaults: TPU v5e
-    bf16 / HBM).  The first stop for 'where did my step time go'."""
+    TFLOP/s and GB/s against the published peaks of the chip the
+    capture came from (``device_kind`` as jax reports it; core/peaks.py
+    — an unknown kind raises).  The first stop for 'where did my step
+    time go'."""
+    from paddle_tpu.core.peaks import device_peaks
+
+    peaks = device_peaks(device_kind)
+    peak_tflops, peak_gbps = peaks["bf16_tflops"], peaks["hbm_gbps"]
     cats = {}
     for r in op_profile(path, **kwargs):
         c = cats.setdefault(r["category"], {
@@ -339,8 +345,8 @@ def category_profile(path, peak_tflops=197.0, peak_gbps=819.0, **kwargs):
     return out
 
 
-def print_category_profile(path, top=12, **kwargs):
-    cats = category_profile(path, **kwargs)
+def print_category_profile(path, device_kind, top=12, **kwargs):
+    cats = category_profile(path, device_kind, **kwargs)
     total = sum(c["time_ps"] for c in cats) or 1
     print("%-28s %9s %7s %9s %8s %9s %8s" % (
         "category", "ms", "share", "TFLOP/s", "mxu", "GB/s", "hbm"))
@@ -368,11 +374,15 @@ def kernel_profile(path, name_re=r".", plane_re=r"/device:",
     return rows
 
 
-def print_kernel_profile(path, name_re=r".", top=15, flops_per_exec=None,
-                         peak_tflops=197.0, **kwargs):
+def print_kernel_profile(path, device_kind, name_re=r".", top=15,
+                         flops_per_exec=None, **kwargs):
     """Print per-kernel rows; ``flops_per_exec`` maps a regex to the
     analytic FLOPs of ONE execution (e.g. flash-attention tile math) to
-    report achieved TFLOP/s / MXU fraction for custom-calls."""
+    report achieved TFLOP/s / MXU fraction (against ``device_kind``'s
+    bf16 peak, core/peaks.py) for custom-calls."""
+    from paddle_tpu.core.peaks import device_peaks
+
+    peak_tflops = device_peaks(device_kind)["bf16_tflops"]
     all_rows = op_profile(path, **kwargs)   # parse the capture ONCE
     rows = kernel_profile(path, name_re=name_re, _all_rows=all_rows,
                           **kwargs)

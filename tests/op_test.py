@@ -8,8 +8,8 @@ numeric finite differences of the forward program (get_numeric_gradient:40).
 Place sweep parity (reference op_test.py:261 check_output_with_place, :320
 check_output iterating CPUPlace + CUDAPlace): ``check_output`` always checks
 on CPUPlace; when the env var ``TPU_OPTEST=1`` is set it additionally runs
-the same program on ``fluid.TPUPlace()`` (the real chip on this rig) and
-holds it to the same tolerances.  ``tools/tpu_optest.py`` drives the full
+the same program on ``fluid.TPUPlace()`` (needs a process that holds a
+chip) and holds it to the same tolerances.  ``tools/tpu_optest.py`` drives the full
 registry sweep on top of the same harness (CPU result as the oracle).
 """
 from __future__ import annotations

@@ -370,7 +370,7 @@ def test_device_loader_hides_producer_latency():
 
     compute(jax.device_put(field[None], dev), w).block_until_ready()
 
-    # per-batch compute time on THIS rig: the reader delay is sized to
+    # per-batch compute time on THIS host: the reader delay is sized to
     # match it, so the overlappable quantity (min(compute, delay) per
     # steady-state batch) is a fixed fraction of the loop whatever the
     # machine's speed — a hard-coded delay made the bound unsatisfiable

@@ -1,0 +1,292 @@
+"""What the chip will be asked to compile, checked on the CPU.
+
+1. Every Pallas entry point is exported for ``platforms=["tpu"]`` at
+   the shapes chip_smoke.py runs (and at tiny_lm test shapes): this runs
+   the Pallas -> Mosaic lowering — not libtpu's compile — and is the
+   check that would have caught a kernel Mosaic cannot express
+   (the head-batched mat-vec paged_attention used to be; the strided
+   conv stage still is, and must say so by taking its XLA route).
+2. chip_smoke.py's phase bodies are rehearsed at toy width with the
+   kernels interpreted, so chip time is not spent finding typos.
+3. chip_smoke.py has no CPU mode.
+"""
+import importlib.util
+import json
+import os
+import re
+import subprocess
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import export
+
+from paddle_tpu.core.place import placed_on
+from paddle_tpu.kernels import dispatch
+from paddle_tpu.kernels.conv_fused import conv2d_nhwc
+from paddle_tpu.kernels.flash_attention import (
+    flash_attention, flash_attention_bwd, flash_attention_chunk,
+    flash_attention_fwd_lse, paged_attention)
+from paddle_tpu.kernels.fused import fused_softmax_cross_entropy
+from paddle_tpu.kernels.matmul_fused import (add_ln, matmul_epilogue,
+                                             matmul_int8_dequant)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TPU = types.SimpleNamespace(platform="tpu")
+sds = jax.ShapeDtypeStruct
+bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+
+def mosaic_calls(fn, *specs):
+    """Kernel names of the Mosaic custom calls in ``fn`` lowered for a
+    TPU-placed computation."""
+    def placed(*args):
+        with placed_on(TPU):
+            return fn(*args)
+
+    text = export.export(jax.jit(placed), platforms=["tpu"])(
+        *specs).mlir_module()
+    return re.findall(r'@tpu_custom_call\(.*?kernel_name = "(\w+)"', text)
+
+
+# the transformer train step's attention: B16 H8 T2048 D128 bf16
+QKV = sds((16, 8, 2048, 128), bf16)
+LSE = sds((16, 8, 2048), f32)
+
+
+def test_flash_train_kernels_lower():
+    def fwd_bwd(q, k, v):
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
+        return vjp(out)
+
+    assert mosaic_calls(fwd_bwd, QKV, QKV, QKV) == [
+        "_flash_kernel", "_dq_kernel", "_dkv_kernel"]
+    assert mosaic_calls(
+        lambda q, k, v: flash_attention_fwd_lse(q, k, v, causal=True),
+        QKV, QKV, QKV) == ["_flash_kernel"]
+    assert mosaic_calls(
+        lambda q, k, v, o, lse, do: flash_attention_bwd(
+            q, k, v, o, lse, do, causal=True),
+        QKV, QKV, QKV, QKV, LSE, QKV) == ["_dq_kernel", "_dkv_kernel"]
+
+
+def test_flash_chunk_lowers():
+    q = sds((2, 8, 2048, 128), bf16)
+    m = sds((2, 8, 2048), f32)
+    acc = sds((2, 8, 2048, 128), f32)
+    assert mosaic_calls(
+        lambda q, k, v, m, l, acc: flash_attention_chunk(
+            q, k, v, m, l, acc, causal=True),
+        q, q, q, m, m, acc) == ["_chunk_kernel"]
+
+
+@pytest.mark.parametrize("s_len", [16, 512, 2048])
+def test_prefill_flash_lowers(s_len):
+    # the serving prefill ladder: one sequence, fp32, [1, H, S, D]
+    q = sds((1, 8, s_len, 128), f32)
+    assert mosaic_calls(
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        q, q, q) == ["_flash_kernel"]
+
+
+@pytest.mark.parametrize("b,h,d,n,bs,nb", [
+    (8, 8, 128, 256, 16, 128),     # chip_smoke decode, top block bucket
+    (1, 8, 128, 256, 16, 32),      # solo request, tight bucket
+    (4, 2, 16, 32, 8, 8),          # tiny_lm test shapes
+    (3, 4, 16, 16, 8, 4),
+])
+def test_paged_attention_lowers(b, h, d, n, bs, nb):
+    pages = sds((n, bs, h, d), f32)
+    assert mosaic_calls(
+        paged_attention, sds((b, h, d), f32), pages, pages,
+        sds((b, nb), i32), sds((b,), i32)) == ["_paged_kernel"]
+
+
+def test_matmul_epilogue_and_add_ln_lower():
+    x, w = sds((32768, 1024), bf16), sds((1024, 4096), bf16)
+    bias = sds((4096,), f32)
+    assert mosaic_calls(
+        lambda x, w, b: matmul_epilogue(x, w, b, act="relu"),
+        x, w, bias) == ["_matmul_kernel"]
+    res = sds((32768, 4096), bf16)
+    assert mosaic_calls(
+        lambda x, w, b, r: matmul_epilogue(x, w, b, r, act="gelu",
+                                           save_preact=True),
+        x, w, bias, res) == ["_matmul_kernel"]
+    h = sds((32768, 1024), bf16)
+    g = sds((1024,), f32)
+    assert mosaic_calls(lambda x, y, s, b: add_ln(x, y, s, b),
+                        h, h, g, g) == ["_add_ln_kernel"]
+
+
+@pytest.mark.parametrize("rows", [8, 64, 512])
+@pytest.mark.parametrize("k,n,chunk", [(1024, 3072, 1024),
+                                       (1024, 4096, 1024),
+                                       (4096, 1024, 2048)])
+def test_matmul_int8_lowers(rows, k, n, chunk):
+    # the int8 tenant's projections at d1024: decode batch 8, prefill rows
+    assert mosaic_calls(
+        lambda x, wq, s: matmul_int8_dequant(x, wq, s, chunk),
+        sds((rows, k), f32), sds((k, n), i8),
+        sds((k // chunk, n), f32)) == ["_matmul_int8_kernel"]
+
+
+def test_matmul_int8_narrow_batch_takes_xla_and_says_so():
+    before = dispatch.counts().get("matmul_int8_dequant.xla", 0)
+    assert mosaic_calls(
+        lambda x, wq, s: matmul_int8_dequant(x, wq, s, 1024),
+        sds((4, 1024), f32), sds((1024, 3072), i8),
+        sds((1, 3072), f32)) == []
+    assert dispatch.counts()["matmul_int8_dequant.xla"] == before + 1
+
+
+@pytest.mark.parametrize("dtype", [f32, bf16])
+def test_fused_softmax_cross_entropy_lowers(dtype):
+    assert mosaic_calls(
+        fused_softmax_cross_entropy, sds((32768, 8192), dtype),
+        sds((32768,), i32)) == ["_ce_kernel"]
+
+
+@pytest.mark.parametrize("hw,ci,co,k,pad", [(56, 64, 64, 3, 1),
+                                            (56, 64, 256, 1, 0),
+                                            (7, 512, 512, 3, 1)])
+def test_conv_stride1_lowers(hw, ci, co, k, pad):
+    # ResNet-50 stride-1 stages, bs256 bf16, train form (fused BN stats)
+    assert mosaic_calls(
+        lambda x, w: conv2d_nhwc(x, w, (1, 1), (pad, pad), stats=True),
+        sds((256, hw, hw, ci), bf16),
+        sds((k, k, ci, co), bf16)) == ["_conv_stage_kernel"]
+
+
+@pytest.mark.parametrize("hw,ci,co,k,pad", [(224, 3, 64, 7, 3),
+                                            (56, 128, 128, 3, 1)])
+def test_conv_stride2_takes_xla_and_says_so(hw, ci, co, k, pad):
+    # Mosaic rejects the strided tap windows; the stem and the
+    # downsample stages must compile through the XLA conv instead
+    before = dispatch.counts().get("conv2d_nhwc.xla", 0)
+    assert mosaic_calls(
+        lambda x, w: conv2d_nhwc(x, w, (2, 2), (pad, pad), stats=True),
+        sds((256, hw, hw, ci), bf16), sds((k, k, ci, co), bf16)) == []
+    assert dispatch.counts()["conv2d_nhwc.xla"] == before + 1
+
+
+def test_interpret_is_not_a_chip_path():
+    q = jnp.zeros((1, 1, 8, 8), f32)
+    with placed_on(TPU), pytest.raises(ValueError, match="interpret"):
+        flash_attention(q, q, q, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def meter(smoke):
+    # one per module: jax keeps monitoring listeners for the process
+    return smoke.CompileMeter()
+
+
+@pytest.fixture
+def rehearsal(smoke, meter, tmp_path, monkeypatch):
+    """(target, meter) for a CPU rehearsal: kernels decide as they
+    would for a TPU-placed computation and run in Pallas' TPU
+    interpreter."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    import paddle_tpu.fluid as fluid
+
+    monkeypatch.setattr(dispatch, "target_platform", lambda: "tpu")
+    target = smoke.Target(fluid.CPUPlace(), "cpu", str(tmp_path))
+    with pltpu.force_tpu_interpret_mode():
+        yield target, meter
+
+
+TOY_LM = dict(vocab_size=64, seq_len=32, d_model=32, n_head=2, n_layers=1,
+              d_ff=64)
+
+
+def test_rehearse_train_transformer(smoke, rehearsal):
+    target, meter = rehearsal
+    rec = smoke.phase_train_transformer(target, meter, TOY_LM, 2,
+                                        run_steps=2, prepared_steps=1)
+    assert set(rec["kernels"]) == {"flash_attention_fwd_lse.pallas",
+                                   "flash_attention_bwd.pallas"}
+    assert rec["losses"][-1] < rec["losses"][0]
+    json.dumps(rec)
+
+
+def test_a_kernel_forced_to_xla_fails_the_kernel_assertion(smoke):
+    """The failure the smoke exists to catch — a kernel that fell to
+    its XLA reference on the path — shows up as an unexpected
+    '<kernel>.xla' dispatch, which every phase asserts against."""
+    q = sds((2, 2, 64, 16), f32)
+    mark = smoke.kernel_mark()
+    mosaic_calls(lambda q, k, v: flash_attention_fwd_lse(
+        q, k, v, force_xla=True), q, q, q)
+    with pytest.raises(AssertionError, match="flash_attention_fwd_lse.xla"):
+        smoke.assert_kernels(smoke.kernel_delta(mark),
+                             {"flash_attention_fwd_lse.pallas": 1})
+
+
+def test_rehearse_serve_generate(smoke, rehearsal):
+    target, meter = rehearsal
+    # d_model 128 / max_batch 8: the narrowest LM whose int8 matmuls
+    # tile; 2 blocks of 16 keep every request in the top block bucket
+    lm = dict(vocab=64, d_model=128, n_heads=2, n_layers=1, d_ff=128,
+              block_size=16, max_blocks=2, max_batch=8)
+    rec = smoke.phase_serve_generate(target, meter, lm,
+                                     (17, 18, 19, 20, 21), 3, 32)
+    assert set(rec["tenants"]) == {"lm_fp32", "lm_int8"}
+    assert rec["tenants"]["lm_int8"]["kernels"][
+        "matmul_int8_dequant.pallas"] > 0
+    json.dumps(rec)
+
+
+def test_rehearse_train_resnet(smoke, rehearsal):
+    target, meter = rehearsal
+    rec = smoke.phase_train_resnet50(target, meter, 4, depth=8, steps=2,
+                                     data_set="cifar10")
+    assert rec["losses"][-1] < rec["losses"][0]
+    json.dumps(rec)
+
+
+def test_rehearse_multichip(smoke, rehearsal):
+    target, meter = rehearsal
+    rec = smoke.phase_multichip(target, meter, TOY_LM, 2, None,
+                                meshes=({"dp": 2, "tp": 2},), steps=1)
+    assert rec["dp2xtp2"]["tp_sliced_params"] > 0
+    assert rec["dp2xtp2"]["batch"] == 4
+    json.dumps(rec)
+
+
+def test_result_line_holds_exactly_the_contract_keys(smoke):
+    import jax
+
+    line = json.loads(json.dumps(smoke.result_line(True)))
+    assert set(line) == {"ok", "device"} and line["ok"] is True
+    assert line["device"] == {"platform": jax.devices()[0].platform,
+                              "kind": jax.devices()[0].device_kind,
+                              "count": len(jax.devices())}
+
+
+def test_chip_smoke_has_no_cpu_mode():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")], cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=120)
+    assert proc.returncode != 0
+    last = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert last["phase"] == "device" and last["ok"] is False
+    assert "no TPU" in last["error"]

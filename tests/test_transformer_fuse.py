@@ -152,9 +152,8 @@ def test_fused_program_structure_survives_sum_consumers():
 def test_fused_transformer_cpu_step_wall():
     """ISSUE 7 acceptance: fused block stages measurably reduce the
     transformer step wall on the CPU-tier microbench vs unfused.
-    Measured at the PROFILE_r07.md shape (bs4 seq256 d256 L2, ~4-6%
-    on this rig); asserted with margin (best-of-3 fused must not be
-    slower than best-of-3 unfused by more than 2%)."""
+    Shape bs4 seq256 d256 L2; asserted with margin (best-of-3 fused
+    must not be slower than best-of-3 unfused by more than 2%)."""
     import time
 
     def bench(fuse, iters=12):

@@ -290,16 +290,30 @@ def test_sentinel_quick_runs_gate_against_quick_floors_only():
 
 
 def test_sentinel_builds_from_repo_artifacts(tmp_path):
-    """The real in-repo *_BENCH.json + BENCH_r*.json pile becomes one
-    trajectory with the expected headline metrics."""
+    """The real in-repo *_BENCH.json pile becomes one trajectory with
+    the expected headline metrics; a driver-wrapped BENCH_r*.json
+    (none is committed any more) parses out of its 'tail'."""
     ps = _tool("perf_sentinel")
     traj = ps.build_trajectory(REPO)
     names = set(traj["metrics"])
     assert "serve_floor_qps" in names
     assert "pserver_dense_rounds_per_sec" in names
     assert "scale_peak_rows_per_sec" in names
-    # training rounds parsed out of the driver-wrapped tails
-    assert any(n.startswith("resnet50") for n in names)
+    # training rounds parse out of a driver-wrapped tail
+    wrapped = {"cmd": "python bench.py", "rc": 0, "tail": "\n".join([
+        "some log line",
+        json.dumps({"metric": "resnet50_flowers_train_bs256_bf16",
+                    "value": 100.0, "unit": "images/sec",
+                    "partial": True}),
+        json.dumps({"metric": "resnet50_flowers_train_bs256_bf16",
+                    "value": 101.0, "unit": "images/sec",
+                    "secondary": {"metric": "transformer_lm_train",
+                                  "value": 5.0,
+                                  "unit": "tokens/sec"}})])}
+    got, quick = ps.extract_metrics(wrapped)
+    assert not quick
+    assert got["resnet50_flowers_train_bs256_bf16"]["value"] == 101.0
+    assert got["transformer_lm_train"]["value"] == 5.0
     for ent in traj["metrics"].values():
         assert ent["runs"] and ent["latest"] is not None
     # the CLI writes the canonical record atomically

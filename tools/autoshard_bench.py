@@ -79,8 +79,6 @@ def _force_cpu():
     os.environ.setdefault(
         "XLA_FLAGS", "--xla_force_host_platform_device_count=%d" % N_DEV)
     os.environ["JAX_PLATFORMS"] = "cpu"
-    import __graft_entry__ as graft
-    graft._force_cpu_platform(N_DEV)
     # the measured-cost loop needs a TSDB to write hand-leg step times
     # into (and for auto_shard to read back); a throwaway store when
     # the operator didn't point FLAGS_tsdb_dir somewhere durable

@@ -11,8 +11,8 @@ conv+BN+ReLU stage of the headline model, measure fwd wall time of
 
 with the microbench traps handled: distinct pre-staged inputs, unrolled
 chain, one final d2h drain.  On the real chip the per-kernel xplane
-attribution for PROFILE_r06.md comes from wrapping this in
-``jax.profiler.trace`` (CONV_TUNE_PROFILE=<dir>).
+attribution comes from wrapping this in ``jax.profiler.trace``
+(CONV_TUNE_PROFILE=<dir>).
 
 Usage: python tools/conv_tune.py [steps] [batch]
 """

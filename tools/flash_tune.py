@@ -44,8 +44,8 @@ FLOPS = 0.5 * (4 + 10) * B * H * T * T * D
 def bench(dtype, block_q, block_k, force_xla=False,
           block_q_bwd=0, block_k_bwd=0, block_q_dkv=0, block_k_dkv=0):
     # NO lax.scan: kernels inside a while loop measured ~2x slower than
-    # the identical kernels in the bench's straight-line step (see
-    # PROFILE_r05.md) — unroll over distinct pre-staged inputs instead,
+    # the identical kernels in the bench's straight-line step —
+    # unroll over distinct pre-staged inputs instead,
     # which matches how the model invokes them.
     rng = np.random.RandomState(0)
     base = [(jnp.asarray(rng.randn(B, H, T, D), dtype),

@@ -193,9 +193,8 @@ def _collectives_from_dump(dump_dir):
 
 
 def _run_child(strategy, dump_dir, steps):
-    import __graft_entry__ as graft
-
-    graft._force_cpu_platform(N_DEV)
+    # the parent started this child with JAX_PLATFORMS=cpu and N_DEV
+    # virtual devices in XLA_FLAGS
     annotated = strategy.startswith("ann:")
     key = strategy[4:] if annotated else strategy
     name = dict(STRATEGIES)[key]

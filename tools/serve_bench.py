@@ -37,6 +37,8 @@ import sys
 import threading
 import time
 
+# a CPU tool: its rates are host-side scheduling evidence, never chip
+# numbers (the chip path is chip_smoke.py / the benchmark cells)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -51,6 +53,14 @@ import numpy as np
 D_IN = int(os.environ.get("SVB_D_IN", "128"))
 HIDDEN = int(os.environ.get("SVB_HIDDEN", "512"))
 D_OUT = int(os.environ.get("SVB_D_OUT", "32"))
+
+
+def _device_fields():
+    """The device the run was on, as jax reports it."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind}
 
 
 def _build_and_save(dirname, seed, max_batch):
@@ -1029,8 +1039,7 @@ def main(argv=None):
                "spec": lambda: _run_spec(args.quick)}[args.mode]()
         return _finish({
             "metric": "serve_bench", "quick": bool(args.quick),
-            "mode": args.mode,
-            "platform": os.environ.get("JAX_PLATFORMS", ""),
+            "mode": args.mode, **_device_fields(),
             args.mode: rec, "ok": rec["ok"]})
 
     tmp = tempfile.mkdtemp(prefix="serve_bench_")
@@ -1080,8 +1089,7 @@ def main(argv=None):
     p99_budget_ms = max(2.0 * floor["p99_ms"], 10.0)
     out = {
         "metric": "serve_bench",
-        "quick": bool(args.quick),
-        "platform": os.environ.get("JAX_PLATFORMS", ""),
+        "quick": bool(args.quick), **_device_fields(),
         "model": {"d_in": D_IN, "hidden": HIDDEN, "d_out": D_OUT},
         "max_batch": max_batch,
         "max_wait_us": max_wait_us,

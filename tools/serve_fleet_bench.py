@@ -558,7 +558,7 @@ def run_full(args, dump_dir, tsdb_dir):
         mig1, _ = _fleet_migrations(tr, procs)
         scale["migrations"] = mig1 - mig0
         # the same Poisson trace against the solo monolith: the honest
-        # reference for what disaggregation costs (or buys) on this rig
+        # reference for what disaggregation costs (or buys) on this host
         _, mono_scale = _replay(solo_router,
                                 _schedule(31, n, rate_rps, prefix="sm"),
                                 args.max_new)

@@ -3,8 +3,8 @@
 Parity: reference python/paddle/fluid/tests/unittests/op_test.py:261
 (check_output_with_place) and :320 (check_output sweeping every available
 place): the reference runs every op test on CPU *and* CUDA; this tool runs
-every registered op on CPUPlace *and* TPUPlace (the real chip on this rig)
-and holds the TPU result to the CPU result (the CPU path being the one the
+every registered op on CPUPlace *and* TPUPlace (needs a process that
+holds a chip) and holds the TPU result to the CPU result (the CPU path being the one the
 full pytest suite validates numerically against references / finite
 differences).
 
