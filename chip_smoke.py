@@ -277,9 +277,9 @@ def phase_train_transformer(target, meter, model_kw, batch,
                                  "flash_attention_bwd.pallas": layers})
         mosaic = mosaic_kernels(ir_files(target) - ir0)
         if target.platform == "tpu":
-            assert mosaic == {"_flash_kernel": layers,
-                              "_dq_kernel": layers,
-                              "_dkv_kernel": layers}, mosaic
+            assert mosaic == {"flash_fwd": layers,
+                              "flash_bwd_dq": layers,
+                              "flash_bwd_dkv": layers}, mosaic
 
         # steady window 1: exe.run, no compile after the first step
         m1 = meter.mark()
@@ -388,7 +388,7 @@ def phase_serve_generate(target, meter, lm_kw, prompt_lens, new_tokens,
             mosaic = mosaic_kernels(ir_files(target) - ir0)
             if target.platform == "tpu":
                 want_mosaic = {"_paged_kernel": want["paged_attention.pallas"],
-                               "_flash_kernel": want["flash_attention.pallas"]}
+                               "flash_fwd": want["flash_attention.pallas"]}
                 if quant:
                     want_mosaic["_matmul_int8_kernel"] = \
                         want["matmul_int8_dequant.pallas"]

@@ -40,7 +40,8 @@ from paddle_tpu.observability.trace import TRACER as _TRC
 
 # always-on metrics (one short lock per step — see
 # tools/telemetry_overhead.py for the hot-path overhead gate); span
-# tracing below is additionally gated on _TRC.on (FLAGS_telemetry)
+# tracing below is additionally gated on _TRC.live() (FLAGS_telemetry,
+# or a jax profiler session: the spans are then in its capture)
 _M_STEPS = _obs_metrics.counter(
     "executor_steps_total", "executor steps (run + run_prepared)")
 _M_CACHE_HITS = _obs_metrics.counter(
@@ -390,15 +391,16 @@ class PreparedProgram:
         np.asarray until the value is actually consumed).
 
         Telemetry: one step counter per COMPLETED step; with
-        FLAGS_telemetry on, a 'step.prepared' span with 'step.feed' /
-        'step.dispatch' phases and a step_wall_ms histogram
-        observation.  A failed attempt records neither (the
-        PreparedShapeMismatch fallback re-runs the step through run(),
-        which does its own counting — inc-ing up front would count
-        such a step twice).  Disabled cost: the counter inc plus one
-        attribute read (the < 2% overhead gate in
+        FLAGS_telemetry on or under a jax profiler session, a
+        'step.prepared' span with 'step.feed' / 'step.dispatch' phases
+        and a step_wall_ms histogram observation.  A failed attempt
+        records neither (the PreparedShapeMismatch fallback re-runs the
+        step through run(), which does its own counting — inc-ing up
+        front would count such a step twice).  Dead cost: the counter
+        inc plus ONE liveness check, handed down so the phase sites
+        test a local (the < 2% overhead gate in
         tools/telemetry_overhead.py)."""
-        if not _TRC.on:
+        if not _TRC.live():
             out = self._run_prepared_impl(feed, None)
             _M_STEPS.inc()
             return out
@@ -590,7 +592,7 @@ class PreparedProgram:
         executor) wins: the device copy is dropped and re-staged from
         the scope instead of clobbering the newer value."""
         _M_FLUSHES.inc()
-        if _TRC.on:
+        if _TRC.live():
             with _TRC.span("step.sync_scope"):
                 return self._sync_scope_impl()
         return self._sync_scope_impl()
@@ -690,7 +692,7 @@ class ExecutorCore:
         # those would report shard-apply time as the process's step
         # stats (10 shards x 100 rounds = 1000 phantom "steps").
         is_step = block_id == 0
-        if not _TRC.on:
+        if not _TRC.live():
             out = self._run_impl(program, scope, block_id, feed,
                                  fetch_list, mode, return_numpy)
             if is_step:
@@ -962,7 +964,7 @@ class ExecutorCore:
             don_site = "block %d of program %s" % (
                 block_id, getattr(program, "uid", "?"))
         try:
-            if _TRC.on:
+            if _TRC.live():
                 sp = _TRC.begin("executor.dispatch")
                 try:
                     out = entry.fn(tuple(args), seed, counter)
@@ -1092,7 +1094,10 @@ class ExecutorCore:
             ctx = LoweringContext(program, block_id, env, rng, mode)
             ctx.block = block
             ctx.mesh = self.mesh
-            with placed_on(target):
+            # the block's ops carry its name scope in their HLO metadata
+            # (op_name "jit(fn_flat)/block0/..."); the executable keeps
+            # its name, which the benchmark's readers match
+            with placed_on(target), jax.named_scope("block%d" % block_id):
                 for op in ops:
                     run_op(ctx, op)
             fetches = tuple(env.get(n) for n in fetch_list)

@@ -44,8 +44,9 @@ _state = {
 
 class RecordEvent:
     """RAII host-event annotation (reference platform/profiler.h:72).
-    Backed by a telemetry span: records whenever the TRACER is on —
-    under a profiler session OR plain FLAGS_telemetry."""
+    Backed by a telemetry span: records whenever the TRACER is live —
+    under a profiler session (this module's or jax's own) OR plain
+    FLAGS_telemetry."""
 
     __slots__ = ("name", "_span")
 
@@ -54,7 +55,7 @@ class RecordEvent:
         self._span = None
 
     def __enter__(self):
-        if _TRC.on:
+        if _TRC.live():
             self._span = _TRC.begin(self.name)
         return self
 

@@ -116,7 +116,7 @@ def _vmem_bytes(hp, wp, ci, kh, kw, co, ho, wo, in_dtype):
 
 # launch-site span (FLAGS_telemetry): records the TRACE/lowering-time
 # cost of building this kernel — the on-device execution shows up in
-# the xplane capture that observability/export.py merges alongside
+# the profiler capture's device plane
 @_traced("pallas.conv2d_nhwc",
          lambda x, w, *a, **kw: {"x": str(x.shape), "w": str(w.shape)})
 def conv2d_nhwc(x, w, strides=(1, 1), paddings=(0, 0), *, stats=False,

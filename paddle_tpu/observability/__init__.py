@@ -2,12 +2,14 @@
 metrics, chrome-trace export with distributed round correlation, and a
 hang flight recorder.
 
-  trace      span API + process tracer (FLAGS_telemetry gates; the
-             disabled hot path is one attribute read)
+  trace      span API + process tracer (live under FLAGS_telemetry or
+             a jax profiler session, whose capture then holds the
+             spans; the dead hot path is one check a step)
   metrics    counters/gauges/histograms, always on; Prometheus text +
              JSON snapshot exports
-  export     merge per-process dumps (+ xplane device traces) into one
-             chrome://tracing JSON; per-phase breakdown rows
+  export     merge per-process dumps into one chrome://tracing JSON;
+             per-phase breakdown rows; a profiler capture's device
+             idle gaps put down to the program's spans (gap_rows)
   flight     dump the ring + open spans + metrics + resource ledgers
              on watchdog timeout, wall-budget expiry, injected
              faults, SIGTERM/SIGALRM

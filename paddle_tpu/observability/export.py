@@ -1,6 +1,7 @@
-"""Trace export: merge per-process telemetry dumps (and optional
-xplane device traces) into ONE chrome://tracing JSON, and reduce a
-trace to a per-phase breakdown table.
+"""Trace export: merge per-process telemetry dumps into ONE
+chrome://tracing JSON, reduce a trace to a per-phase breakdown table,
+and put a profiler capture's device idle gaps down to the program's
+spans.
 
 The per-process dump (trace.Tracer.dump) stamps spans in absolute
 wall-clock microseconds, so merging is pure concatenation: each process
@@ -9,12 +10,16 @@ the same sync round share a ``cid`` arg (trace.round_cid) — select one
 in the viewer to see the trainer's send/barrier/get next to the
 pserver's scatter/apply for that round.
 
-Device traces: ``jax.profiler.trace`` captures convert through
-utils/xplane.device_trace_events (XLine.timestamp_ns is unix-epoch
-based, so device ops land on the same absolute timeline).
+Device traces: under a jax profiler session the tracer's spans are
+events of the capture itself (trace.py's bridge), so the merged
+timeline of host spans and device ops IS the xplane — open it in
+Perfetto/TensorBoard, or reduce it with ``gap_rows``, which first moves
+the device plane onto the host planes' clock (``clock_skew``).
 """
 from __future__ import annotations
 
+import bisect
+import glob
 import json
 import os
 
@@ -25,7 +30,8 @@ __all__ = ["load_dump", "chrome_trace", "merge_files", "phase_rows",
            "numerics_rows", "format_numerics_table", "serve_rows",
            "format_serve_table", "scale_rows", "format_scale_table",
            "slo_rows", "format_slo_table", "weaver_rows",
-           "format_weaver_table"]
+           "format_weaver_table", "load_profile", "clock_skew",
+           "gap_rows", "format_gap_table"]
 
 
 def load_dump(path):
@@ -40,8 +46,9 @@ def load_dump(path):
             # open-span markers carry elapsed-at-dump-time as their
             # duration; re-ingesting them as completed spans would let
             # a hung run's open barriers dominate the phase table.
-            # Device events (an --xplane merge) are likewise excluded:
-            # the original dumps never contained them, so the re-loaded
+            # Device events (merges written before the tracer bridged
+            # to the profiler held them) are likewise excluded: the
+            # original dumps never contained them, so the re-loaded
             # phase table must not be device-op-dominated either.
             if ev.get("cat") in ("open", "device") \
                     or (ev.get("args") or {}).get("open"):
@@ -57,10 +64,8 @@ def load_dump(path):
     return data
 
 
-def chrome_trace(dumps, device_events=None):
-    """[per-process dump dicts] -> chrome trace dict.  ``device_events``
-    is an optional pre-built list of chrome events (see
-    utils/xplane.device_trace_events)."""
+def chrome_trace(dumps):
+    """[per-process dump dicts] -> chrome trace dict."""
     events = []
     used_pids = set()
     for i, d in enumerate(dumps):
@@ -97,22 +102,15 @@ def chrome_trace(dumps, device_events=None):
             args["open"] = True
             ev["args"] = args
             events.append(ev)
-    if device_events:
-        events.extend(device_events)
     events.sort(key=lambda e: (e.get("ts", 0), e.get("pid", 0)))
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
-def merge_files(paths, out_path=None, xplane=None):
-    """Merge per-process dump files (+ an optional xplane capture dir)
-    into one chrome trace; write it to ``out_path`` when given.
-    Returns (trace_dict, dumps)."""
+def merge_files(paths, out_path=None):
+    """Merge per-process dump files into one chrome trace; write it to
+    ``out_path`` when given.  Returns (trace_dict, dumps)."""
     dumps = [load_dump(p) for p in paths]
-    device_events = None
-    if xplane:
-        from paddle_tpu.utils.xplane import device_trace_events
-        device_events = device_trace_events(xplane)
-    trace = chrome_trace(dumps, device_events)
+    trace = chrome_trace(dumps)
     if out_path:
         d = os.path.dirname(out_path)
         if d:
@@ -170,9 +168,9 @@ def _kernel_group(name):
 def kernel_rows(dumps, trace=None):
     """Per-kernel rollup (ISSUE 7 satellite): Pallas launch-site spans
     (the ``pallas.*`` spans the kernels emit under FLAGS_telemetry)
-    grouped by kernel name, merged with device-side events from an
-    --xplane capture (cat 'device' in the merged chrome trace), so a
-    fusion win is readable straight from a telemetry dump.  Returns
+    grouped by kernel name, merged with the device-side events
+    (cat 'device') of a chrome trace that holds them, so a fusion win
+    is readable straight from a telemetry dump.  Returns
     [{kernel, side, count, total_ms, mean_ms, share}] sorted by total
     time; host and device entries stay separate rows ('side')."""
     groups = {}
@@ -601,4 +599,201 @@ def format_phase_table(rows, top=0):
         out.append("%-32s %7d %10.3f %9.3f %9.3f %9.3f %6.1f%%" % (
             r["name"][:32], r["count"], r["total_ms"], r["mean_ms"],
             r["p50_ms"], r["p99_ms"], 100.0 * r["share"]))
+    return "\n".join(out)
+
+
+# ---------------------------------------------------------------------------
+# Device idle gaps put down to the program's spans (one profiler capture)
+# ---------------------------------------------------------------------------
+
+PROGRAM_SPANS = ("serve.", "step.", "executor.")
+# the TPU runtime's own host events that tie the two clocks of a
+# capture together: it hands a program to the device / learns that one
+# has ended
+LAUNCHED = "DoEnqueueProgram"
+SEEN_DONE = "tpu::System::Execute=>Done"
+
+
+def load_profile(path):
+    """``jax.profiler.ProfileData`` of a capture: an ``.xplane.pb``
+    file, or the newest one under a ``jax.profiler.start_trace`` dir."""
+    from jax.profiler import ProfileData
+
+    if os.path.isdir(path):
+        found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                                 recursive=True), key=os.path.getmtime)
+        if not found:
+            raise FileNotFoundError("no .xplane.pb under %s" % path)
+        path = found[-1]
+    return ProfileData.from_file(path)
+
+
+def _innermost(spans):
+    """Properly nested ``(start, end, name)`` spans of one thread ->
+    disjoint ``(start, end, name)`` in time order, every instant owned
+    by the deepest span over it."""
+    out, stack = [], []     # stack of [end, name]; t: owned up to here
+    t = 0
+
+    def close(upto):
+        nonlocal t
+        while stack and stack[-1][0] <= upto:
+            end, name = stack.pop()
+            if end > t:
+                out.append((t, end, name))
+                t = end
+
+    for a, b, name in sorted(spans, key=lambda x: (x[0], -x[1])):
+        close(a)
+        if stack and a > t:
+            out.append((t, a, stack[-1][1]))
+        t = max(t, a)
+        stack.append([b, name])
+    close(float("inf"))
+    return out
+
+
+def _nearest_diffs(marks, times):
+    """For each of ``times`` the signed distance (mark - time) to the
+    nearest of the sorted ``marks``, kept where it lies within a
+    millisecond of the median one: a run paired with another run's
+    mark (a pipeline that launches far ahead, the capture's edges)
+    falls out, and with fewer than half left the marks say nothing."""
+    if not marks or not times:
+        return []
+    diffs = []
+    for t in times:
+        i = bisect.bisect_left(marks, t)
+        diffs.append(min((marks[j] - t for j in (i - 1, i)
+                          if 0 <= j < len(marks)), key=abs))
+    mid = sorted(diffs)[len(diffs) // 2]
+    near = [d for d in diffs if abs(d - mid) < 1e6]
+    return near if 2 * len(near) >= len(diffs) else []
+
+
+def clock_skew(profile):
+    """``(lo, hi)`` ns by which a capture's device planes run AHEAD of
+    its host planes (either bound None where nothing shows it).  The
+    profiler converts the device's clock to the host's with an error of
+    a millisecond or two (v5e: 1.1 and 1.5-1.8 ms in two captures,
+    steady inside each), which is the size of the gaps to be explained.
+    Causality bounds it: a run of an executable (the device's ``XLA
+    Modules`` line) starts after the runtime enqueued it (``LAUNCHED``
+    ends; skew >= end - start) and the runtime sees it done
+    (``SEEN_DONE``) after it ended (skew <= seen - end)."""
+    runs, launched, seen = [], [], []
+    for plane in profile.planes:
+        for line in plane.lines:
+            if plane.name.startswith("/device:"):
+                if line.name == "XLA Modules":
+                    runs.extend((e.start_ns, e.start_ns + e.duration_ns)
+                                for e in line.events)
+            elif plane.name.startswith("/host:"):
+                for e in line.events:
+                    if e.name == LAUNCHED:
+                        launched.append(e.start_ns + e.duration_ns)
+                    elif e.name == SEEN_DONE:
+                        seen.append(e.start_ns)
+    lo = _nearest_diffs(sorted(launched), [a for a, _ in runs])
+    hi = _nearest_diffs(sorted(seen), [b for _, b in runs])
+    lo, hi = (max(lo) if lo else None), (min(hi) if hi else None)
+    if lo is not None and hi is not None and lo > hi:
+        # a loop that launches far ahead of the device (training keeps
+        # 24 steps in flight) pairs a run with another run's launch,
+        # steadily; the end of a run is seen promptly in every regime
+        lo = None
+    return lo, hi
+
+
+def gap_rows(profile, prefixes=PROGRAM_SPANS):
+    """From one capture (``jax.profiler.ProfileData``): the union of
+    each device's op intervals, and every idle gap between them put
+    down to the deepest program span — a host event whose name starts
+    with one of ``prefixes``, on the scheduler's or executor's thread —
+    that covers more of it than any other (or than no span at all:
+    ``unspanned``).  The device's times are first moved onto the host
+    planes' clock by ``clock_skew`` (the middle of its bounds, or the
+    one it has; the ``(window)`` row carries ``skew_ns`` and the shift
+    applied).  The window is the extent of the device's ops.
+    Returns ``[{span, gaps, idle_s, share, under_s}]`` — ``share`` of
+    the window; ``under_s`` the idle seconds that fall inside the span
+    itself, whichever span its gap went to — most idle first and
+    ``unspanned`` last, after a ``(window)`` row holding the window's
+    seconds, all gaps, all idle seconds and the idle share."""
+    prefixes = tuple(prefixes)
+    skew = clock_skew(profile)
+    known = [x for x in skew if x is not None]
+    shift = sum(known) // len(known) if known else 0
+    owned = []          # disjoint (start, end, name) over all threads
+    devices = []        # per device plane: sorted (start, end) of ops
+    for plane in profile.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                         for e in line.events
+                         if e.name.startswith(prefixes)]
+                owned.extend(_innermost(spans))
+        elif plane.name.startswith("/device:"):
+            ops = sorted((e.start_ns + shift,
+                          e.start_ns + e.duration_ns + shift)
+                         for line in plane.lines if line.name == "XLA Ops"
+                         for e in line.events)
+            if ops:
+                devices.append(ops)
+    owned.sort()
+    starts = [o[0] for o in owned]
+    tally = {}          # name -> [gaps, idle ns of them, ns under it]
+    window = idle = n_gaps = 0
+    for ops in devices:
+        window += max(b for _, b in ops) - ops[0][0]
+        busy_to = ops[0][0]
+        for a, b in ops:
+            if a > busy_to:
+                gap = a - busy_to
+                cover = {"unspanned": gap}
+                i = max(0, bisect.bisect_right(starts, busy_to) - 1)
+                while i < len(owned) and owned[i][0] < a:
+                    ov = min(a, owned[i][1]) - max(busy_to, owned[i][0])
+                    if ov > 0:
+                        cover[owned[i][2]] = cover.get(owned[i][2], 0) + ov
+                        cover["unspanned"] -= ov
+                    i += 1
+                for name, ov in cover.items():
+                    tally.setdefault(name, [0, 0, 0])[2] += ov
+                row = tally[max(cover, key=cover.get)]
+                row[0] += 1
+                row[1] += gap
+                n_gaps += 1
+                idle += gap
+            busy_to = max(busy_to, b)
+    window = window or 1
+    rows = [{"span": name, "gaps": n, "idle_s": ns / 1e9,
+             "share": ns / window, "under_s": under / 1e9}
+            for name, (n, ns, under) in tally.items() if n or under]
+    rows.sort(key=lambda r: (r["span"] == "unspanned", -r["idle_s"]))
+    return [{"span": "(window)", "gaps": n_gaps, "idle_s": idle / 1e9,
+             "share": idle / window, "under_s": idle / 1e9,
+             "window_s": window / 1e9, "skew_ns": skew,
+             "shift_ns": shift}] + rows
+
+
+def format_gap_table(rows):
+    out = []
+    if rows and rows[0]["span"] == "(window)":
+        out.append("window %.3f s, device idle %.3f s (%.2f%%) in %d "
+                   "gaps" % (rows[0]["window_s"], rows[0]["idle_s"],
+                             100.0 * rows[0]["share"], rows[0]["gaps"]))
+        lo, hi = rows[0]["skew_ns"]
+        out.append("device clock ahead of the host planes' by %s to %s "
+                   "ms: device times moved %.3f ms" % (
+                       "?" if lo is None else "%.3f" % (lo / 1e6),
+                       "?" if hi is None else "%.3f" % (hi / 1e6),
+                       rows[0]["shift_ns"] / 1e6))
+        rows = rows[1:]
+    out.append("%-28s %9s %10s %16s %10s" % (
+        "span", "gaps", "idle_s", "share of window", "under_s"))
+    for r in rows:
+        out.append("%-28s %9d %10.4f %15.2f%% %10.4f" % (
+            r["span"][:28], r["gaps"], r["idle_s"], 100.0 * r["share"],
+            r["under_s"]))
     return "\n".join(out)

@@ -8,11 +8,21 @@ client; here the instrumented sites are named in ISSUE 6).
 
 Design constraints:
 
-- **Disabled cost is one attribute read.**  Hot paths guard every
-  begin/end behind ``TRACER.on`` (a plain bool), so with
-  ``FLAGS_telemetry`` off the executor step allocates nothing and never
-  reads a clock — tools/telemetry_overhead.py gates this at < 2% of the
-  prepared hot path.
+- **A site is live under the flag or under a profiler session.**
+  ``TRACER.live()`` is ``TRACER.on`` (``FLAGS_telemetry``) or a running
+  jax profiler session (``TraceAnnotation.is_enabled()``).  Under a
+  session ``begin``/``end`` also enter/exit a
+  ``jax.profiler.TraceAnnotation`` carrying the span's cid and args, so
+  the span is an event of the capture's ``/host:`` plane, on the
+  profiler's own host clock, beside the runtime's events and the
+  device ops (whose plane the profiler converts from the device's
+  clock to within a millisecond or two: ``export.clock_skew``).
+- **Dead cost is one check per step.**  Hot paths ask ``live()`` once
+  (per ``run_prepared``, per ``DecodeLoop`` iteration), hand the answer
+  down and test a local at every inner site, so with neither the flag
+  nor a session a step builds no span and reads no clock for one —
+  tools/telemetry_overhead.py gates this at < 2% of the prepared hot
+  path.
 - **Completed spans land in a bounded ring** (``collections.deque`` with
   maxlen — append is GIL-atomic, so the record path takes no lock),
   sized by ``FLAGS_telemetry_ring_size``.  The same ring is the flight
@@ -39,6 +49,8 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from paddle_tpu.core.flags import FLAGS
 
 __all__ = ["TRACER", "Tracer", "Span", "round_cid", "traced",
@@ -57,7 +69,8 @@ class Span:
     """One host event.  ``t1 == 0`` means still open (the flight
     recorder reports such spans as where a thread is blocked)."""
 
-    __slots__ = ("name", "t0", "t1", "tid", "cid", "args", "depth")
+    __slots__ = ("name", "t0", "t1", "tid", "cid", "args", "depth",
+                 "ann")
 
     def __init__(self, name, t0, tid, cid, args, depth):
         self.name = name
@@ -67,6 +80,7 @@ class Span:
         self.cid = cid
         self.args = args
         self.depth = depth
+        self.ann = None     # the profiler's annotation, under a session
 
 
 class _NoopCtx:
@@ -119,6 +133,12 @@ class Tracer:
     def disable(self):
         self.on = False
 
+    def live(self):
+        """Would a span opened now be kept: in the ring
+        (``FLAGS_telemetry``) or in a running jax profiler session's
+        capture.  Hot paths ask once a step and hand the answer down."""
+        return self.on or _Annotation.is_enabled()
+
     def configure(self, ring_size):
         """Resize the ring (keeps the newest spans)."""
         self._ring = deque(self._ring, maxlen=int(ring_size))
@@ -142,8 +162,8 @@ class Tracer:
 
     # -- record path --------------------------------------------------
     def begin(self, name, cid=None, args=None):
-        """Open a span.  ENABLED-path only: callers guard on ``.on`` so
-        the disabled path never reaches here."""
+        """Open a span.  LIVE-path only: callers guard on ``live()``
+        so the dead path never reaches here."""
         tid = threading.get_ident()
         stack = self._stacks.get(tid)
         if stack is None:
@@ -151,6 +171,12 @@ class Tracer:
         span = Span(name, time.perf_counter_ns(), tid, cid, args,
                     len(stack))
         stack.append(span)
+        if _Annotation.is_enabled():
+            meta = dict(args) if args else {}
+            if cid is not None:
+                meta["cid"] = cid
+            span.ann = _Annotation(name, **meta)
+            span.ann.__enter__()
         return span
 
     def end(self, span, cid=None, args=None):
@@ -158,6 +184,13 @@ class Tracer:
         unbalanced nesting (an exception that unwound past un-ended
         children): the stack pops back to this span."""
         span.t1 = time.perf_counter_ns()
+        ann = span.ann
+        if ann is not None:
+            if cid is not None:
+                ann.set_metadata(cid=cid)
+            if args:
+                ann.set_metadata(**args)
+            ann.__exit__(None, None, None)
         if cid is not None:
             span.cid = cid
         if args:
@@ -171,8 +204,8 @@ class Tracer:
 
     def span(self, name, cid=None, args=None):
         """Context-manager form for non-hot paths (RPC rounds, kernel
-        lowering).  Returns a shared no-op when tracing is off."""
-        if not self.on:
+        lowering).  Returns a shared no-op when no site is live."""
+        if not self.live():
             return _NOOP
         return _SpanCtx(self, self.begin(name, cid, args))
 
@@ -268,17 +301,17 @@ def _default_label():
 
 
 def traced(name, args_fn=None):
-    """Decorator form: span the whole call when tracing is on, a plain
-    passthrough (one attribute read) when off.  ``args_fn(*a, **kw)``
-    may build the span args lazily — it only runs when tracing is on,
-    so the disabled path pays nothing.  Used at Pallas kernel launch
-    sites: the span records the trace/lowering-time cost (inside jit,
-    the launch itself happens on device, which utils/xplane.py covers).
+    """Decorator form: span the whole call when the site is live, a
+    plain passthrough (one check) when not.  ``args_fn(*a, **kw)`` may
+    build the span args lazily — it only runs on the live path.  Used
+    at Pallas kernel launch sites: the span records the
+    trace/lowering-time cost (inside jit, the launch itself happens on
+    device: the capture's device plane has it).
     """
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*a, **kw):
-            if not TRACER.on:
+            if not TRACER.live():
                 return fn(*a, **kw)
             span = TRACER.begin(
                 name, None, args_fn(*a, **kw) if args_fn else None)

@@ -65,6 +65,7 @@ Two raw-decode-speed levers ride on top (ISSUE 19), both default-off:
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 
@@ -132,14 +133,97 @@ _M_SPEC_VERIFY_US = _metrics.counter(
     "wall micros in target verify dispatches")
 
 
+# where a DecodeLoop iteration's wall time goes, counted where it is
+# spent (integers of microseconds, like the spec meters above): every
+# instant of an iteration is host, prefill or wait, so the three add up
+# to wall.
+# The benchmark's sched_host_share / prefill_share / queue_wait_ms read
+# these; an operator cross-checks wall - host - prefill against wait
+_M_LOOP_WALL_US = _metrics.counter(
+    "serve_loop_wall_us_total",
+    "wall micros of decode-loop iterations")
+_M_LOOP_HOST_US = _metrics.counter(
+    "serve_loop_host_us_total",
+    "iteration micros in the scheduler's own work: admit, grow, "
+    "stage, dispatch, emit and what lies between them (neither inside "
+    "a prefill nor blocked on a decode result)")
+_M_LOOP_PREFILL_US = _metrics.counter(
+    "serve_loop_prefill_us_total",
+    "iteration micros inside prefills run from the loop (the running "
+    "batch waits for each)")
+_M_LOOP_WAIT_US = _metrics.counter(
+    "serve_loop_wait_us_total",
+    "iteration micros blocked on the device for a decode result "
+    "(speculative rounds: their draft and verify dispatches)")
+_M_QUEUE_WAIT_US = _metrics.counter(
+    "serve_queue_wait_us_total",
+    "arrival -> admission micros summed over admissions (a preempted "
+    "request's re-admission counts again, from its original arrival)")
+_M_ADMISSIONS = _metrics.counter(
+    "serve_admissions_total",
+    "requests admitted into the running batch (re-admissions after "
+    "preemption included)")
+
+_REQUEST_IDS = itertools.count(1)
+_clock_ns = time.perf_counter_ns
+_FLUSH_NS = 10_000_000      # of loop wall time between counter flushes
+
+
+class _LoopTimes:
+    """Nanoseconds of DecodeLoop iterations by phase, not yet counted.
+    ``mark`` is the clock at the last phase boundary: a boundary adds
+    the time since to the phase that ends there and moves the mark, so
+    nothing of an iteration goes uncounted.  The loop flushes whole micros to the ``serve_loop_*`` counters (and
+    keeps the remainder) at the end of an iteration once ``_FLUSH_NS``
+    of wall time have gathered, and whenever it goes idle — four
+    locked counter increments an iteration would be most of the
+    phase clock's cost on a sub-millisecond step.  So the counters lag
+    a running loop by under ``_FLUSH_NS`` and are exact for an idle
+    one."""
+
+    __slots__ = ("wall", "host", "prefill", "wait", "mark")
+
+    def __init__(self):
+        self.wall = self.host = self.prefill = self.wait = self.mark = 0
+
+    def flush(self):
+        us, self.wall = divmod(self.wall, 1000)
+        _M_LOOP_WALL_US.inc(us)
+        us, self.host = divmod(self.host, 1000)
+        _M_LOOP_HOST_US.inc(us)
+        us, self.prefill = divmod(self.prefill, 1000)
+        _M_LOOP_PREFILL_US.inc(us)
+        us, self.wait = divmod(self.wait, 1000)
+        _M_LOOP_WAIT_US.inc(us)
+
+
 def token_metrics_probe(iters):
     """The COMPLETE per-token metric op set of the decode loop, for the
     telemetry_overhead.py decode gate (batcher.metrics_probe's rule):
     every operation ``_METRICS_ON`` gates per emitted token in the
     single-sequence worst case, where the per-iteration ops are not
-    amortized across batch neighbours."""
+    amortized across batch neighbours — the iteration's liveness check
+    and phase clock reads with the ``serve_loop_*`` flush included."""
+    times = _LoopTimes()
     for _ in range(iters):
-        # per-iteration (worst case: one live row)
+        # per-iteration (worst case: one live row): the dead-path
+        # sites of DecodeLoop._iterate and decode_step — one liveness
+        # check, four clock reads (start, around the wait, end), the
+        # phase adds, a flush every _FLUSH_NS of (here, 0.2-ms)
+        # iterations
+        TRACER.live()
+        times.mark = t_iter = _clock_ns()
+        now = _clock_ns()
+        times.host += now - times.mark              # up to the wait
+        times.mark = now
+        now = _clock_ns()
+        times.wait += now - times.mark              # the wait
+        times.mark = now
+        now = _clock_ns()
+        times.host += now - times.mark              # emit
+        times.wall += now - t_iter + 200000
+        if times.wall >= _FLUSH_NS:
+            times.flush()
         _M_DECODE_STEPS.inc()
         _M_DECODE_ROWS.inc(1)
         _M_DECODE_SLOTS.inc(1)
@@ -239,11 +323,14 @@ class GenRequest:
     ``out`` are reset by preemption — greedy decode regenerates the
     same tokens on re-admission."""
 
-    __slots__ = ("prompt", "max_new", "eos_id", "future", "t_arrival",
-                 "blocks", "context_len", "out", "t_first", "t_last",
-                 "itl_ms", "preempted", "cached_len", "draft_len")
+    __slots__ = ("rid", "prompt", "max_new", "eos_id", "future",
+                 "t_arrival", "blocks", "context_len", "out", "t_first",
+                 "t_last", "itl_ms", "preempted", "cached_len",
+                 "draft_len")
 
     def __init__(self, prompt, max_new, eos_id, future):
+        # small process-wide id: the cid of this request's spans
+        self.rid = next(_REQUEST_IDS)
         self.prompt = [int(t) for t in prompt]
         self.max_new = int(max_new)
         self.eos_id = eos_id
@@ -668,18 +755,31 @@ class GenerativeEngine:
     def _block_fwd(self, p, l, h, attend):
         """One transformer block: pre-LN attention (via ``attend``,
         which owns QKV cache writes + attention for its mode) then the
-        pre-LN gelu MLP."""
+        pre-LN gelu MLP.
+
+        The projections and the MLP sit in ``layer/attention`` and
+        ``layer/ffn`` name scopes; ``attend`` is called outside every
+        scope: XLA names a Mosaic custom call after the innermost scope
+        around it, and the benchmark's paged_attn_roofline.serve finds
+        the decode kernel as ``%serve_decode.N`` (the kernel's own name
+        rides its ``kernel_metadata``, kernels/flash_attention.py)."""
         import jax
 
         pre = "l%d." % l
-        a = self._layer_norm(h, p[pre + "ln1.scale"], p[pre + "ln1.bias"])
-        qkv = self._mm(p, pre + "wqkv", a)
+        with jax.named_scope("layer"), jax.named_scope("attention"):
+            a = self._layer_norm(h, p[pre + "ln1.scale"],
+                                 p[pre + "ln1.bias"])
+            qkv = self._mm(p, pre + "wqkv", a)
         att = attend(l, qkv)
-        h = h + self._mm(p, pre + "wo", att)
-        m = self._layer_norm(h, p[pre + "ln2.scale"], p[pre + "ln2.bias"])
-        h = h + self._mm(p, pre + "w2",
-                         jax.nn.gelu(self._mm(p, pre + "w1", m),
-                                     approximate=True))
+        with jax.named_scope("layer"):
+            with jax.named_scope("attention"):
+                h = h + self._mm(p, pre + "wo", att)
+            with jax.named_scope("ffn"):
+                m = self._layer_norm(h, p[pre + "ln2.scale"],
+                                     p[pre + "ln2.bias"])
+                h = h + self._mm(p, pre + "w2",
+                                 jax.nn.gelu(self._mm(p, pre + "w1", m),
+                                             approximate=True))
         return h
 
     @staticmethod
@@ -726,6 +826,7 @@ class GenerativeEngine:
         paged attention over each sequence's pages, greedy next token
         out.  Pages are donated — the returned buffers REPLACE the
         engine's, nothing round-trips the host."""
+        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import paged_attention
@@ -754,9 +855,10 @@ class GenerativeEngine:
 
             for l in range(cfg.n_layers):
                 h = self._block_fwd(p, l, h, attend)
-            h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-            logits = self._mm(p, "lm_head", h)             # [B, V] f32
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("head"):
+                h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
+                logits = self._mm(p, "lm_head", h)         # [B, V] f32
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             if with_logits:
                 return nxt, logits, kp, vp
             return nxt, kp, vp
@@ -774,6 +876,7 @@ class GenerativeEngine:
         into the sequence's blocks (pad positions redirect to the
         reserved scratch block), greedy first token from the last real
         position."""
+        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import flash_attention
@@ -810,10 +913,11 @@ class GenerativeEngine:
 
             for l in range(cfg.n_layers):
                 h = self._block_fwd(p, l, h, attend)
-            h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-            last = jnp.take(h, length - 1, axis=0)
-            logits = jnp.dot(last, p["lm_head"])           # [V]
-            nxt = jnp.argmax(logits).astype(jnp.int32)
+            with jax.named_scope("head"):
+                h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
+                last = jnp.take(h, length - 1, axis=0)
+                logits = jnp.dot(last, p["lm_head"])       # [V]
+                nxt = jnp.argmax(logits).astype(jnp.int32)
             return nxt, kp, vp
 
         return self._aot(
@@ -833,6 +937,7 @@ class GenerativeEngine:
         the page gather).  Greedy first token from the last real row.
         Compute scales with the suffix, not the prompt — the entire
         point of the cache."""
+        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import paged_attention
@@ -864,10 +969,11 @@ class GenerativeEngine:
 
             for l in range(cfg.n_layers):
                 h = self._block_fwd(p, l, h, attend)
-            h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-            last = jnp.take(h, count - 1, axis=0)
-            logits = jnp.dot(last, p["lm_head"])           # [V]
-            nxt = jnp.argmax(logits).astype(jnp.int32)
+            with jax.named_scope("head"):
+                h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
+                last = jnp.take(h, count - 1, axis=0)
+                logits = jnp.dot(last, p["lm_head"])       # [V]
+                nxt = jnp.argmax(logits).astype(jnp.int32)
             return nxt, kp, vp
 
         return self._aot(
@@ -887,6 +993,7 @@ class GenerativeEngine:
         small draft's step cost.  K/V for the k positions lands in the
         pages exactly as k separate decode steps would have written
         it."""
+        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import paged_attention
@@ -919,10 +1026,11 @@ class GenerativeEngine:
                 h2 = h
                 for l in range(cfg.n_layers):
                     h2 = self._block_fwd(p, l, h2, attend)
-                h2 = self._layer_norm(h2, p["lnf.scale"],
-                                      p["lnf.bias"])
-                logits = self._mm(p, "lm_head", h2)
-                cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("head"):
+                    h2 = self._layer_norm(h2, p["lnf.scale"],
+                                          p["lnf.bias"])
+                    logits = self._mm(p, "lm_head", h2)
+                    cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 props.append(cur)
             return jnp.stack(props, axis=1), kp, vp      # [B, k]
 
@@ -945,6 +1053,7 @@ class GenerativeEngine:
         mismatch write K/V the host will roll back over — attention
         never reads past ``lens``, so stale writes are dead until
         overwritten."""
+        import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import paged_attention
@@ -977,9 +1086,10 @@ class GenerativeEngine:
 
             for l in range(cfg.n_layers):
                 h = self._block_fwd(p, l, h, attend)
-            h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-            logits = self._mm(p, "lm_head", h)             # [R, V]
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("head"):
+                h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
+                logits = self._mm(p, "lm_head", h)         # [R, V]
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             nxt = nxt.reshape(bb, k1)
             if with_logits:
                 return nxt, logits.reshape(bb, k1, cfg.vocab), kp, vp
@@ -993,12 +1103,15 @@ class GenerativeEngine:
 
     # -- dispatch -------------------------------------------------------
 
-    def prefill(self, seq):
+    def prefill(self, seq, _tr=None):
         """Run ``seq``'s prompt through the prefill bucket that fits it;
         returns the first generated token.  ``seq.blocks`` must already
         hold the prompt's blocks (TokenScheduler.try_admit).  With a
         prefix-cache hit recorded on the sequence (``seq.cached_len``),
-        only the un-cached suffix is computed."""
+        only the un-cached suffix is computed.  ``_tr`` is the tracer
+        where the caller found its spans live (DecodeLoop asks once an
+        iteration), else None: the ``serve.prefill`` span then carries
+        the request's id as its cid."""
         cfg = self.config
         n = len(seq.prompt)
         if n < 1:
@@ -1007,22 +1120,28 @@ class GenerativeEngine:
             raise ValueError("prompt length %d exceeds max_seq %d "
                              "(block_size x max_blocks)" % (n, cfg.max_seq))
         cached = getattr(seq, "cached_len", 0)
+        cid = getattr(seq, "rid", None)
         if self.prefix_cache is not None and 0 < cached < n:
-            tok = self._prefill_suffix(seq.prompt, seq.blocks, cached)
+            tok = self._prefill_suffix(seq.prompt, seq.blocks, cached,
+                                       _tr, cid)
         else:
-            tok = self.prefill_tokens(seq.prompt, seq.blocks)
+            tok = self.prefill_tokens(seq.prompt, seq.blocks, _tr, cid)
         seq.context_len = n
         if _batcher._METRICS_ON:
             _M_PREFILLS.inc()
         return tok
 
-    def prefill_tokens(self, tokens, blocks):
+    def prefill_tokens(self, tokens, blocks, _tr=None, _cid=None):
         """Raw full prefill: write K/V for every position of ``tokens``
         into ``blocks`` and return the greedy next token.  No sequence
         bookkeeping — the speculative draft's re-prefill path uses this
-        directly (its catch-up feed is tokens, not a GenRequest)."""
+        directly (its catch-up feed is tokens, not a GenRequest).
+        ``serve.prefill`` spans staging, dispatch and the wait for the
+        token; its child ``serve.prefill.dispatch`` the dispatch."""
         cfg = self.config
         n = len(tokens)
+        sp = (_tr.begin("serve.prefill", _cid, {"tokens": n})
+              if _tr is not None else None)
         want = pow2_bucket(max(n, cfg.block_size), cfg.max_seq)
         key, exe = self._prefill.pick((want,))
         (s_len,) = key
@@ -1034,26 +1153,35 @@ class GenerativeEngine:
         # extras hold positions past this prefill's writes
         m = min(len(blocks), len(ids))
         ids[:m] = blocks[:m]
-        with TRACER.span("serve.prefill",
-                         args={"bucket": s_len, "len": n}):
-            with self._state_lock:
-                self._kv_steps += 1
-                self._kv_guard.begin("prefill", self._kv_steps)
-                nxt, kp, vp = exe(self._params, self._kp, self._vp,
-                                  toks, np.int32(n), ids)
-                self._kp, self._vp = kp, vp
-                self._kv_guard.rebind()
-        return int(nxt)
+        spd = (_tr.begin("serve.prefill.dispatch", _cid)
+               if sp is not None else None)
+        with self._state_lock:
+            self._kv_steps += 1
+            self._kv_guard.begin("prefill", self._kv_steps)
+            nxt, kp, vp = exe(self._params, self._kp, self._vp,
+                              toks, np.int32(n), ids)
+            self._kp, self._vp = kp, vp
+            self._kv_guard.rebind()
+        if sp is None:
+            return int(nxt)
+        _tr.end(spd)
+        tok = int(nxt)      # blocks until the device has the token
+        _tr.end(sp, args={"bucket": s_len})
+        return tok
 
-    def _prefill_suffix(self, tokens, blocks, start):
+    def _prefill_suffix(self, tokens, blocks, start, _tr=None,
+                        _cid=None):
         """Suffix-only prefill (prefix-cache hit): positions
         ``start..n-1`` computed and written, the cached prefix read
         through the page gather.  Bucketed by SUFFIX length — an
         80%-cached prompt runs a bucket ~5x narrower than its full
-        prefill."""
+        prefill.  Spanned like ``prefill_tokens``, with ``cached``."""
         cfg = self.config
         n = len(tokens)
         count = n - start
+        sp = (_tr.begin("serve.prefill", _cid,
+                        {"tokens": n, "cached": start})
+              if _tr is not None else None)
         want = pow2_bucket(max(count, cfg.block_size), cfg.max_seq)
         key, exe = self._prefill_cached.pick((want,))
         (s_len,) = key
@@ -1061,18 +1189,22 @@ class GenerativeEngine:
         toks[:count] = tokens[start:]
         ids = np.zeros(cfg.max_blocks, np.int32)
         ids[:len(blocks)] = blocks
-        with TRACER.span("serve.prefill_cached",
-                         args={"bucket": s_len, "len": n,
-                               "cached": start}):
-            with self._state_lock:
-                self._kv_steps += 1
-                self._kv_guard.begin("prefill_cached", self._kv_steps)
-                nxt, kp, vp = exe(self._params, self._kp, self._vp,
-                                  toks, np.int32(start), np.int32(count),
-                                  ids)
-                self._kp, self._vp = kp, vp
-                self._kv_guard.rebind()
-        return int(nxt)
+        spd = (_tr.begin("serve.prefill.dispatch", _cid)
+               if sp is not None else None)
+        with self._state_lock:
+            self._kv_steps += 1
+            self._kv_guard.begin("prefill_cached", self._kv_steps)
+            nxt, kp, vp = exe(self._params, self._kp, self._vp,
+                              toks, np.int32(start), np.int32(count),
+                              ids)
+            self._kp, self._vp = kp, vp
+            self._kv_guard.rebind()
+        if sp is None:
+            return int(nxt)
+        _tr.end(spd)
+        tok = int(nxt)
+        _tr.end(sp, args={"bucket": s_len})
+        return tok
 
     def copy_block(self, src, dst):
         """Device-copy one block's K/V pages across all layers — the
@@ -1095,30 +1227,42 @@ class GenerativeEngine:
                 self._kp, self._vp, np.int32(src), np.int32(dst))
             self._kv_guard.rebind()
 
-    def decode(self, seqs, with_logits=False):
+    def decode(self, seqs, with_logits=False, _tr=None, _times=None):
         """One decode iteration over the running sequences: picks the
         ``(batch, block-count)`` bucket, pads the block tables /
         context lengths / last tokens up to it (padding rows aim every
         table slot at the reserved scratch block), dispatches, and
-        returns the next token per live sequence."""
-        b = len(seqs)
+        returns the next token per live sequence.  ``_tr`` / ``_times``
+        are DecodeLoop's: the tracer where spans are live and the
+        iteration's phase clock (``decode_step``)."""
         out = self.decode_step(
             [s.blocks for s in seqs],
             [s.context_len for s in seqs],
             [s.out[-1] if s.out else (s.prompt[-1] if s.prompt else 0)
              for s in seqs],
-            with_logits=with_logits, _count=True)
+            with_logits=with_logits, _count=True, _tr=_tr,
+            _times=_times)
         for s in seqs:
             s.context_len += 1
         return out
 
     def decode_step(self, blocks_list, lens_list, toks_list,
-                    with_logits=False, _count=False):
+                    with_logits=False, _count=False, _tr=None,
+                    _times=None):
         """Raw single-token decode over parallel lists (one entry per
         row): no sequence bookkeeping, callers own their own length
         accounting — the speculative draft's propose/catch-up steps
-        run the draft engine through this."""
+        run the draft engine through this.
+
+        Three phases, each a span where ``_tr`` is given:
+        ``serve.decode.stage`` (bucket pick, tables/lens/toks),
+        ``serve.decode`` (the dispatch under the state lock) and
+        ``serve.decode.wait`` (the read-back, blocked on the device).
+        ``_times`` gets the time up to the read-back as host time and
+        the read-back as wait."""
         cfg = self.config
+        sp = (_tr.begin("serve.decode.stage")
+              if _tr is not None else None)
         b = len(blocks_list)
         nb = max(len(bl) for bl in blocks_list)
         want = (pow2_bucket(b, cfg.max_batch),
@@ -1133,27 +1277,45 @@ class GenerativeEngine:
             tables[i, :len(bl)] = bl
             lens[i] = lens_list[i]
             toks[i] = toks_list[i]
-        with TRACER.span("serve.decode",
-                         args={"bucket": "%dx%d" % key, "rows": b}):
-            with self._state_lock:
-                self._kv_steps += 1
-                self._kv_guard.begin("decode", self._kv_steps)
-                outs = exe(self._params, self._kp, self._vp,
-                           tables, lens, toks)
-                if with_logits:
-                    nxt, logits, kp, vp = outs
-                else:
-                    nxt, kp, vp = outs
-                self._kp, self._vp = kp, vp
-                self._kv_guard.rebind()
+        if sp is not None:
+            bucket = "%dx%d" % key
+            _tr.end(sp, args={"bucket": bucket})
+            sp = _tr.begin("serve.decode", None,
+                           {"bucket": bucket, "rows": b})
+        with self._state_lock:
+            self._kv_steps += 1
+            self._kv_guard.begin("decode", self._kv_steps)
+            outs = exe(self._params, self._kp, self._vp,
+                       tables, lens, toks)
+            if with_logits:
+                nxt, logits, kp, vp = outs
+            else:
+                nxt, kp, vp = outs
+            self._kp, self._vp = kp, vp
+            self._kv_guard.rebind()
         if _count and _batcher._METRICS_ON:
             _M_DECODE_STEPS.inc()
             _M_DECODE_ROWS.inc(b)
             _M_DECODE_SLOTS.inc(bb)
             _M_OCC_PCT.observe(100.0 * b / bb)
+        if sp is not None:
+            _tr.end(sp)
+            sp = _tr.begin("serve.decode.wait")
+        if _times is not None:
+            now = _clock_ns()
+            _times.host += now - _times.mark
+            _times.mark = now
         nxt = np.asarray(nxt)[:b]
         if with_logits:
-            return nxt, np.asarray(logits)[:b]
+            logits = np.asarray(logits)[:b]
+        if _times is not None:
+            now = _clock_ns()
+            _times.wait += now - _times.mark
+            _times.mark = now
+        if sp is not None:
+            _tr.end(sp)
+        if with_logits:
+            return nxt, logits
         return nxt
 
     # -- speculative decoding (ISSUE 19 tentpole b) ---------------------
@@ -1235,7 +1397,7 @@ class GenerativeEngine:
             return nxt, np.asarray(logits)[:b]
         return nxt
 
-    def spec_decode(self, seqs):
+    def spec_decode(self, seqs, _times=None):
         """One speculative round over the running set: catch the draft
         pages up to the target context (a re-prefill for fresh or
         preemption-reset sequences, single catch-up steps otherwise),
@@ -1251,7 +1413,11 @@ class GenerativeEngine:
         advances context to c+m+1.  The draft's pages are valid
         through position c+m (its proposals matched there), which is
         exactly the new context minus one when m < k — only a full
-        accept leaves the draft one catch-up step behind."""
+        accept leaves the draft one catch-up step behind.
+
+        ``_times`` (DecodeLoop's phase clock) gets the draft and verify
+        dispatches this round already meters as wait, the rest of the
+        round as host."""
         k = self.spec_k
         d = self.draft
         t_draft = 0.0
@@ -1310,6 +1476,12 @@ class GenerativeEngine:
             _M_DECODE_ROWS.inc(b)
             _M_DECODE_SLOTS.inc(b)
             _M_OCC_PCT.observe(100.0)
+        if _times is not None:
+            now = _clock_ns()
+            busy = int((t_draft + t_verify) * 1e9)
+            _times.wait += busy
+            _times.host += now - _times.mark - busy
+            _times.mark = now
         return emitted
 
     @property
@@ -1481,6 +1653,7 @@ class DecodeLoop:
                                         engine.config.max_batch,
                                         prefix_cache=engine.prefix_cache)
         self.label = label
+        self._times = _LoopTimes()
         self._stop = _san.make_event("generative.decode.stop")
         self._thread = threading.Thread(
             target=self._loop, daemon=True,
@@ -1499,7 +1672,9 @@ class DecodeLoop:
         running = []
         while True:
             if not running:
-                req = self.queue.get(timeout=0.25)
+                self._times.flush()
+                with TRACER.span("serve.idle"):
+                    req = self.queue.get(timeout=0.25)
                 if req is None:
                     if self._stop.is_set() and self.queue.closed:
                         return
@@ -1515,38 +1690,86 @@ class DecodeLoop:
                 del running[:]
 
     def _iterate(self, running):
+        """One scheduler iteration.  Liveness of the span sites is
+        asked ONCE here and handed down (``trc``), as is whether the
+        ``serve_loop_*`` phase clock runs (``times``): every inner site
+        tests a local, and with neither no span is built and no clock
+        read.  All spans are this thread's, children of
+        ``serve.iterate``."""
+        trc = TRACER if TRACER.live() else None
+        times = self._times if _batcher._METRICS_ON else None
+        if times is not None:
+            times.mark = t0 = _clock_ns()
+        sp = (trc.begin("serve.iterate", None, {"rows": len(running)})
+              if trc is not None else None)
+        try:
+            self._iterate_phases(running, trc, times)
+        finally:
+            if sp is not None:
+                trc.end(sp)
+            if times is not None:
+                now = _clock_ns()
+                times.host += now - times.mark
+                times.wall += now - t0
+                if times.wall >= _FLUSH_NS:
+                    times.flush()
+
+    def _iterate_phases(self, running, trc, times):
         # 1. admission: stream prefills into the running batch.  A
         # prefill failure (over-long prompt that slipped validation, a
         # cold-bucket compile error) fails THAT request and returns its
         # just-allocated blocks — it must not leak pool capacity or
         # take the rest of the batch down with it
-        for req in self.scheduler.try_admit(self.queue, len(running)):
-            if req.blocks and req.context_len:
-                # migrated-in (serving/fleet.py): the prompt's KV pages
-                # are already resident and `out` holds the first token
-                # — joining the batch IS the admission, no prefill
-                if len(req.out) >= req.max_new or (
-                        req.eos_id is not None and req.out
-                        and req.out[-1] == req.eos_id):
-                    self.engine.free_sequence(req)
-                    if not req.future.done():
-                        req.future.set_result(req.result())
-                    continue
-                running.append(req)
+        sp = trc.begin("serve.admit") if trc is not None else None
+        admitted = self.scheduler.try_admit(self.queue, len(running))
+        if admitted and times is not None:
+            now = time.perf_counter()
+            _M_ADMISSIONS.inc(len(admitted))
+            _M_QUEUE_WAIT_US.inc(int(1e6 * sum(
+                now - req.t_arrival for req in admitted)))
+        joining = []    # (request, still needs its prefill), in order
+        for req in admitted:
+            if not (req.blocks and req.context_len):
+                joining.append((req, True))
                 continue
-            try:
-                tok = self.engine.prefill(req)
-            except Exception as e:
+            # migrated-in (serving/fleet.py): the prompt's KV pages
+            # are already resident and `out` holds the first token
+            # — joining the batch IS the admission, no prefill
+            if len(req.out) >= req.max_new or (
+                    req.eos_id is not None and req.out
+                    and req.out[-1] == req.eos_id):
                 self.engine.free_sequence(req)
                 if not req.future.done():
-                    req.future.set_exception(e)
+                    req.future.set_result(req.result())
                 continue
-            if self.engine.prefix_cache is not None:
-                # index the fully-written prompt blocks — the NEXT
-                # request sharing this prefix admits against them
-                self.engine.prefix_cache.insert(req)
+            joining.append((req, False))
+        if sp is not None:
+            trc.end(sp, args={"admitted": len(admitted)})
+        for req, needs_prefill in joining:
+            if needs_prefill:
+                if times is not None:
+                    now = _clock_ns()
+                    times.host += now - times.mark
+                    times.mark = now
+                try:
+                    tok = self.engine.prefill(req, trc)
+                except Exception as e:
+                    self.engine.free_sequence(req)
+                    if not req.future.done():
+                        req.future.set_exception(e)
+                    continue
+                finally:
+                    if times is not None:
+                        now = _clock_ns()
+                        times.prefill += now - times.mark
+                        times.mark = now
+                if self.engine.prefix_cache is not None:
+                    # index the fully-written prompt blocks — the NEXT
+                    # request sharing this prefix admits against them
+                    self.engine.prefix_cache.insert(req)
             running.append(req)
-            self._emit(req, tok, running)
+            if needs_prefill:
+                self._emit_all(((req, tok),), running, trc)
         if not running:
             return
         # this iteration runs speculatively when every sequence has
@@ -1563,6 +1786,8 @@ class DecodeLoop:
         # 2. growth/preemption: every sequence writing into a fresh
         # block this iteration needs one allocated (a speculative
         # iteration writes k+1 positions, so it provisions that far)
+        sp = trc.begin("serve.grow") if trc is not None else None
+        preempted = 0
         for seq in list(running):
             if seq not in running:
                 continue
@@ -1582,28 +1807,56 @@ class DecodeLoop:
                         self.engine.pool.capacity))
                     break
                 self._preempt(victim, running)
+                preempted += 1
+        if sp is not None:
+            trc.end(sp, args={"preempted": preempted})
         if not running:
             return
         # 3. one decode iteration over the whole running set — a
         # speculative round emits up to k+1 tokens per sequence
         if spec:
+            pairs = []
             for seq, toks in zip(list(running),
-                                 self.engine.spec_decode(running)):
+                                 self.engine.spec_decode(running, times)):
                 # replay the round token-at-a-time so _emit's
                 # done-checks (max_new / eos / max_seq) see the same
                 # intermediate context a plain-decode iteration would
                 seq.context_len -= len(toks)
-                for tok in toks:
-                    seq.context_len += 1
-                    self._emit(seq, int(tok), running)
-                    if seq not in running:
-                        break   # finished mid-round; discard the rest
+                pairs.extend((seq, int(tok)) for tok in toks)
+            self._emit_all(pairs, running, trc, replay=True)
         else:
-            toks = self.engine.decode(running)
-            for seq, tok in zip(list(running), toks):
-                self._emit(seq, int(tok), running)
+            toks = self.engine.decode(running, _tr=trc, _times=times)
+            self._emit_all([(seq, int(tok)) for seq, tok
+                            in zip(list(running), toks)],
+                           running, trc)
+
+    def _emit_all(self, pairs, running, trc, replay=False):
+        """Emit ``(sequence, token)`` pairs under ONE ``serve.emit``
+        span (not one a token).  The span's cid
+        is the first finished request's id (``finished_ids`` has all),
+        so a request's prefill and its finishing emit share a cid.
+        ``replay``: a speculative round's tokens, each advancing the
+        context by one; a sequence that finishes mid-round drops the
+        rest."""
+        sp = trc.begin("serve.emit") if trc is not None else None
+        finished = []
+        tokens = 0
+        for seq, tok in pairs:
+            if replay:
+                if seq not in running:
+                    continue    # finished mid-round; discard the rest
+                seq.context_len += 1
+            tokens += 1
+            if self._emit(seq, tok, running):
+                finished.append(seq.rid)
+        if sp is not None:
+            trc.end(sp, cid=finished[0] if finished else None,
+                    args={"tokens": tokens,
+                          "finished": len(finished),
+                          "finished_ids": finished})
 
     def _emit(self, seq, tok, running):
+        """Deliver one token; True when it finished the sequence."""
         now = time.perf_counter()
         metrics_on = _batcher._METRICS_ON
         if seq.t_first is None:
@@ -1633,6 +1886,7 @@ class DecodeLoop:
                 _M_GEN_MS.observe((now - seq.t_arrival) * 1e3)
                 _M_TTFT.observe((seq.t_first - seq.t_arrival) * 1e3)
             seq.future.set_result(seq.result())
+        return done
 
     def _preempt(self, victim, running):
         """Recompute-style eviction: free the victim's blocks, requeue

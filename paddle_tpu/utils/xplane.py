@@ -25,8 +25,7 @@ import re
 
 __all__ = ["read_xspace", "op_totals", "print_op_profile",
            "op_profile", "category_profile", "print_category_profile",
-           "kernel_profile", "print_kernel_profile",
-           "device_trace_events"]
+           "kernel_profile", "print_kernel_profile"]
 
 
 def _varint(buf, i):
@@ -404,51 +403,3 @@ def print_kernel_profile(path, device_kind, name_re=r".", top=15,
             "%.1f" % tf if tf is not None else "-",
             "%.1f%%" % (100 * mxu) if mxu is not None else "-"))
     return rows
-
-
-def device_trace_events(path, plane_re=r"/device:", line_re=r".",
-                        max_events=200000):
-    """Chrome-trace events (ph 'X', absolute wall µs) from the device
-    planes of an xplane capture — the device half of a merged telemetry
-    timeline (observability/export.py feeds these next to the host
-    spans; XLine.timestamp_ns is unix-epoch based, matching the
-    tracer's wall-clock anchor).  Each device plane becomes one chrome
-    pid; each XLine one tid."""
-    events = []
-    n_planes = 0
-    for plane in read_xspace(path):
-        if not re.search(plane_re, plane["name"]):
-            continue
-        md = plane["event_metadata"]
-        # one distinct chrome pid per plane, based above any real OS
-        # pid (kernel.pid_max tops out at 4194304) so device tracks
-        # can't collide with the host dumps' genuine pids
-        pid = 10_000_000 + n_planes
-        n_planes += 1
-        events.append({"name": "process_name", "ph": "M", "pid": pid,
-                       "tid": 0, "args": {"name": plane["name"]}})
-        for tid, line in enumerate(plane.get("xlines", [])):
-            if not re.search(line_re, line["name"]):
-                continue
-            base_us = line["timestamp_ns"] / 1e3
-            events.append({"name": "thread_name", "ph": "M", "pid": pid,
-                           "tid": tid, "args": {"name": line["name"]}})
-            for meta_id, dur_ps, off_ps in line["events"]:
-                if len(events) >= max_events:
-                    # no silent cap: a marker event names the drop so a
-                    # merged timeline's empty tail reads as truncation,
-                    # not as the device going idle
-                    events.append({
-                        "name": "XPLANE EVENTS TRUNCATED (max_events="
-                                "%d reached; later lines/planes "
-                                "dropped)" % max_events,
-                        "ph": "I", "pid": pid, "tid": tid,
-                        "ts": base_us + off_ps / 1e6, "s": "g",
-                        "cat": "device"})
-                    return events
-                name = md.get(meta_id, "#%d" % meta_id).split(" = ")[0]
-                events.append({
-                    "name": name, "ph": "X", "pid": pid, "tid": tid,
-                    "ts": base_us + off_ps / 1e6,
-                    "dur": dur_ps / 1e6, "cat": "device"})
-    return events

@@ -399,7 +399,7 @@ def test_prefill_failure_fails_only_that_request():
                                   warm=False)
         orig = eng.prefill
 
-        def bomb(seq):
+        def bomb(seq, _tr=None):
             raise RuntimeError("synthetic prefill fault")
 
         eng.prefill = bomb

@@ -522,13 +522,27 @@ def test_profiler_events_are_bounded():
 
 # --------------------------------------------------------- overhead gate
 
-def test_instrumented_disabled_hot_path_under_two_percent():
-    """CI satellite: tools/telemetry_overhead.py gate, in-process."""
+sys.path.insert(0, os.path.join(REPO, "tools"))
+try:
+    import telemetry_overhead
+finally:
+    sys.path.pop(0)
+
+
+@pytest.fixture(scope="module")
+def overhead_gates():
+    """tools/telemetry_overhead.py's gates, measured once, in-process."""
     os.environ.setdefault("TELEMETRY_OVERHEAD_STEPS", "150")
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import telemetry_overhead
-    finally:
-        sys.path.pop(0)
     assert not TRACER.on
-    assert telemetry_overhead.main([]) == 0
+    return telemetry_overhead.measure()["gates"]
+
+
+@pytest.mark.parametrize("gate", telemetry_overhead.GATES)
+def test_instrumented_disabled_hot_path_under_two_percent(overhead_gates,
+                                                          gate):
+    """CI satellite: one case for each gate of the tool, so a failure
+    names the gate (every limit is the tool's own: 2%)."""
+    assert set(overhead_gates) == set(telemetry_overhead.GATES)
+    g = overhead_gates[gate]
+    assert g["ok"], "%s: %.5f of the step, limit %.3f" % (
+        gate, g["frac"], g["limit"])

@@ -63,14 +63,14 @@ def test_flash_train_kernels_lower():
         return vjp(out)
 
     assert mosaic_calls(fwd_bwd, QKV, QKV, QKV) == [
-        "_flash_kernel", "_dq_kernel", "_dkv_kernel"]
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
     assert mosaic_calls(
         lambda q, k, v: flash_attention_fwd_lse(q, k, v, causal=True),
-        QKV, QKV, QKV) == ["_flash_kernel"]
+        QKV, QKV, QKV) == ["flash_fwd"]
     assert mosaic_calls(
         lambda q, k, v, o, lse, do: flash_attention_bwd(
             q, k, v, o, lse, do, causal=True),
-        QKV, QKV, QKV, QKV, LSE, QKV) == ["_dq_kernel", "_dkv_kernel"]
+        QKV, QKV, QKV, QKV, LSE, QKV) == ["flash_bwd_dq", "flash_bwd_dkv"]
 
 
 def test_flash_chunk_lowers():
@@ -89,7 +89,7 @@ def test_prefill_flash_lowers(s_len):
     q = sds((1, 8, s_len, 128), f32)
     assert mosaic_calls(
         lambda q, k, v: flash_attention(q, k, v, causal=True),
-        q, q, q) == ["_flash_kernel"]
+        q, q, q) == ["flash_fwd"]
 
 
 @pytest.mark.parametrize("b,h,d,n,bs,nb", [

@@ -446,7 +446,9 @@ def test_trace_report_all_implies_every_rollup(tmp_path, capsys):
     tr = _tool("trace_report")
     # registry covers exactly the known rollups
     assert [r[0] for r in tr.ROLLUPS] == [
-        "numerics", "wire", "serve", "scale", "slo", "moe", "weaver"]
+        "numerics", "wire", "serve", "scale", "slo", "moe", "weaver",
+        "gaps"]     # gaps reads a profiler capture: --all asks for it
+    #                 only where --xplane gives one
     from paddle_tpu.observability.trace import Tracer
     obs_metrics.counter("slo_alerts_total").inc()
     t = Tracer(enabled=True)

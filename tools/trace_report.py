@@ -6,14 +6,16 @@ telemetry layer).
 Inputs are the per-process dump files the tracer writes
 (``trace_<label>_<pid>.json`` under FLAGS_telemetry_dump_dir, or any
 ``Tracer.dump`` output; a previously merged chrome trace also loads).
-Device traces from a ``jax.profiler.trace`` capture dir merge in with
-``--xplane`` (utils/xplane.py parses them; XLine timestamps are
-unix-epoch, so they land on the host spans' wall-clock timeline).
+A jax profiler capture (``--xplane DIR``) already holds the program's
+spans beside the runtime's events and the device ops
+(observability/trace.py bridges them): ``--gaps`` reads it alone, moves
+the device plane onto the host planes' clock (``export.clock_skew``)
+and puts the device's idle gaps down to the spans.
 
 Usage:
     python tools/trace_report.py DUMP.json [DUMP2.json ...]
     python tools/trace_report.py DUMPS... --merge merged_trace.json
-    python tools/trace_report.py DUMPS... --xplane /tmp/xprof_capture
+    python tools/trace_report.py --xplane /tmp/xprof_capture --gaps
     python tools/trace_report.py DUMPS... --prefix step. --top 20
     python tools/trace_report.py DUMPS... --numerics   # grad-norm
         rollup per process; numerics_*.json trip artifacts passed as
@@ -45,7 +47,8 @@ if REPO not in sys.path:
 # one row per rollup: (flag/attr name, export rows fn, export format
 # fn, text-mode section title, --help text).  Everything downstream —
 # argparse registration, --all, the JSON wrap and the text sections —
-# iterates this table.
+# iterates this table.  ``gaps`` alone reads the --xplane capture, not
+# the dumps.
 ROLLUPS = (
     ("numerics", "numerics_rows", "format_numerics_table",
      "numerics rollup (grad-norm trend / nonfinite sightings per "
@@ -98,6 +101,14 @@ ROLLUPS = (
      "failing schedules found, minimized decision-trace length per "
      "process — ISSUE 18); tools/weaver.py leaves a dump when "
      "FLAGS_telemetry_dump_dir is set"),
+    ("gaps", "gap_rows", "format_gap_table",
+     "idle gaps (device idle time put down to the program span the "
+     "scheduler/executor thread was in):",
+     "from the --xplane capture: every gap between device ops put "
+     "down to the deepest program span (serve.* / step.* / "
+     "executor.*) covering most of it — span, gaps, idle seconds, "
+     "share of the window, idle seconds under the span itself; "
+     "'unspanned' last"),
 )
 
 
@@ -136,13 +147,13 @@ def main(argv=None):
 
     ap = argparse.ArgumentParser(
         description="merge telemetry dumps; print per-phase breakdown")
-    ap.add_argument("dumps", nargs="+",
+    ap.add_argument("dumps", nargs="*",
                     help="per-process trace dump JSON files")
     ap.add_argument("--merge", default=None, metavar="OUT.json",
                     help="write the merged chrome://tracing JSON here")
     ap.add_argument("--xplane", default=None, metavar="DIR",
-                    help="jax.profiler.trace capture dir to merge "
-                         "device ops from")
+                    help="jax profiler capture (dir or .xplane.pb) "
+                         "that --gaps reads")
     ap.add_argument("--prefix", default="",
                     help="only report span names with this prefix "
                          "(e.g. 'step.' for the executor phases)")
@@ -165,7 +176,12 @@ def main(argv=None):
     if args.all_rollups:
         args.kernels = True
         for flag, *_ in ROLLUPS:
-            setattr(args, flag, True)
+            setattr(args, flag, flag != "gaps" or bool(args.xplane))
+    if args.gaps and not args.xplane:
+        ap.error("--gaps reads a profiler capture: give --xplane DIR")
+    if not args.dumps and not args.gaps:
+        ap.error("give trace dumps, or --xplane DIR --gaps")
+    profile = export.load_profile(args.xplane) if args.gaps else None
 
     # numerics trip artifacts ride the same dump dir as trace dumps;
     # partition them out by their fixed filename shape
@@ -184,20 +200,20 @@ def main(argv=None):
         _print_trips(trips)
         return 0
 
-    trace, dumps = export.merge_files(dump_paths, out_path=args.merge,
-                                      xplane=args.xplane)
+    trace, dumps = export.merge_files(dump_paths, out_path=args.merge)
     rows = export.phase_rows(dumps)
     if args.prefix:
         rows = [r for r in rows if r["name"].startswith(args.prefix)]
     # per-kernel rollup (ISSUE 7): Pallas launch-site spans grouped by
-    # kernel name + device events from the --xplane capture — fusion
-    # wins readable straight from a telemetry dump.  Skipped in plain
+    # kernel name — fusion wins readable straight from a telemetry
+    # dump.  Skipped in plain
     # --json mode (pre-existing contract emits bare phase rows), which
     # also spares the full extra span walk on large rings
     krows = export.kernel_rows(dumps, trace) \
         if (args.kernels or not args.json) else []
     # every registered rollup asked for: flag -> its export rows
-    rollup_rows = {flag: getattr(export, rows_fn)(dumps)
+    rollup_rows = {flag: getattr(export, rows_fn)(
+                       profile if flag == "gaps" else dumps)
                    for flag, rows_fn, _fmt, _title, _h in ROLLUPS
                    if getattr(args, flag)}
     if args.json:
@@ -211,9 +227,10 @@ def main(argv=None):
             print(json.dumps(rows, indent=2))
     else:
         total_spans = sum(len(d.get("spans", [])) for d in dumps)
-        print("%d process dump(s), %d spans, %d trace events%s" % (
-            len(dumps), total_spans, len(trace["traceEvents"]),
-            (" -> %s" % args.merge) if args.merge else ""))
+        if dumps:
+            print("%d process dump(s), %d spans, %d trace events%s" % (
+                len(dumps), total_spans, len(trace["traceEvents"]),
+                (" -> %s" % args.merge) if args.merge else ""))
         open_spans = [s for d in dumps
                       for s in d.get("open_spans", [])]
         if open_spans:
@@ -223,10 +240,11 @@ def main(argv=None):
                 print("  %-32s elapsed %.1f ms  %s" % (
                     s["name"], s.get("elapsed_us", 0) / 1e3,
                     s.get("cid", "")))
-        print(export.format_phase_table(rows, top=args.top))
+        if dumps:
+            print(export.format_phase_table(rows, top=args.top))
         if krows:
-            print("\nper-kernel rollup (pallas launch sites + xplane "
-                  "device ops):")
+            print("\nper-kernel rollup (pallas launch sites + device "
+                  "ops of a chrome trace that holds them):")
             print(export.format_kernel_table(krows))
         for flag, _rows_fn, fmt_fn, title, _h in ROLLUPS:
             if not getattr(args, flag):
@@ -240,7 +258,8 @@ def main(argv=None):
         # produced rows (flight dumps carry metrics but no completed
         # spans) — is a success even when the span table is empty;
         # fail only when the run produced no output at all
-        print("no completed spans matched", file=sys.stderr)
+        if dumps:
+            print("no completed spans matched", file=sys.stderr)
         return 0 if (args.merge or krows
                      or any(rollup_rows.values())) else 1
     return 0
