@@ -457,16 +457,16 @@ def _flash_pallas(q, k, v, scale, causal, block_q, block_k, interpret):
 # attends over K/V gathered THROUGH a block table from a paged pool.
 # ---------------------------------------------------------------------------
 
-def _paged_attention_xla(q, k_pages, v_pages, block_tables, context_lens,
-                         scale):
+def _paged_attention_xla(q, k_pages, v_pages, layer, block_tables,
+                         context_lens, scale):
     """Identical-math XLA path: gather the pages, mask past the context
     length, softmax, weighted sum.  The gather materializes the
     per-sequence context [B, NB*bs, H, D] — fine off-TPU; the Pallas
     kernel below streams pages through VMEM instead."""
-    k_ctx = k_pages[block_tables]            # [B, NB, bs, H, D]
+    k_ctx = k_pages[layer, block_tables]     # [B, NB, bs, H, D]
     b, nb, bs, h, d = k_ctx.shape
     k_ctx = k_ctx.reshape(b, nb * bs, h, d)
-    v_ctx = v_pages[block_tables].reshape(b, nb * bs, h, d)
+    v_ctx = v_pages[layer, block_tables].reshape(b, nb * bs, h, d)
     s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32),
                    k_ctx.astype(jnp.float32)) * scale
     pos = jnp.arange(nb * bs, dtype=jnp.int32)
@@ -526,12 +526,17 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 @_traced("pallas.paged_attention",
          lambda q, *a, **kw: {"q": str(q.shape)})
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    scale=None, force_xla=False, interpret=False):
+                    scale=None, layer=None, force_xla=False,
+                    interpret=False):
     """Decode-mode attention through a paged KV cache (ISSUE 11; the
     vLLM/PagedAttention access pattern, TPU-native).
 
     ``q`` [B, H, D] — ONE query token per sequence (the decode step);
-    ``k_pages``/``v_pages`` [N, bs, H, D] — the shared block pool;
+    ``k_pages``/``v_pages`` [L, N, bs, H, D] — the shared block pool of
+    every layer, handed over WHOLE, with ``layer`` the static index of
+    the layer that attends (a ``pool[layer]`` in front of the call is a
+    copy of that layer's pool: a Mosaic call takes whole buffers); a
+    one-layer pool [N, bs, H, D] goes without ``layer``;
     ``block_tables`` [B, NB] int32 — per-sequence page indices (unused
     slots may point anywhere; they are masked);
     ``context_lens`` [B] int32 — tokens of real context per sequence
@@ -540,34 +545,40 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 
     On TPU (or under ``interpret``) runs the Pallas kernel: the grid is
     (sequence, page) and the block table rides scalar prefetch, so each
-    grid step DMAs exactly the page the table names — the gathered
-    [B, S] context never materializes in HBM.  Elsewhere the
+    grid step DMAs exactly the page ``(layer, table entry)`` names — the
+    gathered [B, S] context never materializes in HBM.  Elsewhere the
     identical-math XLA gather path runs."""
+    if k_pages.ndim == 4:
+        # the same code with a unit leading dimension (a bitcast)
+        assert layer is None, "layer= indexes a [L, N, bs, H, D] pool"
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     b, h, d = q.shape
-    n, bs, hp, dp = k_pages.shape
+    n_l, n, bs, hp, dp = k_pages.shape
     assert (hp, dp) == (h, d), (q.shape, k_pages.shape)
+    assert layer is not None and 0 <= layer < n_l, (layer, k_pages.shape)
     nb = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(d)
     block_tables = block_tables.astype(jnp.int32)
     context_lens = context_lens.astype(jnp.int32)
     if not take_pallas("paged_attention", True, force_xla, interpret):
-        return _paged_attention_xla(q, k_pages, v_pages, block_tables,
-                                    context_lens, scale)
+        return _paged_attention_xla(q, k_pages, v_pages, layer,
+                                    block_tables, context_lens, scale)
     kernel = functools.partial(_paged_kernel, scale=scale,
                                block_size=bs, n_b=nb)
+    # the leading pool dimension squeezed: the kernel sees one
+    # [1, bs, H, D] page a grid step, whatever pool it came from
+    page = pl.BlockSpec((None, 1, bs, h, d),
+                        lambda bi, ki, tables, lens:
+                        (layer, tables[bi, ki], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, nb),
         in_specs=[
             pl.BlockSpec((1, h, d),
                          lambda bi, ki, tables, lens: (bi, 0, 0)),
-            pl.BlockSpec((1, bs, h, d),
-                         lambda bi, ki, tables, lens:
-                         (tables[bi, ki], 0, 0, 0)),
-            pl.BlockSpec((1, bs, h, d),
-                         lambda bi, ki, tables, lens:
-                         (tables[bi, ki], 0, 0, 0)),
+            page,
+            page,
         ],
         out_specs=pl.BlockSpec((1, h, d),
                                lambda bi, ki, tables, lens: (bi, 0, 0)),

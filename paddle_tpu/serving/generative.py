@@ -850,7 +850,8 @@ class GenerativeEngine:
                 v = v.reshape(bb, cfg.n_heads, cfg.head_dim)
                 kp = kp.at[l, blk, off].set(k)
                 vp = vp.at[l, blk, off].set(v)
-                att = paged_attention(q, kp[l], vp[l], tables, new_lens)
+                att = paged_attention(q, kp, vp, tables, new_lens,
+                                      layer=l)
                 return att.reshape(bb, cfg.d_model)
 
             for l in range(cfg.n_layers):
@@ -964,7 +965,7 @@ class GenerativeEngine:
                 v = v.reshape(s_len, cfg.n_heads, cfg.head_dim)
                 kp = kp.at[l, blk, off].set(k)
                 vp = vp.at[l, blk, off].set(v)
-                att = paged_attention(q, kp[l], vp[l], tables, lens)
+                att = paged_attention(q, kp, vp, tables, lens, layer=l)
                 return att.reshape(s_len, cfg.d_model)
 
             for l in range(cfg.n_layers):
@@ -1019,8 +1020,8 @@ class GenerativeEngine:
                     v = v.reshape(bb, cfg.n_heads, cfg.head_dim)
                     kp = kp.at[l, blk, off].set(kk)
                     vp = vp.at[l, blk, off].set(v)
-                    att = paged_attention(q, kp[l], vp[l], tables,
-                                          pos + 1)
+                    att = paged_attention(q, kp, vp, tables, pos + 1,
+                                          layer=l)
                     return att.reshape(bb, cfg.d_model)
 
                 h2 = h
@@ -1080,8 +1081,8 @@ class GenerativeEngine:
                 v = v.reshape(R, cfg.n_heads, cfg.head_dim)
                 kp = kp.at[l, blk, off].set(k)
                 vp = vp.at[l, blk, off].set(v)
-                att = paged_attention(q, kp[l], vp[l], tab_f,
-                                      pos_f + 1)
+                att = paged_attention(q, kp, vp, tab_f, pos_f + 1,
+                                      layer=l)
                 return att.reshape(R, cfg.d_model)
 
             for l in range(cfg.n_layers):

@@ -174,6 +174,42 @@ def test_paged_attention_kernel_interpret_parity():
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
 
+@pytest.mark.parametrize("layer", [0, 2, 4])
+@pytest.mark.parametrize("path", ["xla", "interpret"])
+@pytest.mark.parametrize("seed", [0, 4])
+def test_paged_attention_whole_pool_equals_layer_slice(seed, path, layer):
+    """The engine hands the kernel every layer's pool and a static
+    ``layer`` (a ``pool[l]`` in front of a Mosaic call is a copy of the
+    layer's whole pool): same floats, bit for bit, as the one-layer
+    call on ``pool[l]``."""
+    from paddle_tpu.kernels.flash_attention import paged_attention
+
+    q, one_layer, _, tables, lens = _paged_case(seed)
+    rng = np.random.RandomState(100 + seed)
+    kp = rng.randn(5, *one_layer.shape).astype(np.float32)
+    vp = rng.randn(5, *one_layer.shape).astype(np.float32)
+    kw = {"force_xla": True} if path == "xla" else {"interpret": True}
+    whole = np.asarray(paged_attention(q, kp, vp, tables, lens,
+                                       layer=layer, **kw))
+    sliced = np.asarray(paged_attention(q, kp[layer], vp[layer], tables,
+                                        lens, **kw))
+    np.testing.assert_array_equal(whole, sliced)
+    np.testing.assert_allclose(
+        whole, _paged_ref(q, kp[layer], vp[layer], tables, lens),
+        atol=1e-5)
+
+
+def test_paged_attention_layer_needs_the_whole_pool():
+    from paddle_tpu.kernels.flash_attention import paged_attention
+
+    q, kp, vp, tables, lens = _paged_case()
+    with pytest.raises(AssertionError):
+        paged_attention(q, kp, vp, tables, lens, layer=1, force_xla=True)
+    with pytest.raises(AssertionError):
+        paged_attention(q, kp[None], vp[None], tables, lens, layer=1,
+                        force_xla=True)
+
+
 # ------------------------------------------------ int8 matmul
 
 def test_quantize_weight_roundtrip_bound():

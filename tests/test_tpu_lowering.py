@@ -10,6 +10,7 @@
    kernels interpreted, so chip time is not spent finding typos.
 3. chip_smoke.py has no CPU mode.
 """
+import functools
 import importlib.util
 import json
 import os
@@ -39,16 +40,21 @@ sds = jax.ShapeDtypeStruct
 bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 
 
-def mosaic_calls(fn, *specs):
-    """Kernel names of the Mosaic custom calls in ``fn`` lowered for a
-    TPU-placed computation."""
+def tpu_module(fn, *specs):
+    """StableHLO text of ``fn`` lowered for a TPU-placed computation."""
     def placed(*args):
         with placed_on(TPU):
             return fn(*args)
 
-    text = export.export(jax.jit(placed), platforms=["tpu"])(
+    return export.export(jax.jit(placed), platforms=["tpu"])(
         *specs).mlir_module()
-    return re.findall(r'@tpu_custom_call\(.*?kernel_name = "(\w+)"', text)
+
+
+def mosaic_calls(fn, *specs):
+    """Kernel names of the Mosaic custom calls in ``fn`` lowered for a
+    TPU-placed computation."""
+    return re.findall(r'@tpu_custom_call\(.*?kernel_name = "(\w+)"',
+                      tpu_module(fn, *specs))
 
 
 # the transformer train step's attention: B16 H8 T2048 D128 bf16
@@ -92,17 +98,71 @@ def test_prefill_flash_lowers(s_len):
         q, q, q) == ["flash_fwd"]
 
 
-@pytest.mark.parametrize("b,h,d,n,bs,nb", [
-    (8, 8, 128, 256, 16, 128),     # chip_smoke decode, top block bucket
-    (1, 8, 128, 256, 16, 32),      # solo request, tight bucket
-    (4, 2, 16, 32, 8, 8),          # tiny_lm test shapes
-    (3, 4, 16, 16, 8, 4),
+@pytest.mark.parametrize("b,h,d,n,bs,nb,lead,layer", [
+    (8, 8, 128, 256, 16, 128, (), None),  # one layer's pool, no layer=
+    (1, 8, 128, 256, 16, 32, (), None),
+    (4, 2, 16, 32, 8, 8, (), None),       # tiny_lm test shapes
+    (3, 4, 16, 16, 8, 4, (), None),
+    # every layer's pool whole, as the engine hands it over
+    (16, 16, 128, 1024, 16, 128, (24,), 23),  # cgpt13b-serve-chat-c16
+    (8, 8, 128, 256, 16, 128, (2,), 0),       # chip_smoke decode
+    (1, 8, 128, 256, 16, 32, (2,), 1),        # solo request, tight bucket
+    (4, 2, 16, 32, 8, 8, (3,), 1),
 ])
-def test_paged_attention_lowers(b, h, d, n, bs, nb):
-    pages = sds((n, bs, h, d), f32)
+def test_paged_attention_lowers(b, h, d, n, bs, nb, lead, layer):
+    pages = sds((*lead, n, bs, h, d), f32)
     assert mosaic_calls(
-        paged_attention, sds((b, h, d), f32), pages, pages,
+        functools.partial(paged_attention, layer=layer),
+        sds((b, h, d), f32), pages, pages,
         sds((b, nb), i32), sds((b,), i32)) == ["_paged_kernel"]
+
+
+# one step of every kind that attends through the pages, at its
+# StepCache key; the engine below: 3 layers, 24 blocks of 8, 2 heads x 16
+PAGED_STEPS = {"_compile_decode": (4, 4),
+               "_compile_prefill_cached": (16,),
+               "_compile_propose": (4, 4, 2),
+               "_compile_verify": (4, 4, 3)}
+
+
+@pytest.mark.parametrize("compile_step", sorted(PAGED_STEPS))
+def test_engine_steps_hand_the_kernel_the_whole_pool(compile_step,
+                                                     monkeypatch):
+    """A Mosaic call takes whole buffers, so a ``pool[l]`` in front of
+    it is a copy of one layer's whole pool, 48 a decode step (54% of
+    the serving cell's device time before PR 26).  Every paged kernel
+    of every step takes the 5-D pool itself, and nothing in the module
+    has one layer's pool as its shape."""
+    from paddle_tpu.serving import GenerativeEngine, tiny_lm
+
+    cfg, params = tiny_lm(3, vocab=64, d_model=32, n_heads=2, n_layers=3,
+                          d_ff=64, block_size=8, max_blocks=4, max_batch=4)
+    eng = GenerativeEngine(cfg, params, kv_blocks=24, warm=False,
+                           prefix_cache=False, spec_k=0)
+    try:
+        # the step as _aot is handed it, before jit: traced here as
+        # placed on a TPU, which the engine's own device is not
+        monkeypatch.setattr(eng, "_aot",
+                            lambda name, step, *specs: (step, specs))
+        step, specs = getattr(eng, compile_step)(PAGED_STEPS[compile_step])
+        pool = sds(eng._kp.shape, f32)
+        text = tpu_module(
+            step, jax.tree_util.tree_map(
+                lambda a: sds(a.shape, a.dtype), eng._params),
+            pool, pool, *[sds(shape, dtype) for shape, dtype in specs])
+    finally:
+        eng.close()
+    kernels = [ln for ln in text.splitlines()
+               if "@tpu_custom_call" in ln
+               and 'kernel_name = "_paged_kernel"' in ln]
+    per_step = 2 if compile_step == "_compile_propose" else 1
+    assert len(kernels) == cfg.n_layers * per_step
+    whole = "tensor<3x24x8x2x16xf32>"
+    for ln in kernels:
+        operands = ln[ln.rindex(": (") + 3:ln.rindex(") -> ")]
+        assert operands.split(", ")[-2:] == [whole, whole], operands
+    # pool[l] is a slice to 1x24x8x2x16 and a reshape to 24x8x2x16
+    assert not re.search(r"tensor<(1x)?24x8x2x16xf32>", text)
 
 
 def test_matmul_epilogue_and_add_ln_lower():
