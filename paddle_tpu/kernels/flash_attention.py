@@ -477,50 +477,93 @@ def _paged_attention_xla(q, k_pages, v_pages, layer, block_tables,
     return out.astype(q.dtype)
 
 
-def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale, block_size, n_b):
-    """One (sequence, page) grid step of decode attention: the page the
-    block table named for this step was DMA'd into VMEM by the
-    scalar-prefetch index maps; online-softmax scratch carries across
-    the sequential page axis exactly like _flash_kernel's K tiles."""
+# VMEM the paged kernel's page buffers may take: two slots of K and of V
+_PAGED_VMEM_BUDGET = 2 << 20
+# and the most pages a chunk holds, however small a page is
+_PAGED_MAX_PAGES = 8
+
+
+def _paged_kernel(layer_ref, tables_ref, lens_ref, q_ref, k_hbm, v_hbm,
+                  o_ref, kbuf, vbuf, sems, slot_ref, *, scale, block_size,
+                  pages):
+    """One sequence a grid step.  The pools stay in HBM; the row's live
+    pages, ``cdiv(lens[bi], block_size)`` of them, come into VMEM
+    ``pages`` at a time by manual copies ``pool[layer, tables[bi, j]] ->
+    buf[slot, p]``, double-buffered over two slots: the next chunk (at a
+    row's end the next row's first) is in flight while this one is
+    reduced, as one [pages * block_size, H, D] block with one
+    online-softmax rescale.  The trip count is the row's own, so a
+    call's work follows the pages held, not the table's width; and the
+    order of a row's sums is fixed by ``pages`` alone, so its result
+    does not depend on the batch or block-count bucket around it."""
     bi = pl.program_id(0)
-    ki = pl.program_id(1)
+    layer = layer_ref[0]
+    chunk = pages * block_size
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def chunk_dma(row, c, slot, wait=False):
+        """Start, or wait for, the copies of chunk ``c`` of ``row``: one
+        a live page of K and of V.  A page past the row's length is not
+        copied: its slot keeps stale bits, masked below."""
+        n_pages = pl.cdiv(lens_ref[row], block_size)
 
+        def page_dma(p, _):
+            page = tables_ref[row, c * pages + p]
+            for hbm, buf, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                dma = pltpu.make_async_copy(
+                    hbm.at[layer, page], buf.at[slot, p], sems.at[s, slot])
+                dma.wait() if wait else dma.start()
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages - c * pages, 0, pages),
+                          page_dma, None)
+
+    @pl.when(bi == 0)
+    def _first():
+        slot_ref[0] = 0
+        chunk_dma(0, 0, 0)
+
+    slot0 = slot_ref[0]
     ctx = lens_ref[bi]
-    # pages wholly past the context are dead weight (padding rows of a
-    # bucketed decode batch point every table slot at the scratch
-    # block); skip their FLOPs, not just their probability mass
-    live = ki * block_size < ctx
+    # at least one chunk, pages or not: each row's last chunk hands the
+    # next row its first
+    n_c = jnp.maximum(pl.cdiv(ctx, chunk), 1)
+    q = q_ref[0].astype(jnp.float32) * scale           # [H, D]
+    h, d = q.shape
 
-    @pl.when(live)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale       # [H, D]
-        k = k_ref[0].astype(jnp.float32)               # [bs, H, D]
-        v = v_ref[0].astype(jnp.float32)
+    def reduce_chunk(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + c) & 1
+        more = c + 1 < n_c
+
+        @pl.when(jnp.logical_or(more, bi + 1 < pl.num_programs(0)))
+        def _next():    # this row's next chunk, else the next row's first
+            chunk_dma(jnp.where(more, bi, bi + 1),
+                      jnp.where(more, c + 1, 0), 1 - slot)
+
+        chunk_dma(bi, c, slot, wait=True)
+        k = kbuf[slot].astype(jnp.float32).reshape(chunk, h, d)
+        v = vbuf[slot].astype(jnp.float32).reshape(chunk, h, d)
         # per-head mat-vec as multiply + lane reduce: Mosaic's dot has no
-        # head-batched [H,D] x [bs,H,D] form, and one page is a handful
-        # of vregs — decode attention is bound by the page DMA, not this
-        s = (k * q[None]).sum(axis=-1, keepdims=True)  # [bs, H, 1]
-        pos = ki * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        s = jnp.where(pos < ctx, s, NEG_INF)
-        m_prev = m_ref[...]                            # [H, 1]
-        m_new = jnp.maximum(m_prev, s.max(axis=0))
-        p = jnp.exp(s - m_new[None])                   # [bs, H, 1]
+        # head-batched [H,D] x [S,H,D] form
+        s = (k * q[None]).sum(axis=-1, keepdims=True)  # [S, H, 1]
+        live = c * chunk + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0) < ctx
+        s = jnp.where(live, s, NEG_INF)
+        # V too: p is 0 there, but 0 x (stale NaN) is NaN
+        v = jnp.where(live, v, 0.0)
+        m_new = jnp.maximum(m_prev, s.max(axis=0))     # [H, 1]
+        p = jnp.exp(s - m_new[None])                   # [S, H, 1]
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=0)
-        acc_ref[...] = acc_ref[...] * alpha + (p * v).sum(axis=0)
-        m_ref[...] = m_new
+        return (m_new, l_prev * alpha + p.sum(axis=0),
+                acc * alpha + (p * v).sum(axis=0))
 
-    @pl.when(ki == n_b - 1)
-    def _done():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    _, l_sum, acc = jax.lax.fori_loop(
+        0, n_c, reduce_chunk,
+        (jnp.full((h, 1), NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32),
+         jnp.zeros((h, d), jnp.float32)))
+    slot_ref[0] = (slot0 + n_c) & 1
+    # a row of no pages: every p was exp(0), acc is 0, l is not
+    o_ref[0] = (acc / l_sum).astype(o_ref.dtype)
 
 
 @_traced("pallas.paged_attention",
@@ -537,16 +580,18 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     the layer that attends (a ``pool[layer]`` in front of the call is a
     copy of that layer's pool: a Mosaic call takes whole buffers); a
     one-layer pool [N, bs, H, D] goes without ``layer``;
-    ``block_tables`` [B, NB] int32 — per-sequence page indices (unused
-    slots may point anywhere; they are masked);
+    ``block_tables`` [B, NB] int32 — per-sequence page indices (slots
+    past a row's length may point anywhere; they are never read);
     ``context_lens`` [B] int32 — tokens of real context per sequence
-    (positions >= context_len are masked; a padding row uses 1 so its
-    softmax stays finite).
+    (positions >= context_len are masked; a padding row may give 0 or 1:
+    its output is finite either way).
 
     On TPU (or under ``interpret``) runs the Pallas kernel: the grid is
-    (sequence, page) and the block table rides scalar prefetch, so each
-    grid step DMAs exactly the page ``(layer, table entry)`` names — the
-    gathered [B, S] context never materializes in HBM.  Elsewhere the
+    the sequences, the pools stay in HBM and the block table rides
+    scalar prefetch, so each row copies exactly the pages ``(layer,
+    table entry)`` that hold its context, several a step — the gathered
+    [B, S] context never materializes in HBM, and a call's time follows
+    the pages held, not the table's width.  Elsewhere the
     identical-math XLA gather path runs."""
     if k_pages.ndim == 4:
         # the same code with a unit leading dimension (a bitcast)
@@ -556,48 +601,65 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     n_l, n, bs, hp, dp = k_pages.shape
     assert (hp, dp) == (h, d), (q.shape, k_pages.shape)
     assert layer is not None and 0 <= layer < n_l, (layer, k_pages.shape)
-    nb = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(d)
     block_tables = block_tables.astype(jnp.int32)
     context_lens = context_lens.astype(jnp.int32)
-    if not take_pallas("paged_attention", True, force_xla, interpret):
+    # Mosaic copies a page [bs, H, D] out of the HBM pool only where D
+    # fills whole lanes: a narrower head is padded to 128 there, and the
+    # slice is refused as unaligned
+    usable = interpret or d % 128 == 0
+    if not take_pallas("paged_attention", usable, force_xla, interpret):
         return _paged_attention_xla(q, k_pages, v_pages, layer,
                                     block_tables, context_lens, scale)
-    kernel = functools.partial(_paged_kernel, scale=scale,
-                               block_size=bs, n_b=nb)
-    # the leading pool dimension squeezed: the kernel sees one
-    # [1, bs, H, D] page a grid step, whatever pool it came from
-    page = pl.BlockSpec((None, 1, bs, h, d),
-                        lambda bi, ki, tables, lens:
-                        (layer, tables[bi, ki], 0, 0, 0))
+    # pages a chunk, from the page's bytes alone: never from the table's
+    # width, or a row's sums would change order with the bucket
+    page_bytes = bs * h * d * k_pages.dtype.itemsize
+    pages = max(1, min(_PAGED_MAX_PAGES,
+                       _PAGED_VMEM_BUDGET // (4 * page_bytes)))
+    return _paged_call(jnp.full((1,), layer, jnp.int32), block_tables,
+                       context_lens, q, k_pages, v_pages, scale=float(scale),
+                       pages=pages, interpret=interpret)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("scale", "pages", "interpret"))
+def _paged_call(layer, block_tables, context_lens, q, k_pages, v_pages, *,
+                scale, pages, interpret):
+    """The kernel's call, with the layer as an operand ([1] int32, scalar
+    prefetch) under an inline jit: a step's 24 layers then share one
+    trace of the kernel and one Mosaic compile (a layer closed over made
+    24 of each, seconds of every bucket's set-up), and the inlined call
+    leaves no jit of its own in the caller's program."""
+    b, h, d = q.shape
+    bs = k_pages.shape[2]
+    kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs,
+                               pages=pages)
+    row = pl.BlockSpec((1, h, d), lambda bi, *prefetched: (bi, 0, 0))
+    pool = pl.BlockSpec(memory_space=pltpu.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[
-            pl.BlockSpec((1, h, d),
-                         lambda bi, ki, tables, lens: (bi, 0, 0)),
-            page,
-            page,
-        ],
-        out_specs=pl.BlockSpec((1, h, d),
-                               lambda bi, ki, tables, lens: (bi, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
-                        pltpu.VMEM((h, 1), jnp.float32),
-                        pltpu.VMEM((h, d), jnp.float32)],
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[row, pool, pool],
+        out_specs=row,
+        scratch_shapes=[pltpu.VMEM((2, pages, bs, h, d), k_pages.dtype),
+                        pltpu.VMEM((2, pages, bs, h, d), v_pages.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        # rows in order: each hands the next its first chunk
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         # no name=: pallas would open a name scope of it, XLA names the
         # custom call after its innermost scope, and the benchmark's
         # paged_attn_roofline.serve finds this kernel as %serve_decode.N
         metadata={"kernel": "paged_attention"},
-    )(block_tables, context_lens, q, k_pages, v_pages)
+    )(layer, block_tables, context_lens, q, k_pages, v_pages)
 
 
 def flash_attention_fwd_lse(q, k, v, scale=None, causal=False,

@@ -105,6 +105,14 @@ _M_DECODE_ROWS = _metrics.counter("serve_decode_rows_total",
 _M_DECODE_SLOTS = _metrics.counter("serve_decode_slots_total",
                                    "bucket rows summed over decode "
                                    "iterations (occupancy denominator)")
+_M_DECODE_PAGES = _metrics.counter("serve_decode_pages_total",
+                                   "KV pages the live rows hold, summed "
+                                   "over decode iterations: what the "
+                                   "paged kernel has to read")
+_M_DECODE_PAGE_SLOTS = _metrics.counter(
+    "serve_decode_page_slots_total",
+    "bucket rows x bucket table width summed over decode iterations: "
+    "pages / page slots is the share of the block table that is live")
 _M_OCC_PCT = _metrics.histogram(
     "serve_decode_occupancy_pct",
     "live rows / bucket rows per decode iteration, percent",
@@ -227,6 +235,8 @@ def token_metrics_probe(iters):
         _M_DECODE_STEPS.inc()
         _M_DECODE_ROWS.inc(1)
         _M_DECODE_SLOTS.inc(1)
+        _M_DECODE_PAGES.inc(1)
+        _M_DECODE_PAGE_SLOTS.inc(1)
         _M_OCC_PCT.observe(100.0)
         # per-token
         _M_TOKENS.inc()
@@ -1274,15 +1284,17 @@ class GenerativeEngine:
         tables = np.zeros((bb, nbb), np.int32)
         lens = np.zeros(bb, np.int32)
         toks = np.zeros(bb, np.int32)
+        pages = 0
         for i, bl in enumerate(blocks_list):
             tables[i, :len(bl)] = bl
             lens[i] = lens_list[i]
             toks[i] = toks_list[i]
+            pages += len(bl)
         if sp is not None:
             bucket = "%dx%d" % key
             _tr.end(sp, args={"bucket": bucket})
             sp = _tr.begin("serve.decode", None,
-                           {"bucket": bucket, "rows": b})
+                           {"bucket": bucket, "rows": b, "pages": pages})
         with self._state_lock:
             self._kv_steps += 1
             self._kv_guard.begin("decode", self._kv_steps)
@@ -1298,6 +1310,8 @@ class GenerativeEngine:
             _M_DECODE_STEPS.inc()
             _M_DECODE_ROWS.inc(b)
             _M_DECODE_SLOTS.inc(bb)
+            _M_DECODE_PAGES.inc(pages)
+            _M_DECODE_PAGE_SLOTS.inc(bb * nbb)
             _M_OCC_PCT.observe(100.0 * b / bb)
         if sp is not None:
             _tr.end(sp)

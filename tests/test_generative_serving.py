@@ -4,6 +4,8 @@ kernel parity), int8 weight-quantized matmul parity, batcher
 token-granularity — a prefill admitted mid-decode produces
 bit-identical tokens to the same request run solo — eviction/requeue
 under block-pool exhaustion, and the serve_bench generate smoke."""
+import functools
+import importlib
 import json
 import os
 import subprocess
@@ -208,6 +210,138 @@ def test_paged_attention_layer_needs_the_whole_pool():
     with pytest.raises(AssertionError):
         paged_attention(q, kp[None], vp[None], tables, lens, layer=1,
                         force_xla=True)
+
+
+# the kernel walks a row's live pages a chunk at a time; a chunk of the
+# tiny pages below is _PAGED_MAX_PAGES pages
+PG_BS, PG_H, PG_D = 8, 2, 16
+
+
+def _chunk_tokens():
+    # (paddle_tpu.kernels.flash_attention, the attribute, is a function)
+    mod = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    return mod._PAGED_MAX_PAGES * PG_BS
+
+
+def _pool_case(lens, nb, seed=0, n_l=None, n=None):
+    """Rows of ``lens`` tokens over a pool of [bs 8, H 2, D 16] pages:
+    each row's live pages are its own, drawn anywhere in the pool; every
+    other slot of its table names a page no row holds.  Returns the pool
+    as made, and the same pool with NaN wherever the tables name no live
+    position: block 0, the pages nobody holds, the pool's tail, and the
+    last live page's positions past the row's length."""
+    rng = np.random.RandomState(seed)
+    B = len(lens)
+    held = [-(-int(x) // PG_BS) for x in lens]
+    n = n or sum(held) + 9
+    lead = () if n_l is None else (n_l,)
+    q = rng.randn(B, PG_H, PG_D).astype(np.float32)
+    kp = rng.randn(*lead, n, PG_BS, PG_H, PG_D).astype(np.float32)
+    vp = rng.randn(*lead, n, PG_BS, PG_H, PG_D).astype(np.float32)
+    ids = rng.permutation(np.arange(1, n - 4))
+    dead = np.array([0, n - 1, n - 2, n - 3, n - 4], np.int32)
+    tables = dead[rng.randint(0, len(dead), size=(B, nb))].astype(np.int32)
+    live = np.zeros((n, PG_BS), bool)
+    at = 0
+    for i in range(B):
+        tables[i, :held[i]] = ids[at:at + held[i]]
+        at += held[i]
+        flat = np.arange(held[i] * PG_BS) < int(lens[i])
+        live[tables[i, :held[i]]] = flat.reshape(held[i], PG_BS)
+    mask = live[..., None, None]
+    return (q, kp, vp, tables, np.asarray(lens, np.int32),
+            np.where(mask, kp, np.nan).astype(np.float32),
+            np.where(mask, vp, np.nan).astype(np.float32))
+
+
+def _paged_both(q, kp, vp, tables, lens, kp_nan, vp_nan, layer=None):
+    """(XLA path on the pool as made, kernel on the poisoned pool)."""
+    from paddle_tpu.kernels.flash_attention import paged_attention
+
+    ref = np.asarray(paged_attention(q, kp, vp, tables, lens, layer=layer,
+                                     force_xla=True))
+    out = np.asarray(paged_attention(q, kp_nan, vp_nan, tables, lens,
+                                     layer=layer, interpret=True))
+    return ref, out
+
+
+@pytest.mark.parametrize("length", [
+    "1", "bs-1", "bs", "bs+1", "chunk-1", "chunk", "chunk+1", "nb*bs"])
+def test_paged_kernel_reads_only_live_positions(length):
+    """At every edge of a page and of a chunk the kernel, on a pool
+    that is NaN wherever no live position lies, answers the XLA path's
+    floats on the pool as made: a dead page never reaches the result."""
+    nb, chunk = 24, _chunk_tokens()
+    n = {"1": 1, "bs-1": PG_BS - 1, "bs": PG_BS, "bs+1": PG_BS + 1,
+         "chunk-1": chunk - 1, "chunk": chunk, "chunk+1": chunk + 1,
+         "nb*bs": nb * PG_BS}[length]
+    ref, out = _paged_both(*_pool_case([n, 37, n], nb, seed=n))
+    np.testing.assert_allclose(out, ref, atol=1e-5)
+
+
+@pytest.mark.parametrize("n_l,layer", [(None, None), (5, 0), (5, 2),
+                                       (5, 4)])
+def test_paged_kernel_poisoned_pool_every_pool_form(n_l, layer):
+    chunk = _chunk_tokens()
+    lens = [1, 2 * chunk + 3, chunk, 5, chunk + 1, 3 * chunk]
+    ref, out = _paged_both(*_pool_case(lens, 24, seed=7, n_l=n_l),
+                           layer=layer)
+    np.testing.assert_allclose(out, ref, atol=1e-5)
+
+
+@pytest.mark.parametrize("pad_len", [0, 1])
+def test_paged_kernel_padding_row_is_finite(pad_len):
+    """decode_step pads a bucket with rows of length 0 (1 once the
+    step's own token counts), every table slot block 0: no page, or one,
+    must give a finite row and leave its neighbours' floats alone."""
+    from paddle_tpu.kernels.flash_attention import paged_attention
+
+    chunk = _chunk_tokens()
+    lens = [pad_len, chunk + 5, pad_len, pad_len, 11, pad_len]
+    q, kp, vp, tables, lens, kp_nan, vp_nan = _pool_case(lens, 16, seed=3)
+    pads = lens == pad_len
+    tables[pads] = 0
+    if pad_len:     # block 0 is the scratch block: written, never NaN
+        kp_nan[0], vp_nan[0] = kp[0], vp[0]
+    out = np.asarray(paged_attention(q, kp_nan, vp_nan, tables, lens,
+                                     interpret=True))
+    assert np.isfinite(out).all()
+    ref = np.asarray(paged_attention(q, kp, vp, tables, lens,
+                                     force_xla=True))
+    rows = ~pads if pad_len == 0 else np.ones_like(pads)
+    np.testing.assert_allclose(out[rows], ref[rows], atol=1e-5)
+
+
+@functools.lru_cache(maxsize=None)
+def _invariance_row(b, nb, seed):
+    """One fixed row (3 chunks less a few tokens) at a seeded place
+    among ``b`` rows of seeded lengths, its table ``nb`` wide."""
+    from paddle_tpu.kernels.flash_attention import paged_attention
+
+    rng = np.random.RandomState(seed)
+    length = 3 * _chunk_tokens() - 19
+    lens = rng.randint(1, 32 * PG_BS, size=b)
+    at = rng.randint(b)
+    lens[at] = length
+    q, kp, vp, tables, lens, _, _ = _pool_case(lens, nb, seed=seed, n=600)
+    # the same row everywhere: the query, and the pages it holds
+    fixed = np.random.RandomState(99)
+    q[at] = fixed.randn(PG_H, PG_D)
+    held = -(-length // PG_BS)
+    pages = fixed.randn(2, held, PG_BS, PG_H, PG_D).astype(np.float32)
+    kp[tables[at, :held]], vp[tables[at, :held]] = pages
+    return np.asarray(paged_attention(q, kp, vp, tables, lens,
+                                      interpret=True))[at]
+
+
+@pytest.mark.parametrize("nb", [32, 64, 128])
+@pytest.mark.parametrize("b", [1, 8, 16])
+def test_paged_kernel_row_does_not_depend_on_its_bucket(b, nb):
+    """Solo == batched == repeat rests on this: a row's floats are the
+    same bit for bit alone or among 8 or 16 rows, under a table 32, 64
+    or 128 wide, whatever its neighbours hold and wherever it sits."""
+    np.testing.assert_array_equal(_invariance_row(b, nb, seed=b + nb),
+                                  _invariance_row(1, 32, seed=0))
 
 
 # ------------------------------------------------ int8 matmul

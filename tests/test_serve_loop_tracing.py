@@ -164,6 +164,29 @@ def test_loop_spans_nest_under_the_iteration(ring, child):
                    <= p["ts_us"] + p["dur_us"] + 1.0 for p in parents), k
 
 
+def test_decode_counts_the_pages_held_against_the_page_slots(ring, server):
+    """``serve_decode_pages_total`` / ``serve_decode_page_slots_total``:
+    the share of a decode bucket's block table that names a held page,
+    which is what a kernel walking the whole table would waste; the
+    ``serve.decode`` span says the same a step."""
+    decodes = [s for s in ring if s["name"] == "serve.decode"]
+    assert decodes
+    for s in decodes:
+        rows, slots = (int(x) for x in s["args"]["bucket"].split("x"))
+        # prompts of 4 and 5 new tokens: one block of 8 a row, or two
+        assert s["args"]["rows"] <= s["args"]["pages"] <= 2 * rows
+        assert s["args"]["pages"] <= rows * slots
+    c0 = _counters()
+    server.generate("g", [1, 2, 3, 4], max_new_tokens=6).result(180)
+    d = _delta(c0)
+    # one row of 5..9 tokens: one page a step, then two; a 1-row bucket
+    steps = d["serve_decode_steps_total"]
+    assert steps == 5
+    assert steps <= d["serve_decode_pages_total"] <= 2 * steps
+    assert (d["serve_decode_page_slots_total"]
+            >= d["serve_decode_pages_total"])
+
+
 def test_a_requests_prefill_and_finishing_emit_share_its_cid(ring):
     prefills = [s for s in ring if s["name"] == "serve.prefill"]
     assert len(prefills) == 2

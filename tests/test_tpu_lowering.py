@@ -101,13 +101,12 @@ def test_prefill_flash_lowers(s_len):
 @pytest.mark.parametrize("b,h,d,n,bs,nb,lead,layer", [
     (8, 8, 128, 256, 16, 128, (), None),  # one layer's pool, no layer=
     (1, 8, 128, 256, 16, 32, (), None),
-    (4, 2, 16, 32, 8, 8, (), None),       # tiny_lm test shapes
-    (3, 4, 16, 16, 8, 4, (), None),
     # every layer's pool whole, as the engine hands it over
-    (16, 16, 128, 1024, 16, 128, (24,), 23),  # cgpt13b-serve-chat-c16
+    (16, 16, 128, 1024, 16, 64, (24,), 11),   # cgpt13b-serve-chat-c16
+    (16, 16, 128, 1024, 16, 128, (24,), 23),
+    (256, 16, 128, 1024, 16, 128, (24,), 0),  # its suffix prefill
     (8, 8, 128, 256, 16, 128, (2,), 0),       # chip_smoke decode
     (1, 8, 128, 256, 16, 32, (2,), 1),        # solo request, tight bucket
-    (4, 2, 16, 32, 8, 8, (3,), 1),
 ])
 def test_paged_attention_lowers(b, h, d, n, bs, nb, lead, layer):
     pages = sds((*lead, n, bs, h, d), f32)
@@ -117,25 +116,40 @@ def test_paged_attention_lowers(b, h, d, n, bs, nb, lead, layer):
         sds((b, nb), i32), sds((b,), i32)) == ["_paged_kernel"]
 
 
+@pytest.mark.parametrize("b,h,d,n,bs,nb,lead,layer", [
+    (4, 2, 16, 32, 8, 8, (), None),       # tiny_lm test shapes
+    (3, 4, 16, 16, 8, 4, (), None),
+    (4, 2, 16, 32, 8, 8, (3,), 1),
+    (16, 12, 64, 1024, 16, 64, (12,), 5),  # a GPT-2-small-sized head
+])
+def test_paged_attention_narrow_head_takes_xla_and_says_so(
+        b, h, d, n, bs, nb, lead, layer):
+    # a head narrower than the 128 lanes is padded to them in HBM, and
+    # libtpu's Mosaic refuses the kernel's page copy out of such a pool
+    # ("Slice shape along dimension 4 must be aligned to tiling (128)"):
+    # those shapes compile through the XLA gather
+    pages = sds((*lead, n, bs, h, d), f32)
+    before = dispatch.counts().get("paged_attention.xla", 0)
+    assert mosaic_calls(
+        functools.partial(paged_attention, layer=layer),
+        sds((b, h, d), f32), pages, pages,
+        sds((b, nb), i32), sds((b,), i32)) == []
+    assert dispatch.counts()["paged_attention.xla"] == before + 1
+
+
 # one step of every kind that attends through the pages, at its
-# StepCache key; the engine below: 3 layers, 24 blocks of 8, 2 heads x 16
+# StepCache key; the engine below: 3 layers, 24 blocks of 8, 2 heads x 128
 PAGED_STEPS = {"_compile_decode": (4, 4),
                "_compile_prefill_cached": (16,),
                "_compile_propose": (4, 4, 2),
                "_compile_verify": (4, 4, 3)}
 
 
-@pytest.mark.parametrize("compile_step", sorted(PAGED_STEPS))
-def test_engine_steps_hand_the_kernel_the_whole_pool(compile_step,
-                                                     monkeypatch):
-    """A Mosaic call takes whole buffers, so a ``pool[l]`` in front of
-    it is a copy of one layer's whole pool, 48 a decode step (54% of
-    the serving cell's device time before PR 26).  Every paged kernel
-    of every step takes the 5-D pool itself, and nothing in the module
-    has one layer's pool as its shape."""
+def paged_step_module(compile_step, monkeypatch):
+    """(config, StableHLO text) of one engine step lowered for a TPU."""
     from paddle_tpu.serving import GenerativeEngine, tiny_lm
 
-    cfg, params = tiny_lm(3, vocab=64, d_model=32, n_heads=2, n_layers=3,
+    cfg, params = tiny_lm(3, vocab=64, d_model=256, n_heads=2, n_layers=3,
                           d_ff=64, block_size=8, max_blocks=4, max_batch=4)
     eng = GenerativeEngine(cfg, params, kv_blocks=24, warm=False,
                            prefix_cache=False, spec_k=0)
@@ -146,23 +160,44 @@ def test_engine_steps_hand_the_kernel_the_whole_pool(compile_step,
                             lambda name, step, *specs: (step, specs))
         step, specs = getattr(eng, compile_step)(PAGED_STEPS[compile_step])
         pool = sds(eng._kp.shape, f32)
-        text = tpu_module(
+        return cfg, tpu_module(
             step, jax.tree_util.tree_map(
                 lambda a: sds(a.shape, a.dtype), eng._params),
             pool, pool, *[sds(shape, dtype) for shape, dtype in specs])
     finally:
         eng.close()
+
+
+@pytest.mark.parametrize("compile_step", sorted(PAGED_STEPS))
+def test_engine_steps_hand_the_kernel_the_whole_pool(compile_step,
+                                                     monkeypatch):
+    """A Mosaic call takes whole buffers, so a ``pool[l]`` in front of
+    it is a copy of one layer's whole pool, 48 a decode step (54% of
+    the serving cell's device time before PR 26).  Every paged kernel
+    of every step takes the 5-D pool itself, and nothing in the module
+    has one layer's pool as its shape."""
+    cfg, text = paged_step_module(compile_step, monkeypatch)
     kernels = [ln for ln in text.splitlines()
                if "@tpu_custom_call" in ln
                and 'kernel_name = "_paged_kernel"' in ln]
     per_step = 2 if compile_step == "_compile_propose" else 1
     assert len(kernels) == cfg.n_layers * per_step
-    whole = "tensor<3x24x8x2x16xf32>"
+    whole = "tensor<3x24x8x2x128xf32>"
     for ln in kernels:
         operands = ln[ln.rindex(": (") + 3:ln.rindex(") -> ")]
         assert operands.split(", ")[-2:] == [whole, whole], operands
-    # pool[l] is a slice to 1x24x8x2x16 and a reshape to 24x8x2x16
-    assert not re.search(r"tensor<(1x)?24x8x2x16xf32>", text)
+    # pool[l] is a slice to 1x24x8x2x128 and a reshape to 24x8x2x128
+    assert not re.search(r"tensor<(1x)?24x8x2x128xf32>", text)
+
+
+def test_decode_step_holds_one_mosaic_call_a_layer(monkeypatch):
+    """``paged_attn_roofline.serve`` is read only where every run of
+    ``jit_serve_decode`` holds exactly ``n_layers`` custom calls: a
+    second Mosaic call a layer (a kernel split in two, another kernel
+    moved into the step) would silence the metric, not fail a run."""
+    cfg, text = paged_step_module("_compile_decode", monkeypatch)
+    assert text.count("@tpu_custom_call") == cfg.n_layers
+    assert dispatch.counts().get("paged_attention.pallas", 0) > 0
 
 
 def test_matmul_epilogue_and_add_ln_lower():
@@ -303,8 +338,9 @@ def test_a_kernel_forced_to_xla_fails_the_kernel_assertion(smoke):
 def test_rehearse_serve_generate(smoke, rehearsal):
     target, meter = rehearsal
     # d_model 128 / max_batch 8: the narrowest LM whose int8 matmuls
-    # tile; 2 blocks of 16 keep every request in the top block bucket
-    lm = dict(vocab=64, d_model=128, n_heads=2, n_layers=1, d_ff=128,
+    # tile, in one head: the paged kernel wants a head of 128 lanes;
+    # 2 blocks of 16 keep every request in the top block bucket
+    lm = dict(vocab=64, d_model=128, n_heads=1, n_layers=1, d_ff=128,
               block_size=16, max_blocks=2, max_batch=8)
     rec = smoke.phase_serve_generate(target, meter, lm,
                                      (17, 18, 19, 20, 21), 3, 32)
