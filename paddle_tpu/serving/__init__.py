@@ -25,7 +25,8 @@ from .batcher import set_metrics_enabled
 from .engine import ModelEngine, bucket_ladder
 from .fleet import (FleetEndpoint, FleetWorker, LocalTransport,
                     SocketTransport)
-from .generative import GenerativeEngine, LMConfig, tiny_lm
+from .generative import GenerativeEngine
+from .lm import LMConfig, tiny_lm
 from .kv_cache import BlockPool
 from .router import FleetRouter, default_fleet_slos
 from .server import InferenceServer

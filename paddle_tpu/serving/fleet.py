@@ -165,6 +165,23 @@ def encode_migrate(head, k_bytes, v_bytes):
 
 # -- transports ---------------------------------------------------------
 
+def _kv_head(spec):
+    """A page spec (``GenerativeEngine.page_spec``) under the MigrateKV
+    header's ``kv`` keys."""
+    (n_layers, n_blocks, block_size, n_heads, head_dim), dtype = spec
+    return {"n_blocks": n_blocks, "block_size": block_size,
+            "n_layers": n_layers, "n_heads": n_heads,
+            "head_dim": head_dim, "dtype": np.dtype(dtype).name}
+
+
+def _kv_spec(kv):
+    """The ``(shape, dtype)`` of the K and of the V pages a MigrateKV
+    header's ``kv`` states."""
+    return ((int(kv["n_layers"]), int(kv["n_blocks"]),
+             int(kv["block_size"]), int(kv["n_heads"]),
+             int(kv["head_dim"])), np.dtype(kv.get("dtype", "float32")))
+
+
 def _recv_exact(sock, n):
     buf = np.empty(n, np.uint8)
     view = memoryview(buf)
@@ -433,7 +450,6 @@ class FleetWorker:
         self._slots.acquire()        # bounded admission: see flag doc
         try:
             fault_point("fleet_prefill")
-            cfg = self.engine.config
             seq = GenRequest(prompt, req["max_new"], req.get("eos"),
                              Future())
             blocks = self.engine.pool.alloc(
@@ -454,12 +470,7 @@ class FleetWorker:
                              "first": int(first),
                              "max_new": int(req["max_new"]),
                              "eos": req.get("eos")},
-                     "kv": {"n_blocks": len(blocks),
-                            "block_size": cfg.block_size,
-                            "n_layers": cfg.n_layers,
-                            "n_heads": cfg.n_heads,
-                            "head_dim": cfg.head_dim,
-                            "dtype": "float32"}}
+                     "kv": _kv_head(self.engine.page_spec(len(blocks)))}
             k_bytes, v_bytes = kp.tobytes(), vp.tobytes()
             migrate_error = dest_reply = None
             t0 = time.perf_counter()
@@ -567,22 +578,18 @@ class FleetWorker:
             req = head["req"]
             rid = req["id"]
             kv = head["kv"]
-            cfg = self.engine.config
-            if (int(kv["block_size"]) != cfg.block_size
-                    or int(kv["n_layers"]) != cfg.n_layers
-                    or int(kv["n_heads"]) != cfg.n_heads
-                    or int(kv["head_dim"]) != cfg.head_dim
-                    or kv.get("dtype", "float32") != "float32"):
+            shape, dtype = _kv_spec(kv)
+            n_blocks = shape[1]
+            want, want_dtype = self.engine.page_spec(n_blocks)
+            if shape != tuple(want) or dtype != np.dtype(want_dtype):
                 raise ValueError("migration geometry %r does not match "
                                  "this worker's engine" % (kv,))
             with self._flock:
                 if rid in self._futures:
                     _M_MIGRATE_DUP.inc()
                     return encode_call({"ok": True, "dup": True})
-            n_blocks = int(kv["n_blocks"])
-            shape = (cfg.n_layers, n_blocks, cfg.block_size,
-                     cfg.n_heads, cfg.head_dim)
-            page_bytes = int(np.prod(shape, dtype=np.int64)) * 4
+            page_bytes = int(np.prod(shape, dtype=np.int64)) \
+                * dtype.itemsize
             blocks = self.engine.pool.alloc(n_blocks)
             if blocks is None:
                 raise PoolExhausted("%s: no room for %d migrated blocks"
@@ -602,10 +609,10 @@ class FleetWorker:
                                 len(rollback)),
                         epoch=head.get("epoch"))
                 k = np.frombuffer(view[off:off + page_bytes],
-                                  np.float32).reshape(shape)
+                                  dtype).reshape(shape)
                 v = np.frombuffer(view[off + page_bytes:
                                        off + 2 * page_bytes],
-                                  np.float32).reshape(shape)
+                                  dtype).reshape(shape)
                 dest_epoch = self.engine.import_blocks(blocks, k, v)
             except BaseException:
                 if blocks is not None:

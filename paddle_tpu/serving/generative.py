@@ -1,21 +1,17 @@
-"""Token-level generative serving: paged-KV decode on the AOT path
-(ISSUE 11 tentpole).
+"""Token-level generative serving: paged-KV decode on the AOT path.
 
-PR 9's server dispatches fixed-shape single-shot requests; the
-canonical millions-of-users workload is autoregressive LLM decode.
-This module composes the ingredients already in-tree into that engine:
+The engine of the generate plane.  What it serves is the model behind
+the config object it was given (serving/lm.py: the page spec, the
+staging rule, the forward over paged rows, the forward over a fresh
+prompt, the logit layer); everything here is the engine's:
 
 - **Paged KV cache** — the tenant's K/V pages live as ONE device-
-  resident array pair ``[L, N, bs, H, d]`` (layers x blocks x
-  block_size x heads x head_dim), DONATED through every prefill/decode
-  dispatch and re-bound to the returned buffers, so cache state never
-  round-trips the host between steps — the PR 2 prepared-program
-  donation contract applied to serving state.  Host-side accounting
-  (free list, per-sequence block tables, pressure gauges) is
-  kv_cache.BlockPool; the decode-mode attention that gathers K/V
-  *through* the block table is kernels/flash_attention.paged_attention
-  (Pallas scalar-prefetch kernel on TPU, identical-math XLA gather
-  elsewhere).
+  resident array pair, shaped by the model's page spec, DONATED
+  through every prefill/decode dispatch and re-bound to the returned
+  buffers, so cache state never round-trips the host between steps —
+  the prepared-program donation contract applied to serving state.
+  Host-side accounting (free list, per-sequence block tables, pressure
+  gauges) is kv_cache.BlockPool.
 - **Prefill/decode disaggregated continuous batching** — DecodeLoop
   runs Orca iteration-level scheduling: every decode iteration
   re-decides the batch, admitting queued prefills the moment the block
@@ -27,21 +23,19 @@ This module composes the ingredients already in-tree into that engine:
   at load for the warm set, background compile on a miss with traffic
   falling to the smallest covering bucket, exactly the ModelEngine
   bucket discipline.
-- **int8 weight-quantized decode** — gated per tenant at load
-  (``quant='int8'``): the transformer's projection/MLP weights are
-  quantized with distributed/compress.py's per-chunk symmetric
-  quantizer and dequantized inside the matmul kernel's VMEM epilogue
-  (kernels/matmul_fused.matmul_int8_dequant).  Embedding and lm_head
-  stay fp32 (the standard practice — the logit layer is where
-  quantization noise turns into token flips); tools/serve_bench.py's
-  generate mode pins greedy-token parity vs fp32 over >= 64 steps.
+- **Five compiled steps over the model's two forwards** — decode
+  (paged rows at each sequence's length), verify (the same on
+  batch x (k+1) flattened rows), propose (k decodes chained in one
+  program), the suffix prefill (rows ``start + i`` of one table) and
+  prefill (the prompt forward); each adds the logit layer and a greedy
+  argmax.
 
 Greedy decode is deterministic: a request admitted into a running
 decode batch produces bit-identical tokens to the same request run
 solo (per-row math is batch-independent; pinned by
 tests/test_generative_serving.py).
 
-Two raw-decode-speed levers ride on top (ISSUE 19), both default-off:
+Two raw-decode-speed levers ride on top, both default-off:
 
 - **Copy-on-write prefix caching** (``FLAGS_serve_prefix_cache`` /
   ``load_generative(prefix_cache=True)``) — PrefixCache keeps a
@@ -80,6 +74,7 @@ from . import batcher as _batcher
 from .batcher import TokenScheduler
 from .engine import StepCache, bucket_ladder, pow2_bucket
 from .kv_cache import BlockPool
+from .lm import LMConfig, tiny_lm
 
 __all__ = ["LMConfig", "GenerativeEngine", "GenRequest", "DecodeLoop",
            "PrefixCache", "tiny_lm", "token_metrics_probe",
@@ -254,73 +249,6 @@ def spec_metrics_probe(iters):
         _M_SPEC_ACCEPTED.inc(3)
         _M_SPEC_DRAFT_US.inc(120)
         _M_SPEC_VERIFY_US.inc(300)
-
-
-# ---------------------------------------------------------------------------
-# Model definition: a small pre-LN transformer LM, pure jnp — the bench
-# and test model family (serving real checkpoints means exporting these
-# arrays; the engine only sees the params dict).
-# ---------------------------------------------------------------------------
-
-class LMConfig:
-    """Static model/runtime shape of one generative tenant."""
-
-    def __init__(self, vocab, d_model, n_heads, n_layers, d_ff,
-                 block_size=None, max_blocks=8, max_batch=None):
-        self.vocab = int(vocab)
-        self.d_model = int(d_model)
-        self.n_heads = int(n_heads)
-        self.n_layers = int(n_layers)
-        self.d_ff = int(d_ff)
-        self.block_size = int(block_size or FLAGS.serve_kv_block_size)
-        self.max_blocks = int(max_blocks)
-        self.max_batch = int(max_batch or FLAGS.serve_max_batch)
-        if self.d_model % self.n_heads:
-            raise ValueError("d_model %% n_heads != 0")
-        if self.block_size < 1 or \
-                self.block_size & (self.block_size - 1):
-            raise ValueError("block_size must be a positive power of "
-                             "two (got %d)" % self.block_size)
-        self.head_dim = self.d_model // self.n_heads
-        self.max_seq = self.max_blocks * self.block_size
-
-    def todict(self):
-        return {k: getattr(self, k) for k in
-                ("vocab", "d_model", "n_heads", "n_layers", "d_ff",
-                 "block_size", "max_blocks", "max_batch")}
-
-
-# weights quantized under quant='int8' (embed/pos/lm_head/LN stay fp32)
-_QUANT_SLOTS = ("wqkv", "wo", "w1", "w2")
-
-
-def tiny_lm(seed, vocab=256, d_model=64, n_heads=4, n_layers=2,
-            d_ff=128, **cfg_kw):
-    """(config, params) of a seeded little LM — the serve_bench/test
-    model family.  Different seeds draw different parameters, so two
-    'versions' answer distinguishably (the serve_bench classification
-    trick)."""
-    cfg = LMConfig(vocab, d_model, n_heads, n_layers, d_ff, **cfg_kw)
-    rng = np.random.RandomState(seed)
-
-    def w(*shape):
-        return (rng.randn(*shape) * 0.1).astype(np.float32)
-
-    params = {"embed": w(cfg.vocab, cfg.d_model),
-              "pos": w(cfg.max_seq, cfg.d_model),
-              "lnf.scale": np.ones(cfg.d_model, np.float32),
-              "lnf.bias": np.zeros(cfg.d_model, np.float32),
-              "lm_head": w(cfg.d_model, cfg.vocab)}
-    for l in range(cfg.n_layers):
-        p = "l%d." % l
-        params[p + "wqkv"] = w(cfg.d_model, 3 * cfg.d_model)
-        params[p + "wo"] = w(cfg.d_model, cfg.d_model)
-        params[p + "w1"] = w(cfg.d_model, cfg.d_ff)
-        params[p + "w2"] = w(cfg.d_ff, cfg.d_model)
-        for ln in ("ln1", "ln2"):
-            params[p + ln + ".scale"] = np.ones(cfg.d_model, np.float32)
-            params[p + ln + ".bias"] = np.zeros(cfg.d_model, np.float32)
-    return cfg, params
 
 
 # ---------------------------------------------------------------------------
@@ -561,15 +489,28 @@ class PrefixCache:
 # The engine: device pages + bucket-compiled prefill/decode steps
 # ---------------------------------------------------------------------------
 
+def _as_config(config):
+    """A dict is ``LMConfig``'s fields; anything else is the served
+    model itself (serving/lm.py says what the engine asks of it)."""
+    return LMConfig(**config) if isinstance(config, dict) else config
+
+
+def _greedy(logits):
+    import jax.numpy as jnp
+
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 class GenerativeEngine:
     """One generative tenant: device-resident params + KV pages +
-    StepCache ladders of AOT-compiled prefill/decode steps."""
+    StepCache ladders of AOT-compiled prefill/decode steps.  The model
+    is ``config``'s (serving/lm.py says what is asked of it); a dict
+    becomes an ``LMConfig``."""
 
     def __init__(self, config, params, quant="", kv_blocks=None,
                  name="", place=None, warm=True, prefix_cache=None,
                  spec_k=None, draft=None, _register_pool=True):
-        self.config = config if isinstance(config, LMConfig) \
-            else LMConfig(**config)
+        self.config = _as_config(config)
         self.name = name or "generative"
         self.quant = str(quant or "")
         if self.quant not in ("", "int8"):
@@ -621,7 +562,7 @@ class GenerativeEngine:
                 "load_generative(..., draft=(config, params))"
                 % self.spec_k)
         dcfg, dparams = draft
-        dcfg = dcfg if isinstance(dcfg, LMConfig) else LMConfig(**dcfg)
+        dcfg = _as_config(dcfg)
         for f in ("vocab", "block_size", "max_blocks", "max_batch"):
             if getattr(dcfg, f) != getattr(cfg, f):
                 raise ValueError(
@@ -660,35 +601,16 @@ class GenerativeEngine:
         # InferenceServer sit on the same chip
         self._dev = (place if place is not None
                      else default_place()).jax_device()
-        # params staged once; projection/MLP weights per-chunk int8
-        # under quant='int8' (compress.quantize_symmetric via
-        # matmul_fused.quantize_weight), logit layer fp32
-        self._chunks = {}
-        staged = {}
-        for k, v in params.items():
-            if self.quant == "int8" and k.split(".")[-1] in _QUANT_SLOTS:
-                from paddle_tpu.kernels.matmul_fused import quantize_weight
-                q, s, chunk = quantize_weight(v)
-                self._chunks[k] = chunk
-                staged[k] = (jax.device_put(q, self._dev),
-                             jax.device_put(s, self._dev))
-            else:
-                staged[k] = jax.device_put(
-                    np.asarray(v, np.float32), self._dev)
-        self._params = staged
-        # the paged pool: layers x blocks x block_size x heads x head_dim
-        page_shape = (cfg.n_layers, n_blocks, cfg.block_size,
-                      cfg.n_heads, cfg.head_dim)
-        self._kp = jax.device_put(np.zeros(page_shape, np.float32),
-                                  self._dev)
-        self._vp = jax.device_put(np.zeros(page_shape, np.float32),
-                                  self._dev)
+        self._params = cfg.stage(params, self.quant, self._dev)
+        shape, dtype = self.page_spec(n_blocks)
+        self._kp = jax.device_put(np.zeros(shape, dtype), self._dev)
+        self._vp = jax.device_put(np.zeros(shape, dtype), self._dev)
         self._state_lock = _san.make_lock(
             "serve.generative.state:%s" % self.name)
-        # buffer sanitizer (ISSUE 14): the page pool is donated through
-        # every prefill/decode dispatch and re-bound to the returned
-        # buffers — the guard names a read of the pool mid-dispatch or
-        # through a stale (pre-rebind) epoch (the PR 11 contract)
+        # buffer sanitizer: the page pool is donated through every
+        # prefill/decode dispatch and re-bound to the returned buffers
+        # — the guard names a read of the pool mid-dispatch or through
+        # a stale (pre-rebind) epoch
         self._kv_guard = _san.BufferEpochGuard(
             "kv_pool:%s" % self.name)
         self._kv_steps = 0
@@ -708,18 +630,15 @@ class GenerativeEngine:
             name=self.name + ".decode_logits")
         self._prefill = StepCache(self._compile_prefill,
                                   name=self.name + ".prefill")
-        # ISSUE 19 ladders (warmed only when their feature is on):
-        # suffix-only prefill for prefix-cache hits, keyed by suffix
-        # bucket; batched verify for speculative decode, keyed
-        # (batch, blocks, k+1)
+        # warmed only when their feature is on: suffix-only prefill
+        # for prefix-cache hits, keyed by suffix bucket; batched verify
+        # and the draft's fused proposal for speculative decode, keyed
+        # (batch, blocks, k+1) and (batch, blocks, k)
         self._prefill_cached = StepCache(
             self._compile_prefill_cached,
             name=self.name + ".prefill_cached")
         self._verify = StepCache(self._compile_verify,
                                  name=self.name + ".verify")
-        self._verify_logits = StepCache(
-            lambda key: self._compile_verify(key, with_logits=True),
-            name=self.name + ".verify_logits")
         self._propose = StepCache(self._compile_propose,
                                   name=self.name + ".propose")
         if warm:
@@ -733,73 +652,11 @@ class GenerativeEngine:
                                for b in self.batch_ladder])
             self._prefill.warm([(s,) for s in self.prefill_ladder])
 
-    # -- model math (shared by prefill/decode tracing) ------------------
-
-    def _mm(self, p, name, x):
-        """x @ W with the tenant's quantization gate: int8 weights run
-        the epilogue-dequant kernel (XLA-identical fallback off-TPU).
-
-        Rows are padded to a multiple of 8 (one f32 sublane tile — free
-        on the chip) so a row's result does not depend on the batch
-        bucket it rode in: XLA answers a ONE-row f32 dot exactly on the
-        vector unit but rounds the operands of a many-row dot to bf16
-        for the MXU (measured on the v5e: 0.03 absolute on O(10) values,
-        enough to flip greedy tokens between the batch-1 bucket and the
-        rest), and the int8 kernel only tiles from 8 rows up."""
-        import jax.numpy as jnp
-
-        from paddle_tpu.kernels.matmul_fused import matmul_int8_dequant
-
-        m = x.shape[0]
-        pad = -m % 8
-        if pad:
-            x = jnp.pad(x, ((0, pad), (0, 0)))
-        w = p[name]
-        if isinstance(w, tuple):
-            q, s = w
-            y = matmul_int8_dequant(x, q, s, self._chunks[name])
-        else:
-            y = jnp.dot(x, w)
-        return y[:m] if pad else y
-
-    def _block_fwd(self, p, l, h, attend):
-        """One transformer block: pre-LN attention (via ``attend``,
-        which owns QKV cache writes + attention for its mode) then the
-        pre-LN gelu MLP.
-
-        The projections and the MLP sit in ``layer/attention`` and
-        ``layer/ffn`` name scopes; ``attend`` is called outside every
-        scope: XLA names a Mosaic custom call after the innermost scope
-        around it, and the benchmark's paged_attn_roofline.serve finds
-        the decode kernel as ``%serve_decode.N`` (the kernel's own name
-        rides its ``kernel_metadata``, kernels/flash_attention.py)."""
-        import jax
-
-        pre = "l%d." % l
-        with jax.named_scope("layer"), jax.named_scope("attention"):
-            a = self._layer_norm(h, p[pre + "ln1.scale"],
-                                 p[pre + "ln1.bias"])
-            qkv = self._mm(p, pre + "wqkv", a)
-        att = attend(l, qkv)
-        with jax.named_scope("layer"):
-            with jax.named_scope("attention"):
-                h = h + self._mm(p, pre + "wo", att)
-            with jax.named_scope("ffn"):
-                m = self._layer_norm(h, p[pre + "ln2.scale"],
-                                     p[pre + "ln2.bias"])
-                h = h + self._mm(p, pre + "w2",
-                                 jax.nn.gelu(self._mm(p, pre + "w1", m),
-                                             approximate=True))
-        return h
-
-    @staticmethod
-    def _layer_norm(x, scale, bias, eps=1e-5):
-        import jax.numpy as jnp
-        from jax import lax
-
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-        return (x - mean) * lax.rsqrt(var + eps) * scale + bias
+    def page_spec(self, n_blocks):
+        """``(shape, dtype)`` of each of the K and V pools for
+        ``n_blocks`` blocks — the model's, asked here by everything
+        that allocates, installs or ships pages."""
+        return self.config.page_spec(n_blocks)
 
     # -- compiled steps -------------------------------------------------
 
@@ -836,40 +693,15 @@ class GenerativeEngine:
         paged attention over each sequence's pages, greedy next token
         out.  Pages are donated — the returned buffers REPLACE the
         engine's, nothing round-trips the host."""
-        import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.kernels.flash_attention import paged_attention
-
-        cfg = self.config
+        model = self.config
         bb, nbb = key
 
         def step(p, kp, vp, tables, lens, toks):
-            h = p["embed"][toks] + p["pos"][lens]          # [B, D]
-            new_lens = lens + 1
-            rows = jnp.arange(bb)
-            blk_slot = lens // cfg.block_size
-            blk = tables[rows, blk_slot]                   # [B]
-            off = lens % cfg.block_size
-
-            def attend(l, qkv):
-                nonlocal kp, vp
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                q = q.reshape(bb, cfg.n_heads, cfg.head_dim)
-                k = k.reshape(bb, cfg.n_heads, cfg.head_dim)
-                v = v.reshape(bb, cfg.n_heads, cfg.head_dim)
-                kp = kp.at[l, blk, off].set(k)
-                vp = vp.at[l, blk, off].set(v)
-                att = paged_attention(q, kp, vp, tables, new_lens,
-                                      layer=l)
-                return att.reshape(bb, cfg.d_model)
-
-            for l in range(cfg.n_layers):
-                h = self._block_fwd(p, l, h, attend)
-            with jax.named_scope("head"):
-                h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-                logits = self._mm(p, "lm_head", h)         # [B, V] f32
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            h, kp, vp = model.paged_forward(p, kp, vp, toks, lens, tables)
+            logits = model.head(p, h)                      # [B, V] f32
+            nxt = _greedy(logits)
             if with_logits:
                 return nxt, logits, kp, vp
             return nxt, kp, vp
@@ -882,54 +714,19 @@ class GenerativeEngine:
 
     def _compile_prefill(self, key):
         """AOT-compile one prefill at sequence bucket ``(S,)``: the
-        whole (padded) prompt forward in one dispatch — causal flash
-        attention over the in-flight K/V, every position's K/V written
-        into the sequence's blocks (pad positions redirect to the
-        reserved scratch block), greedy first token from the last real
-        position."""
-        import jax
+        whole (padded) prompt forward in one dispatch, every position's
+        K/V written into the sequence's blocks, greedy first token from
+        the last real position."""
         import jax.numpy as jnp
 
-        from paddle_tpu.kernels.flash_attention import flash_attention
-
-        cfg = self.config
+        model = self.config
         (s_len,) = key
-        n_ids = max(1, s_len // cfg.block_size)
+        n_ids = max(1, s_len // model.block_size)
 
         def step(p, kp, vp, toks, length, block_ids):
-            pos = jnp.arange(s_len, dtype=jnp.int32)
-            h = p["embed"][toks] + p["pos"][pos]           # [S, D]
-            live = pos < length
-            blk = jnp.where(live,
-                            block_ids[pos // cfg.block_size], 0)
-            off = pos % cfg.block_size
-
-            def attend(l, qkv):
-                nonlocal kp, vp
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                q = q.reshape(s_len, cfg.n_heads, cfg.head_dim)
-                k = k.reshape(s_len, cfg.n_heads, cfg.head_dim)
-                v = v.reshape(s_len, cfg.n_heads, cfg.head_dim)
-                kp = kp.at[l, blk, off].set(k)
-                vp = vp.at[l, blk, off].set(v)
-                # causal attention over the in-flight K/V (same values
-                # just written to the pages): rows < length only see
-                # columns <= their own position, all real
-                q4 = q.transpose(1, 0, 2)[None]
-                k4 = k.transpose(1, 0, 2)[None]
-                v4 = v.transpose(1, 0, 2)[None]
-                att = flash_attention(q4, k4, v4, causal=True)[0]
-                return att.transpose(1, 0, 2).reshape(s_len,
-                                                      cfg.d_model)
-
-            for l in range(cfg.n_layers):
-                h = self._block_fwd(p, l, h, attend)
-            with jax.named_scope("head"):
-                h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-                last = jnp.take(h, length - 1, axis=0)
-                logits = jnp.dot(last, p["lm_head"])       # [V]
-                nxt = jnp.argmax(logits).astype(jnp.int32)
-            return nxt, kp, vp
+            h, kp, vp = model.prompt_forward(p, kp, vp, toks, length,
+                                             block_ids)
+            return _greedy(model.head(p, h, n_live=length)), kp, vp
 
         return self._aot(
             "serve_prefill", step,
@@ -938,54 +735,27 @@ class GenerativeEngine:
             ((n_ids,), jnp.int32))
 
     def _compile_prefill_cached(self, key):
-        """AOT-compile a SUFFIX prefill at suffix bucket ``(S,)``
-        (ISSUE 19): the prompt's first ``start`` positions are already
-        resident in shared prefix blocks, so only the ``count``
-        un-cached suffix tokens run — row i is position ``start + i``,
-        its K/V written through the full block table, attention is
-        paged over the shared prefix plus the just-written suffix
-        (per-row lens ``start + i + 1`` makes causality fall out of
-        the page gather).  Greedy first token from the last real row.
-        Compute scales with the suffix, not the prompt — the entire
-        point of the cache."""
-        import jax
+        """AOT-compile a SUFFIX prefill at suffix bucket ``(S,)``: the
+        prompt's first ``start`` positions are already resident in
+        shared prefix blocks, so only the ``count`` un-cached suffix
+        tokens run — row i is position ``start + i`` of the one block
+        table, attending over the shared prefix plus the just-written
+        suffix.  Greedy first token from the last real row.  Compute
+        scales with the suffix, not the prompt — the entire point of
+        the cache."""
         import jax.numpy as jnp
 
-        from paddle_tpu.kernels.flash_attention import paged_attention
-
-        cfg = self.config
+        model = self.config
         (s_len,) = key
-        n_ids = cfg.max_blocks
+        n_ids = model.max_blocks
 
         def step(p, kp, vp, toks, start, count, block_ids):
             rows = jnp.arange(s_len, dtype=jnp.int32)
-            pos = start + rows                             # [S]
-            live = rows < count
-            h = p["embed"][toks] + p["pos"][jnp.where(live, pos, 0)]
-            blk = jnp.where(live, block_ids[pos // cfg.block_size], 0)
-            off = pos % cfg.block_size
-            tables = jnp.broadcast_to(block_ids, (s_len, n_ids))
-            lens = jnp.where(live, pos + 1, 1)
-
-            def attend(l, qkv):
-                nonlocal kp, vp
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                q = q.reshape(s_len, cfg.n_heads, cfg.head_dim)
-                k = k.reshape(s_len, cfg.n_heads, cfg.head_dim)
-                v = v.reshape(s_len, cfg.n_heads, cfg.head_dim)
-                kp = kp.at[l, blk, off].set(k)
-                vp = vp.at[l, blk, off].set(v)
-                att = paged_attention(q, kp, vp, tables, lens, layer=l)
-                return att.reshape(s_len, cfg.d_model)
-
-            for l in range(cfg.n_layers):
-                h = self._block_fwd(p, l, h, attend)
-            with jax.named_scope("head"):
-                h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-                last = jnp.take(h, count - 1, axis=0)
-                logits = jnp.dot(last, p["lm_head"])       # [V]
-                nxt = jnp.argmax(logits).astype(jnp.int32)
-            return nxt, kp, vp
+            h, kp, vp = model.paged_forward(
+                p, kp, vp, toks, start + rows,
+                jnp.broadcast_to(block_ids, (s_len, n_ids)),
+                live=rows < count)
+            return _greedy(model.head(p, h, n_live=count)), kp, vp
 
         return self._aot(
             "serve_prefill_cached", step,
@@ -997,51 +767,25 @@ class GenerativeEngine:
     def _compile_propose(self, key):
         """AOT-compile a FUSED k-step greedy decode at bucket
         ``(B, NB, k)`` — the speculative DRAFT's proposal loop in one
-        dispatch (ISSUE 19).  The k single-token steps run unrolled
-        inside one program, each feeding its argmax to the next, so
-        the per-dispatch overhead is paid once per round instead of k
+        dispatch.  The k single-token steps run unrolled inside one
+        program, each feeding its argmax to the next, so the
+        per-dispatch overhead is paid once per round instead of k
         times — on a dispatch-bound host that overhead IS most of a
         small draft's step cost.  K/V for the k positions lands in the
         pages exactly as k separate decode steps would have written
         it."""
-        import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.kernels.flash_attention import paged_attention
-
-        cfg = self.config
+        model = self.config
         bb, nbb, k = key
 
         def step(p, kp, vp, tables, lens, toks):
-            rows = jnp.arange(bb)
             cur = toks                                     # [B]
             props = []
             for j in range(k):
-                pos = lens + j
-                h = p["embed"][cur] + p["pos"][pos]
-                blk = tables[rows, pos // cfg.block_size]
-                off = pos % cfg.block_size
-
-                def attend(l, qkv, blk=blk, off=off, pos=pos):
-                    nonlocal kp, vp
-                    q, kk, v = jnp.split(qkv, 3, axis=-1)
-                    q = q.reshape(bb, cfg.n_heads, cfg.head_dim)
-                    kk = kk.reshape(bb, cfg.n_heads, cfg.head_dim)
-                    v = v.reshape(bb, cfg.n_heads, cfg.head_dim)
-                    kp = kp.at[l, blk, off].set(kk)
-                    vp = vp.at[l, blk, off].set(v)
-                    att = paged_attention(q, kp, vp, tables, pos + 1,
-                                          layer=l)
-                    return att.reshape(bb, cfg.d_model)
-
-                h2 = h
-                for l in range(cfg.n_layers):
-                    h2 = self._block_fwd(p, l, h2, attend)
-                with jax.named_scope("head"):
-                    h2 = self._layer_norm(h2, p["lnf.scale"],
-                                          p["lnf.bias"])
-                    logits = self._mm(p, "lm_head", h2)
-                    cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                h, kp, vp = model.paged_forward(p, kp, vp, cur,
+                                                lens + j, tables)
+                cur = _greedy(model.head(p, h))
                 props.append(cur)
             return jnp.stack(props, axis=1), kp, vp      # [B, k]
 
@@ -1051,60 +795,30 @@ class GenerativeEngine:
             ((bb,), jnp.int32),
             ((bb,), jnp.int32))
 
-    def _compile_verify(self, key, with_logits=False):
+    def _compile_verify(self, key):
         """AOT-compile one speculative VERIFY at bucket
-        ``(B, NB, k+1)`` (ISSUE 19): for each sequence, k+1 candidate
-        tokens (last emitted + the draft's k proposals) run as k+1
-        paged-attention rows of ONE dispatch — row (i, j) feeds
-        candidate j at position ``lens[i] + j``, writes its K/V, and
-        attends over ``lens[i] + j + 1`` positions (the earlier
-        candidates' K/V included, written this same dispatch), so the
-        greedy outputs are EXACTLY the tokens non-speculative decode
-        would have produced one iteration at a time.  Rows past a
-        mismatch write K/V the host will roll back over — attention
-        never reads past ``lens``, so stale writes are dead until
-        overwritten."""
-        import jax
+        ``(B, NB, k+1)``: for each sequence, k+1 candidate tokens (last
+        emitted + the draft's k proposals) run as k+1 paged rows of ONE
+        dispatch — row (i, j) feeds candidate j at position
+        ``lens[i] + j``, writes its K/V, and attends over
+        ``lens[i] + j + 1`` positions (the earlier candidates' K/V
+        included, written this same dispatch), so the greedy outputs
+        are EXACTLY the tokens non-speculative decode would have
+        produced one iteration at a time.  Rows past a mismatch write
+        K/V the host will roll back over — attention never reads past
+        ``lens``, so stale writes are dead until overwritten."""
         import jax.numpy as jnp
 
-        from paddle_tpu.kernels.flash_attention import paged_attention
-
-        cfg = self.config
+        model = self.config
         bb, nbb, k1 = key
-        R = bb * k1
 
         def step(p, kp, vp, tables, lens, toks):
             pos = (lens[:, None]
                    + jnp.arange(k1, dtype=jnp.int32)[None, :])  # [B,k1]
-            pos_f = pos.reshape(R)
-            toks_f = toks.reshape(R)
-            h = p["embed"][toks_f] + p["pos"][pos_f]       # [R, D]
-            tab_f = jnp.repeat(tables, k1, axis=0)         # [R, NB]
-            blk = tab_f[jnp.arange(R), pos_f // cfg.block_size]
-            off = pos_f % cfg.block_size
-
-            def attend(l, qkv):
-                nonlocal kp, vp
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                q = q.reshape(R, cfg.n_heads, cfg.head_dim)
-                k = k.reshape(R, cfg.n_heads, cfg.head_dim)
-                v = v.reshape(R, cfg.n_heads, cfg.head_dim)
-                kp = kp.at[l, blk, off].set(k)
-                vp = vp.at[l, blk, off].set(v)
-                att = paged_attention(q, kp, vp, tab_f, pos_f + 1,
-                                      layer=l)
-                return att.reshape(R, cfg.d_model)
-
-            for l in range(cfg.n_layers):
-                h = self._block_fwd(p, l, h, attend)
-            with jax.named_scope("head"):
-                h = self._layer_norm(h, p["lnf.scale"], p["lnf.bias"])
-                logits = self._mm(p, "lm_head", h)         # [R, V]
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt = nxt.reshape(bb, k1)
-            if with_logits:
-                return nxt, logits.reshape(bb, k1, cfg.vocab), kp, vp
-            return nxt, kp, vp
+            h, kp, vp = model.paged_forward(
+                p, kp, vp, toks.reshape(bb * k1), pos.reshape(bb * k1),
+                jnp.repeat(tables, k1, axis=0))            # [B*k1, NB]
+            return _greedy(model.head(p, h)).reshape(bb, k1), kp, vp
 
         return self._aot(
             "serve_verify", step,
@@ -1113,6 +827,41 @@ class GenerativeEngine:
             ((bb, k1), jnp.int32))
 
     # -- dispatch -------------------------------------------------------
+
+    def _dispatch(self, op, run):
+        """The bracket around everything that donates the page pool:
+        under the state lock, count the step, open the epoch guard,
+        ``run(k_pages, v_pages)``, re-bind the pools to the last two
+        of what it returns and close the guard.  Returns the rest and
+        the epoch the re-bind left."""
+        with self._state_lock:
+            self._kv_steps += 1
+            self._kv_guard.begin(op, self._kv_steps)
+            *out, self._kp, self._vp = run(self._kp, self._vp)
+            self._kv_guard.rebind()
+            return out, self._kv_guard.epoch
+
+    def _stage_rows(self, cache, blocks_list, lens_list, *tail):
+        """Pick ``cache``'s ``(batch, block-count) + tail`` bucket for
+        these rows and pad their block tables and lengths up to it
+        (padding rows aim every table slot at the reserved scratch
+        block).  Returns ``(key, exe, tables, lens, pages held)``."""
+        cfg = self.config
+        b = len(blocks_list)
+        nb = max(len(bl) for bl in blocks_list)
+        key, exe = cache.pick((pow2_bucket(b, cfg.max_batch),
+                               pow2_bucket(nb, self.nb_top)) + tail)
+        if key[2:] != tail:
+            raise RuntimeError("%s bucket %r does not end in %r"
+                               % (cache.name, key, tail))
+        tables = np.zeros(key[:2], np.int32)
+        lens = np.zeros(key[0], np.int32)
+        lens[:b] = lens_list
+        pages = 0
+        for i, bl in enumerate(blocks_list):
+            tables[i, :len(bl)] = bl
+            pages += len(bl)
+        return key, exe, tables, lens, pages
 
     def prefill(self, seq, _tr=None):
         """Run ``seq``'s prompt through the prefill bucket that fits it;
@@ -1131,89 +880,60 @@ class GenerativeEngine:
             raise ValueError("prompt length %d exceeds max_seq %d "
                              "(block_size x max_blocks)" % (n, cfg.max_seq))
         cached = getattr(seq, "cached_len", 0)
-        cid = getattr(seq, "rid", None)
-        if self.prefix_cache is not None and 0 < cached < n:
-            tok = self._prefill_suffix(seq.prompt, seq.blocks, cached,
-                                       _tr, cid)
-        else:
-            tok = self.prefill_tokens(seq.prompt, seq.blocks, _tr, cid)
+        if self.prefix_cache is None or not 0 < cached < n:
+            cached = 0
+        tok = self.prefill_tokens(seq.prompt, seq.blocks, _tr,
+                                  getattr(seq, "rid", None), start=cached)
         seq.context_len = n
         if _batcher._METRICS_ON:
             _M_PREFILLS.inc()
         return tok
 
-    def prefill_tokens(self, tokens, blocks, _tr=None, _cid=None):
-        """Raw full prefill: write K/V for every position of ``tokens``
-        into ``blocks`` and return the greedy next token.  No sequence
-        bookkeeping — the speculative draft's re-prefill path uses this
-        directly (its catch-up feed is tokens, not a GenRequest).
-        ``serve.prefill`` spans staging, dispatch and the wait for the
-        token; its child ``serve.prefill.dispatch`` the dispatch."""
+    def prefill_tokens(self, tokens, blocks, _tr=None, _cid=None,
+                       start=0):
+        """Raw prefill: write K/V for positions ``start..n-1`` of
+        ``tokens`` into ``blocks`` and return the greedy next token.
+        ``start`` > 0 is a prefix-cache hit: the first ``start``
+        positions are read through the pages, and the bucket is picked
+        by SUFFIX length — an 80%-cached prompt runs a bucket ~5x
+        narrower than its full prefill.  No sequence bookkeeping — the
+        speculative draft's re-prefill path uses this directly (its
+        catch-up feed is tokens, not a GenRequest).  ``serve.prefill``
+        spans staging, dispatch and the wait for the token; its child
+        ``serve.prefill.dispatch`` the dispatch."""
         cfg = self.config
         n = len(tokens)
-        sp = (_tr.begin("serve.prefill", _cid, {"tokens": n})
+        count = n - start
+        if start:
+            cache, op = self._prefill_cached, "prefill_cached"
+            span_args = {"tokens": n, "cached": start}
+        else:
+            cache, op, span_args = self._prefill, "prefill", {"tokens": n}
+        sp = (_tr.begin("serve.prefill", _cid, span_args)
               if _tr is not None else None)
-        want = pow2_bucket(max(n, cfg.block_size), cfg.max_seq)
-        key, exe = self._prefill.pick((want,))
+        want = pow2_bucket(max(count, cfg.block_size), cfg.max_seq)
+        key, exe = cache.pick((want,))
         (s_len,) = key
         toks = np.zeros(s_len, np.int32)
-        toks[:n] = tokens
-        ids = np.zeros(max(1, s_len // cfg.block_size), np.int32)
-        # the sequence may hold MORE blocks than the bucket's table
-        # slots (growth provisions ahead for speculative rounds); the
-        # extras hold positions past this prefill's writes
-        m = min(len(blocks), len(ids))
+        toks[:count] = tokens[start:]
+        if start:   # reads the whole table, through the cached prefix
+            n_ids, lead = cfg.max_blocks, (np.int32(start), np.int32(count))
+        else:       # writes its bucket's blocks
+            n_ids, lead = max(1, s_len // cfg.block_size), (np.int32(n),)
+        # the sequence may hold MORE blocks than the table's slots
+        # (growth provisions ahead for speculative rounds); the extras
+        # hold positions past this prefill's writes
+        ids = np.zeros(n_ids, np.int32)
+        m = min(len(blocks), n_ids)
         ids[:m] = blocks[:m]
         spd = (_tr.begin("serve.prefill.dispatch", _cid)
                if sp is not None else None)
-        with self._state_lock:
-            self._kv_steps += 1
-            self._kv_guard.begin("prefill", self._kv_steps)
-            nxt, kp, vp = exe(self._params, self._kp, self._vp,
-                              toks, np.int32(n), ids)
-            self._kp, self._vp = kp, vp
-            self._kv_guard.rebind()
+        (nxt,), _ = self._dispatch(
+            op, lambda kp, vp: exe(self._params, kp, vp, toks, *lead, ids))
         if sp is None:
             return int(nxt)
         _tr.end(spd)
         tok = int(nxt)      # blocks until the device has the token
-        _tr.end(sp, args={"bucket": s_len})
-        return tok
-
-    def _prefill_suffix(self, tokens, blocks, start, _tr=None,
-                        _cid=None):
-        """Suffix-only prefill (prefix-cache hit): positions
-        ``start..n-1`` computed and written, the cached prefix read
-        through the page gather.  Bucketed by SUFFIX length — an
-        80%-cached prompt runs a bucket ~5x narrower than its full
-        prefill.  Spanned like ``prefill_tokens``, with ``cached``."""
-        cfg = self.config
-        n = len(tokens)
-        count = n - start
-        sp = (_tr.begin("serve.prefill", _cid,
-                        {"tokens": n, "cached": start})
-              if _tr is not None else None)
-        want = pow2_bucket(max(count, cfg.block_size), cfg.max_seq)
-        key, exe = self._prefill_cached.pick((want,))
-        (s_len,) = key
-        toks = np.zeros(s_len, np.int32)
-        toks[:count] = tokens[start:]
-        ids = np.zeros(cfg.max_blocks, np.int32)
-        ids[:len(blocks)] = blocks
-        spd = (_tr.begin("serve.prefill.dispatch", _cid)
-               if sp is not None else None)
-        with self._state_lock:
-            self._kv_steps += 1
-            self._kv_guard.begin("prefill_cached", self._kv_steps)
-            nxt, kp, vp = exe(self._params, self._kp, self._vp,
-                              toks, np.int32(start), np.int32(count),
-                              ids)
-            self._kp, self._vp = kp, vp
-            self._kv_guard.rebind()
-        if sp is None:
-            return int(nxt)
-        _tr.end(spd)
-        tok = int(nxt)
         _tr.end(sp, args={"bucket": s_len})
         return tok
 
@@ -1231,18 +951,13 @@ class GenerativeEngine:
                         vp.at[:, d].set(vp[:, s]))
 
             self._copy_jit = jax.jit(_cp, donate_argnums=(0, 1))
-        with self._state_lock:
-            self._kv_steps += 1
-            self._kv_guard.begin("cow", self._kv_steps)
-            self._kp, self._vp = self._copy_jit(
-                self._kp, self._vp, np.int32(src), np.int32(dst))
-            self._kv_guard.rebind()
+        self._dispatch("cow", lambda kp, vp: self._copy_jit(
+            kp, vp, np.int32(src), np.int32(dst)))
 
     def decode(self, seqs, with_logits=False, _tr=None, _times=None):
         """One decode iteration over the running sequences: picks the
         ``(batch, block-count)`` bucket, pads the block tables /
-        context lengths / last tokens up to it (padding rows aim every
-        table slot at the reserved scratch block), dispatches, and
+        context lengths / last tokens up to it, dispatches, and
         returns the next token per live sequence.  ``_tr`` / ``_times``
         are DecodeLoop's: the tracer where spans are live and the
         iteration's phase clock (``decode_step``)."""
@@ -1271,41 +986,23 @@ class GenerativeEngine:
         ``serve.decode.wait`` (the read-back, blocked on the device).
         ``_times`` gets the time up to the read-back as host time and
         the read-back as wait."""
-        cfg = self.config
         sp = (_tr.begin("serve.decode.stage")
               if _tr is not None else None)
         b = len(blocks_list)
-        nb = max(len(bl) for bl in blocks_list)
-        want = (pow2_bucket(b, cfg.max_batch),
-                pow2_bucket(nb, self.nb_top))
-        cache = self._decode_logits if with_logits else self._decode
-        key, exe = cache.pick(want)
+        key, exe, tables, lens, pages = self._stage_rows(
+            self._decode_logits if with_logits else self._decode,
+            blocks_list, lens_list)
         bb, nbb = key
-        tables = np.zeros((bb, nbb), np.int32)
-        lens = np.zeros(bb, np.int32)
         toks = np.zeros(bb, np.int32)
-        pages = 0
-        for i, bl in enumerate(blocks_list):
-            tables[i, :len(bl)] = bl
-            lens[i] = lens_list[i]
-            toks[i] = toks_list[i]
-            pages += len(bl)
+        toks[:b] = toks_list
         if sp is not None:
             bucket = "%dx%d" % key
             _tr.end(sp, args={"bucket": bucket})
             sp = _tr.begin("serve.decode", None,
                            {"bucket": bucket, "rows": b, "pages": pages})
-        with self._state_lock:
-            self._kv_steps += 1
-            self._kv_guard.begin("decode", self._kv_steps)
-            outs = exe(self._params, self._kp, self._vp,
-                       tables, lens, toks)
-            if with_logits:
-                nxt, logits, kp, vp = outs
-            else:
-                nxt, kp, vp = outs
-            self._kp, self._vp = kp, vp
-            self._kv_guard.rebind()
+        (nxt, *logits), _ = self._dispatch(
+            "decode", lambda kp, vp: exe(self._params, kp, vp,
+                                         tables, lens, toks))
         if _count and _batcher._METRICS_ON:
             _M_DECODE_STEPS.inc()
             _M_DECODE_ROWS.inc(b)
@@ -1322,7 +1019,7 @@ class GenerativeEngine:
             _times.mark = now
         nxt = np.asarray(nxt)[:b]
         if with_logits:
-            logits = np.asarray(logits)[:b]
+            logits = np.asarray(logits[0])[:b]
         if _times is not None:
             now = _clock_ns()
             _times.wait += now - _times.mark
@@ -1333,84 +1030,45 @@ class GenerativeEngine:
             return nxt, logits
         return nxt
 
-    # -- speculative decoding (ISSUE 19 tentpole b) ---------------------
+    # -- speculative decoding -------------------------------------------
 
     def propose_step(self, blocks_list, lens_list, toks_list, k):
         """Fused k-token greedy proposal over parallel lists: ONE
         dispatch runs k chained decode steps (the draft's propose
         phase), returning the [B, k] proposed tokens.  Callers own
         length accounting (each row's pages gained k positions)."""
-        cfg = self.config
         b = len(blocks_list)
-        nb = max(len(bl) for bl in blocks_list)
-        want = (pow2_bucket(b, cfg.max_batch),
-                pow2_bucket(nb, self.nb_top), int(k))
-        key, exe = self._propose.pick(want)
-        bb, nbb, kk = key
-        if kk != k:
-            raise RuntimeError("propose bucket k mismatch (%d != %d)"
-                               % (kk, k))
-        tables = np.zeros((bb, nbb), np.int32)
-        lens = np.zeros(bb, np.int32)
-        toks = np.zeros(bb, np.int32)
-        for i, bl in enumerate(blocks_list):
-            tables[i, :len(bl)] = bl
-            lens[i] = lens_list[i]
-            toks[i] = toks_list[i]
+        key, exe, tables, lens, _ = self._stage_rows(
+            self._propose, blocks_list, lens_list, int(k))
+        toks = np.zeros(key[0], np.int32)
+        toks[:b] = toks_list
         with TRACER.span("serve.propose",
                          args={"bucket": "%dx%dx%d" % key, "rows": b}):
-            with self._state_lock:
-                self._kv_steps += 1
-                self._kv_guard.begin("propose", self._kv_steps)
-                props, kp, vp = exe(self._params, self._kp, self._vp,
-                                    tables, lens, toks)
-                self._kp, self._vp = kp, vp
-                self._kv_guard.rebind()
+            (props,), _ = self._dispatch(
+                "propose", lambda kp, vp: exe(self._params, kp, vp,
+                                              tables, lens, toks))
         return np.asarray(props)[:b]
 
-    def verify_step(self, seqs, props, with_logits=False):
+    def verify_step(self, seqs, props):
         """ONE batched target dispatch verifying ``props`` (the
         draft's [B, k] proposals): row (i, j) of the ``(batch, blocks,
         k+1)`` bucket runs candidate j of sequence i.  Returns the
-        [B, k+1] greedy tokens (and fp32 logits for the parity
-        certificate) — acceptance is the caller's (``spec_decode``)."""
-        cfg = self.config
+        [B, k+1] greedy tokens — acceptance is the caller's
+        (``spec_decode``)."""
         b = len(seqs)
         k1 = int(props.shape[1]) + 1
-        nb = max(len(s.blocks) for s in seqs)
-        want = (pow2_bucket(b, cfg.max_batch),
-                pow2_bucket(nb, self.nb_top), k1)
-        cache = self._verify_logits if with_logits else self._verify
-        key, exe = cache.pick(want)
-        bb, nbb, kk1 = key
-        if kk1 != k1:
-            raise RuntimeError("verify bucket k+1 mismatch (%d != %d)"
-                               % (kk1, k1))
-        tables = np.zeros((bb, nbb), np.int32)
-        lens = np.zeros(bb, np.int32)
-        toks = np.zeros((bb, k1), np.int32)
-        for i, s in enumerate(seqs):
-            tables[i, :len(s.blocks)] = s.blocks
-            lens[i] = s.context_len
-            toks[i, 0] = s.out[-1] if s.out else s.prompt[-1]
-            toks[i, 1:] = props[i]
+        key, exe, tables, lens, _ = self._stage_rows(
+            self._verify, [s.blocks for s in seqs],
+            [s.context_len for s in seqs], k1)
+        toks = np.zeros((key[0], k1), np.int32)
+        toks[:b, 0] = [s.out[-1] if s.out else s.prompt[-1] for s in seqs]
+        toks[:b, 1:] = props
         with TRACER.span("serve.verify",
                          args={"bucket": "%dx%dx%d" % key, "rows": b}):
-            with self._state_lock:
-                self._kv_steps += 1
-                self._kv_guard.begin("verify", self._kv_steps)
-                outs = exe(self._params, self._kp, self._vp,
-                           tables, lens, toks)
-                if with_logits:
-                    nxt, logits, kp, vp = outs
-                else:
-                    nxt, kp, vp = outs
-                self._kp, self._vp = kp, vp
-                self._kv_guard.rebind()
-        nxt = np.asarray(nxt)[:b]
-        if with_logits:
-            return nxt, np.asarray(logits)[:b]
-        return nxt
+            (nxt,), _ = self._dispatch(
+                "verify", lambda kp, vp: exe(self._params, kp, vp,
+                                             tables, lens, toks))
+        return np.asarray(nxt)[:b]
 
     def spec_decode(self, seqs, _times=None):
         """One speculative round over the running set: catch the draft
@@ -1530,7 +1188,7 @@ class GenerativeEngine:
     def export_blocks(self, blocks):
         """Host copies of the K/V pages behind ``blocks`` plus the
         epoch they were read at: ``(k_pages, v_pages, epoch)``, each
-        page array shaped ``[L, len(blocks), bs, H, d]`` fp32.  Taken
+        page array ``page_spec(len(blocks))``'s.  Taken
         under the state lock with a guard check, so an export racing an
         in-flight donating dispatch is a named BufferLifetimeError (the
         MigrateKV race check), never a copy of donated buffers.  The
@@ -1553,21 +1211,19 @@ class GenerativeEngine:
     def import_blocks(self, blocks, k_pages, v_pages):
         """Install migrated K/V pages into ``blocks`` (already
         allocated from this engine's pool by the caller).  Shapes must
-        be exactly ``[L, len(blocks), bs, H, d]`` — a mismatch trips
-        the buffer sanitizer by name instead of scattering garbage into
+        be exactly ``page_spec(len(blocks))``'s — a mismatch trips the
+        buffer sanitizer by name instead of scattering garbage into
         live pages.  The write is bracketed begin/rebind like a
         dispatch, so a concurrent ``kv_pages()`` reader sees either the
         pre- or post-migration epoch, never a half-installed pool.
         Returns the post-install epoch (the MigrateKV handshake
         value)."""
-        cfg = self.config
         ids = np.asarray([int(b) for b in blocks], np.int32)
         if (ids == 0).any():
             raise ValueError("cannot import into reserved block 0")
-        want = (cfg.n_layers, len(ids), cfg.block_size,
-                cfg.n_heads, cfg.head_dim)
-        k_pages = np.asarray(k_pages, np.float32)
-        v_pages = np.asarray(v_pages, np.float32)
+        want, dtype = self.page_spec(len(ids))
+        k_pages = np.asarray(k_pages, dtype)
+        v_pages = np.asarray(v_pages, dtype)
         if k_pages.shape != want or v_pages.shape != want:
             _san.trip("kv_pool:%s" % self.name, op="migrate_in",
                       site="import_blocks: page shape %r/%r != %r "
@@ -1586,13 +1242,10 @@ class GenerativeEngine:
 
             self._scatter_jit = jax.jit(_scatter,
                                         donate_argnums=(0, 1))
-        with self._state_lock:
-            self._kv_steps += 1
-            self._kv_guard.begin("migrate_in", self._kv_steps)
-            self._kp, self._vp = self._scatter_jit(
-                self._kp, self._vp, ids, k_pages, v_pages)
-            self._kv_guard.rebind()
-            return self._kv_guard.epoch
+        _, epoch = self._dispatch(
+            "migrate_in", lambda kp, vp: self._scatter_jit(
+                kp, vp, ids, k_pages, v_pages))
+        return epoch
 
     def warm_role(self, role):
         """Warm only one side of the ladder — a fleet worker
@@ -1634,8 +1287,7 @@ class GenerativeEngine:
     def drain_compiles(self):
         """Join every in-flight background bucket compile."""
         for cache in (self._decode, self._decode_logits, self._prefill,
-                      self._prefill_cached, self._verify,
-                      self._verify_logits, self._propose):
+                      self._prefill_cached, self._verify, self._propose):
             cache.drain()
 
     def close(self):

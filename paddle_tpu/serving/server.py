@@ -167,7 +167,7 @@ class InferenceServer:
                         spec_k=None, draft=None):
         """Load a generative (autoregressive decode) tenant: a
         GenerativeEngine built from ``(config, params)`` — e.g.
-        ``generative.tiny_lm`` output — with int8 weight quantization
+        ``lm.tiny_lm`` output — with int8 weight quantization
         gated per tenant via ``quant='int8'``.  Requests go through
         ``generate()``; the tenant runs token-level continuous batching
         (serving/generative.py), not the predict dispatcher.

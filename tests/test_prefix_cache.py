@@ -117,7 +117,7 @@ def test_cow_write_isolation():
         np.testing.assert_array_equal(v_a, v_b)
         # ...and a write into B's block leaves A's pages untouched
         before = eng.export_blocks([a.blocks[1]])[0]
-        eng._prefill_suffix(b.prompt, b.blocks, 12)
+        eng.prefill_tokens(b.prompt, b.blocks, start=12)
         after = eng.export_blocks([a.blocks[1]])[0]
         np.testing.assert_array_equal(before, after)
         eng.pool.free(a.blocks)
