@@ -16,7 +16,11 @@ prompt, the logit layer); everything here is the engine's:
   runs Orca iteration-level scheduling: every decode iteration
   re-decides the batch, admitting queued prefills the moment the block
   pool can hold them (batcher.TokenScheduler policy) and retiring
-  finished sequences without stalling their neighbours.  Prefill is a
+  finished sequences without stalling their neighbours.  One decode
+  step is always in flight: where the next step runs over the same
+  rows it is dispatched on the device tokens of the one before, ahead
+  of their read, so the device does not wait for the host between
+  steps (``DecodeLoop``).  Prefill is a
   separate dispatch (its own power-of-2 sequence-length bucket ladder);
   decode batches compile per power-of-2 ``(batch, block-count)`` bucket
   on the engine.StepCache ladder — ahead-of-time ``lower().compile()``
@@ -94,6 +98,11 @@ _M_ITL = _metrics.histogram("serve_itl_ms",
                             "after the first)")
 _M_DECODE_STEPS = _metrics.counter("serve_decode_steps_total",
                                    "decode iterations dispatched")
+_M_DECODE_AHEAD = _metrics.counter(
+    "serve_decode_ahead_total",
+    "decode iterations dispatched while the one before was unread: "
+    "their input tokens never left the device (ahead / steps is the "
+    "share of steps whose dispatch the device did not wait for)")
 _M_DECODE_ROWS = _metrics.counter("serve_decode_rows_total",
                                   "live sequences summed over decode "
                                   "iterations")
@@ -210,7 +219,7 @@ def token_metrics_probe(iters):
     times = _LoopTimes()
     for _ in range(iters):
         # per-iteration (worst case: one live row): the dead-path
-        # sites of DecodeLoop._iterate and decode_step — one liveness
+        # sites of DecodeLoop._iterate and decode_read — one liveness
         # check, four clock reads (start, around the wait, end), the
         # phase adds, a flush every _FLUSH_NS of (here, 0.2-ms)
         # iterations
@@ -228,6 +237,7 @@ def token_metrics_probe(iters):
         if times.wall >= _FLUSH_NS:
             times.flush()
         _M_DECODE_STEPS.inc()
+        _M_DECODE_AHEAD.inc()
         _M_DECODE_ROWS.inc(1)
         _M_DECODE_SLOTS.inc(1)
         _M_DECODE_PAGES.inc(1)
@@ -499,6 +509,27 @@ def _greedy(logits):
     import jax.numpy as jnp
 
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+class _DecodeFlight:
+    """A dispatched decode step whose tokens the host has not read:
+    ``nxt`` is the executable's ``(bb,)`` int32 output on the engine's
+    device (``logits`` its ``[bb, V]`` one, where asked for), ``b`` the
+    live rows of the ``bb`` the batch bucket holds.  ``host`` is the
+    read-back once ``read`` has made it."""
+
+    __slots__ = ("nxt", "logits", "b", "bb", "host")
+
+    def __init__(self, nxt, logits, b, bb):
+        self.nxt, self.logits, self.b, self.bb = nxt, logits, b, bb
+        self.host = None
+
+    def read(self):
+        """The live rows' tokens on the host; the first call blocks
+        until the device has them."""
+        if self.host is None:
+            self.host = np.asarray(self.nxt)[:self.b]
+        return self.host
 
 
 class GenerativeEngine:
@@ -954,23 +985,28 @@ class GenerativeEngine:
         self._dispatch("cow", lambda kp, vp: self._copy_jit(
             kp, vp, np.int32(src), np.int32(dst)))
 
-    def decode(self, seqs, with_logits=False, _tr=None, _times=None):
-        """One decode iteration over the running sequences: picks the
-        ``(batch, block-count)`` bucket, pads the block tables /
-        context lengths / last tokens up to it, dispatches, and
-        returns the next token per live sequence.  ``_tr`` / ``_times``
-        are DecodeLoop's: the tracer where spans are live and the
-        iteration's phase clock (``decode_step``)."""
-        out = self.decode_step(
-            [s.blocks for s in seqs],
-            [s.context_len for s in seqs],
-            [s.out[-1] if s.out else (s.prompt[-1] if s.prompt else 0)
-             for s in seqs],
-            with_logits=with_logits, _count=True, _tr=_tr,
-            _times=_times)
+    def decode(self, seqs, with_logits=False):
+        """One decode iteration over the running sequences, dispatched
+        and read: the next token per live sequence."""
+        return self.decode_read(
+            self.decode_start(seqs, with_logits=with_logits))
+
+    def decode_start(self, seqs, after=None, with_logits=False, _tr=None):
+        """Dispatch one decode iteration over ``seqs`` and advance their
+        contexts; the tokens stay on the device behind the returned
+        flight (``decode_read``).  ``after`` is the unread flight of the
+        step before, over these sequences in this order: its tokens are
+        this step's input where they are.  Without it the input is each
+        sequence's last token as the host knows it."""
+        toks = after if after is not None else [
+            s.out[-1] if s.out else (s.prompt[-1] if s.prompt else 0)
+            for s in seqs]
+        flight = self.decode_dispatch(
+            [s.blocks for s in seqs], [s.context_len for s in seqs],
+            toks, with_logits=with_logits, _count=True, _tr=_tr)
         for s in seqs:
             s.context_len += 1
-        return out
+        return flight
 
     def decode_step(self, blocks_list, lens_list, toks_list,
                     with_logits=False, _count=False, _tr=None,
@@ -980,12 +1016,31 @@ class GenerativeEngine:
         accounting — the speculative draft's propose/catch-up steps
         run the draft engine through this.
 
-        Three phases, each a span where ``_tr`` is given:
-        ``serve.decode.stage`` (bucket pick, tables/lens/toks),
-        ``serve.decode`` (the dispatch under the state lock) and
-        ``serve.decode.wait`` (the read-back, blocked on the device).
-        ``_times`` gets the time up to the read-back as host time and
-        the read-back as wait."""
+        ``decode_dispatch`` then ``decode_read``, so it blocks until
+        the device has the tokens.  DecodeLoop calls the two halves
+        itself and dispatches the next step between them."""
+        return self.decode_read(
+            self.decode_dispatch(blocks_list, lens_list, toks_list,
+                                 with_logits=with_logits, _count=_count,
+                                 _tr=_tr),
+            _tr=_tr, _times=_times)
+
+    def decode_dispatch(self, blocks_list, lens_list, toks,
+                        with_logits=False, _count=False, _tr=None):
+        """Stage and dispatch one decode step; returns its
+        ``_DecodeFlight`` without waiting for the device.  Two phases,
+        each a span where ``_tr`` is given: ``serve.decode.stage``
+        (bucket pick, tables/lens/toks) and ``serve.decode`` (the
+        dispatch under the state lock; ``ahead`` says where the input
+        tokens were).
+
+        ``toks`` is one token a row from the host, or the flight of the
+        step before over the same rows in the same order.  An unread
+        flight of this step's batch bucket hands its device tokens to
+        the executable as they are (the ``(bb,)`` int32 output is the
+        ``toks`` operand's own shape and device): the host never sees
+        them in between, so this dispatch does not wait for that step.
+        Any other flight is read here first."""
         sp = (_tr.begin("serve.decode.stage")
               if _tr is not None else None)
         b = len(blocks_list)
@@ -993,18 +1048,28 @@ class GenerativeEngine:
             self._decode_logits if with_logits else self._decode,
             blocks_list, lens_list)
         bb, nbb = key
-        toks = np.zeros(bb, np.int32)
-        toks[:b] = toks_list
+        ahead = False
+        if isinstance(toks, _DecodeFlight):
+            ahead = toks.host is None and toks.bb == bb
+            toks = toks.nxt if ahead else toks.read()
+        if ahead:
+            operand = toks
+        else:
+            operand = np.zeros(bb, np.int32)
+            operand[:b] = toks
         if sp is not None:
             bucket = "%dx%d" % key
             _tr.end(sp, args={"bucket": bucket})
             sp = _tr.begin("serve.decode", None,
-                           {"bucket": bucket, "rows": b, "pages": pages})
+                           {"bucket": bucket, "rows": b, "pages": pages,
+                            "ahead": int(ahead)})
         (nxt, *logits), _ = self._dispatch(
             "decode", lambda kp, vp: exe(self._params, kp, vp,
-                                         tables, lens, toks))
+                                         tables, lens, operand))
         if _count and _batcher._METRICS_ON:
             _M_DECODE_STEPS.inc()
+            if ahead:
+                _M_DECODE_AHEAD.inc()
             _M_DECODE_ROWS.inc(b)
             _M_DECODE_SLOTS.inc(bb)
             _M_DECODE_PAGES.inc(pages)
@@ -1012,23 +1077,30 @@ class GenerativeEngine:
             _M_OCC_PCT.observe(100.0 * b / bb)
         if sp is not None:
             _tr.end(sp)
-            sp = _tr.begin("serve.decode.wait")
+        return _DecodeFlight(nxt, logits[0] if logits else None, b, bb)
+
+    def decode_read(self, flight, _tr=None, _times=None):
+        """The tokens of a dispatched decode step, one a live row (and
+        its logits, where it was dispatched with them): blocks until
+        the device has them.  A span where ``_tr`` is given,
+        ``serve.decode.wait``; ``_times`` gets the time up to here as
+        host time and the read-back as wait."""
+        sp = (_tr.begin("serve.decode.wait")
+              if _tr is not None else None)
         if _times is not None:
             now = _clock_ns()
             _times.host += now - _times.mark
             _times.mark = now
-        nxt = np.asarray(nxt)[:b]
-        if with_logits:
-            logits = np.asarray(logits[0])[:b]
+        nxt = flight.read()
+        logits = (None if flight.logits is None
+                  else np.asarray(flight.logits)[:flight.b])
         if _times is not None:
             now = _clock_ns()
             _times.wait += now - _times.mark
             _times.mark = now
         if sp is not None:
             _tr.end(sp)
-        if with_logits:
-            return nxt, logits
-        return nxt
+        return nxt if logits is None else (nxt, logits)
 
     # -- speculative decoding -------------------------------------------
 
@@ -1306,12 +1378,25 @@ class GenerativeEngine:
 # ---------------------------------------------------------------------------
 
 class DecodeLoop:
-    """One thread per generative tenant.  Each iteration: admit queued
-    prefills the block pool can hold (TokenScheduler), grow/preempt for
-    sequences crossing a block boundary, dispatch ONE decode step over
-    the whole running set, emit tokens, retire finished sequences.  The
-    loop must survive anything — a dead loop wedges the tenant with
-    unresolved futures (the PR 9 dispatcher rule)."""
+    """One thread per generative tenant.  An iteration ends with ONE
+    decode step over the whole running set dispatched and unread (in
+    flight), and the next one starts from it:
+
+    - **ahead**, where the next step runs over the same rows and the
+      host already knows it (``_may_run_ahead``): grow, dispatch step
+      n+1 on step n's device tokens, THEN read step n, emit its tokens
+      and retire finished sequences.  The device goes from step n
+      straight into step n+1; the host's work between two steps runs
+      beside it.
+    - **drain**, anything else: read step n and emit first, then admit
+      queued prefills the block pool can hold (TokenScheduler),
+      grow/preempt for sequences crossing a block boundary and dispatch
+      the step from the host's tokens.  A speculative round is read
+      inside its own iteration and leaves nothing in flight.
+
+    Tokens are delivered one a step, when the device has them, in both
+    orders.  The loop must survive anything — a dead loop wedges the
+    tenant with unresolved futures (the PR 9 dispatcher rule)."""
 
     def __init__(self, engine, queue, label=""):
         self.engine = engine
@@ -1321,6 +1406,9 @@ class DecodeLoop:
                                         prefix_cache=engine.prefix_cache)
         self.label = label
         self._times = _LoopTimes()
+        # the decode step dispatched and not yet read, with the rows it
+        # runs over in its order: (_DecodeFlight, [GenRequest]) or None
+        self._flight = None
         self._stop = _san.make_event("generative.decode.stop")
         self._thread = threading.Thread(
             target=self._loop, daemon=True,
@@ -1350,6 +1438,7 @@ class DecodeLoop:
             try:
                 self._iterate(running)
             except Exception as e:
+                self._flight = None     # its rows fail with the rest
                 for seq in running:
                     self.engine.free_sequence(seq)
                     if not seq.future.done():
@@ -1382,6 +1471,30 @@ class DecodeLoop:
                     times.flush()
 
     def _iterate_phases(self, running, trc, times):
+        # 0. the step the last iteration left in flight: the next one
+        # goes out ahead of its tokens where it can, else it is drained
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            step, rows = flight
+            ahead = self._may_run_ahead(rows, running, trc)
+            if ahead:
+                flight = (self.engine.decode_start(running, after=step,
+                                                   _tr=trc),
+                          list(running))
+            pairs = zip(rows, self.engine.decode_read(step, _tr=trc,
+                                                      _times=times))
+            if len(rows) != len(running):
+                # a row that finished under this step (an EOS the host
+                # could not foresee) ran dead in it: its token is dropped
+                live = {id(seq) for seq in running}
+                pairs = (p for p in pairs if id(p[0]) in live)
+            self._emit_all([(seq, int(tok)) for seq, tok in pairs],
+                           running, trc, ahead=int(ahead))
+            if ahead:
+                # with no row left the step ahead runs dead rows only:
+                # nothing of it is ever read
+                self._flight = flight if running else None
+                return
         # 1. admission: stream prefills into the running batch.  A
         # prefill failure (over-long prompt that slipped validation, a
         # cold-bucket compile error) fails THAT request and returns its
@@ -1444,25 +1557,81 @@ class DecodeLoop:
         # max_seq-bounded sequence) it falls back to plain decode —
         # the emitted tokens are identical either way, only the
         # per-iteration yield changes
-        spec = self.engine.spec_k > 0 and self.engine.draft is not None
+        spec = self._speculative()
         if spec and any(
                 s.context_len + self.engine.spec_k + 1
                 > self.engine.config.max_seq for s in running):
             spec = False
-        need = self.engine.spec_k + 1 if spec else 1
         # 2. growth/preemption: every sequence writing into a fresh
         # block this iteration needs one allocated (a speculative
         # iteration writes k+1 positions, so it provisions that far)
+        self._grow(running, self.engine.spec_k + 1 if spec else 1, trc)
+        if not running:
+            return
+        # 3. one decode iteration over the whole running set — a
+        # speculative round emits up to k+1 tokens per sequence; a
+        # plain step stays in flight for the next iteration
+        if spec:
+            pairs = []
+            for seq, toks in zip(list(running),
+                                 self.engine.spec_decode(running, times)):
+                # replay the round token-at-a-time so _emit's
+                # done-checks (max_new / eos / max_seq) see the same
+                # intermediate context a plain-decode iteration would
+                seq.context_len -= len(toks)
+                pairs.extend((seq, int(tok)) for tok in toks)
+            self._emit_all(pairs, running, trc, replay=True)
+        else:
+            self._flight = (self.engine.decode_start(running, _tr=trc),
+                            list(running))
+
+    def _speculative(self):
+        return self.engine.spec_k > 0 and self.engine.draft is not None
+
+    def _may_run_ahead(self, rows, running, trc):
+        """Whether the step after the one in flight over ``rows`` can be
+        dispatched before that one is read: it runs over the same rows
+        in the same order, and nothing the host has yet to learn or do
+        comes between the two.  So not when the step in flight ends a
+        row by count (``max_new``, ``max_seq``: known before the read;
+        an EOS is not, and costs one dead row), a request waits for a
+        free row, the rounds are speculative (acceptance reads the
+        tokens), the loop is stopping, or a row cannot grow without a
+        preemption.  Rows that could grow keep their new blocks: the
+        drained step writes the same positions."""
+        if (len(rows) != len(running)
+                or any(a is not b for a, b in zip(rows, running))
+                or self._speculative() or self._stop.is_set()
+                or (len(running) < self.scheduler.max_batch
+                    and len(self.queue))):
+            return False
+        max_seq = self.engine.config.max_seq
+        if any(len(seq.out) + 1 >= seq.max_new
+               or seq.context_len >= max_seq for seq in running):
+            return False
+        return self._grow(running, 1, trc, preempt=False)
+
+    def _grow(self, running, need, trc, preempt=True):
+        """Provision every running sequence for the ``need`` positions
+        its next step writes.  With ``preempt`` a sequence the pool
+        cannot grow evicts the youngest (or fails, alone in an empty
+        pool); without, the first such sequence ends the attempt and
+        False comes back."""
         sp = trc.begin("serve.grow") if trc is not None else None
         preempted = 0
+        grown = True
+        block = self.engine.config.block_size
         for seq in list(running):
             if seq not in running:
                 continue
-            cap = len(seq.blocks) * self.engine.config.block_size
+            cap = len(seq.blocks) * block
             while seq.context_len + need > cap and seq in running:
                 if self.scheduler.grow(seq):
-                    cap += self.engine.config.block_size
+                    cap += block
                     continue
+                if not preempt:
+                    grown = False
+                    break
                 victim = self.scheduler.pick_victim(running, seq)
                 if victim is None:
                     self.engine.free_sequence(seq)
@@ -1475,36 +1644,22 @@ class DecodeLoop:
                     break
                 self._preempt(victim, running)
                 preempted += 1
+            if not grown:
+                break
         if sp is not None:
             trc.end(sp, args={"preempted": preempted})
-        if not running:
-            return
-        # 3. one decode iteration over the whole running set — a
-        # speculative round emits up to k+1 tokens per sequence
-        if spec:
-            pairs = []
-            for seq, toks in zip(list(running),
-                                 self.engine.spec_decode(running, times)):
-                # replay the round token-at-a-time so _emit's
-                # done-checks (max_new / eos / max_seq) see the same
-                # intermediate context a plain-decode iteration would
-                seq.context_len -= len(toks)
-                pairs.extend((seq, int(tok)) for tok in toks)
-            self._emit_all(pairs, running, trc, replay=True)
-        else:
-            toks = self.engine.decode(running, _tr=trc, _times=times)
-            self._emit_all([(seq, int(tok)) for seq, tok
-                            in zip(list(running), toks)],
-                           running, trc)
+        return grown
 
-    def _emit_all(self, pairs, running, trc, replay=False):
+    def _emit_all(self, pairs, running, trc, replay=False, ahead=0):
         """Emit ``(sequence, token)`` pairs under ONE ``serve.emit``
         span (not one a token).  The span's cid
         is the first finished request's id (``finished_ids`` has all),
         so a request's prefill and its finishing emit share a cid.
         ``replay``: a speculative round's tokens, each advancing the
         context by one; a sequence that finishes mid-round drops the
-        rest."""
+        rest.  ``ahead``: how many steps past these tokens' own the
+        sequences' contexts already stand (1 where the next step went
+        out before these were read)."""
         sp = trc.begin("serve.emit") if trc is not None else None
         finished = []
         tokens = 0
@@ -1514,7 +1669,7 @@ class DecodeLoop:
                     continue    # finished mid-round; discard the rest
                 seq.context_len += 1
             tokens += 1
-            if self._emit(seq, tok, running):
+            if self._emit(seq, tok, running, ahead):
                 finished.append(seq.rid)
         if sp is not None:
             trc.end(sp, cid=finished[0] if finished else None,
@@ -1522,8 +1677,10 @@ class DecodeLoop:
                           "finished": len(finished),
                           "finished_ids": finished})
 
-    def _emit(self, seq, tok, running):
-        """Deliver one token; True when it finished the sequence."""
+    def _emit(self, seq, tok, running, ahead=0):
+        """Deliver one token; True when it finished the sequence.  The
+        done-checks see the context of the step that made the token:
+        ``ahead`` steps behind where the sequence stands."""
         now = time.perf_counter()
         metrics_on = _batcher._METRICS_ON
         if seq.t_first is None:
@@ -1544,7 +1701,7 @@ class DecodeLoop:
             _M_TOKENS.inc()
         done = (len(seq.out) >= seq.max_new
                 or (seq.eos_id is not None and tok == seq.eos_id)
-                or seq.context_len >= self.engine.config.max_seq)
+                or seq.context_len - ahead >= self.engine.config.max_seq)
         if done:
             if seq in running:
                 running.remove(seq)

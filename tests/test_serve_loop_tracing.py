@@ -95,6 +95,9 @@ def test_loop_phases_add_up_to_the_wall():
     assert wall > 0 and d["serve_loop_prefill_us_total"] > 0
     assert d["serve_loop_wait_us_total"] > 0
     assert abs(wall - parts) <= 0.02 * wall, (wall, parts, d)
+    # the identity holds with steps dispatched ahead of the one before:
+    # that one's read is the wait, after the next one's dispatch
+    assert 0 < d["serve_decode_ahead_total"] <= d["serve_decode_steps_total"]
     # nobody was preempted: one admission a request
     assert d["serve_admissions_total"] == 6
     assert d["serve_queue_wait_us_total"] >= 0
@@ -171,6 +174,10 @@ def test_decode_counts_the_pages_held_against_the_page_slots(ring, server):
     ``serve.decode`` span says the same a step."""
     decodes = [s for s in ring if s["name"] == "serve.decode"]
     assert decodes
+    # ``ahead``: the step went out on the device tokens of the one
+    # before, unread; never the first after a prefill
+    assert {s["args"]["ahead"] for s in decodes} == {0, 1}
+    assert decodes[0]["args"]["ahead"] == 0
     for s in decodes:
         rows, slots = (int(x) for x in s["args"]["bucket"].split("x"))
         # prompts of 4 and 5 new tokens: one block of 8 a row, or two
