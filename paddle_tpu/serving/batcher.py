@@ -356,6 +356,10 @@ class TokenScheduler:
       keeps head-of-line latency bounded), and a lone sequence that
       cannot grow out of an EMPTY pool is a configuration error
       surfaced to the caller, not an infinite preempt-readmit loop;
+    - where the pool keeps state slots (a model with per-sequence
+      state, kv_cache.BlockPool), admission takes the sequence's slot
+      before its blocks and hands it back if the blocks fail: a request
+      is never admitted with one kind of state and not the other;
     - with a prefix index attached (ISSUE 19,
       generative.PrefixCache), admission takes the PARTIALLY-CACHED
       branch: the index shares the prompt's already-resident prefix
@@ -392,12 +396,24 @@ class TokenScheduler:
                     break
                 admitted.append(req)
                 continue
+            slot = 0
+            if self.pool.state_slots:
+                # a model with per-sequence state: the sequence holds
+                # one slot from here to finish or preemption
+                slot = self.pool.take_slot()
+                if slot is None:
+                    queue.put_front([req])
+                    break
             blocks = self.pool.alloc(self.pool.blocks_for(
                 len(req.prompt)))
             if blocks is None:
+                if slot:
+                    self.pool.return_slot(slot)
                 queue.put_front([req])      # keeps its arrival stamp
                 break
             req.blocks = blocks
+            if slot:
+                req.slot = slot
             admitted.append(req)
         return admitted
 

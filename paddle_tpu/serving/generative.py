@@ -5,13 +5,20 @@ the config object it was given (serving/lm.py: the page spec, the
 staging rule, the forward over paged rows, the forward over a fresh
 prompt, the logit layer); everything here is the engine's:
 
-- **Paged KV cache** — the tenant's K/V pages live as ONE device-
-  resident array pair, shaped by the model's page spec, DONATED
+- **Paged KV cache** — the tenant's cache lives as ONE device-
+  resident tree of arrays that the model's ``cache_spec`` names
+  (``LMConfig``'s is the K/V page pair, shaped by its page spec; a
+  model with recurrent layers adds its per-sequence state arrays),
+  DONATED whole
   through every prefill/decode dispatch and re-bound to the returned
   buffers, so cache state never round-trips the host between steps —
   the prepared-program donation contract applied to serving state.
-  Host-side accounting (free list, per-sequence block tables, pressure
-  gauges) is kv_cache.BlockPool.
+  Host-side accounting (free list, per-sequence block tables and state
+  slots, pressure gauges) is kv_cache.BlockPool.  A model that says it
+  has per-sequence state (``state_slots``) gets the slot ids as one
+  more ``[R]`` int32 operand of its steps; copy-on-write, the prefix
+  cache, speculation and page migration are K/V-only and refuse such
+  a model by name.
 - **Prefill/decode disaggregated continuous batching** — DecodeLoop
   runs Orca iteration-level scheduling: every decode iteration
   re-decides the batch, admitting queued prefills the moment the block
@@ -274,7 +281,7 @@ class GenRequest:
     __slots__ = ("rid", "prompt", "max_new", "eos_id", "future",
                  "t_arrival", "blocks", "context_len", "out", "t_first",
                  "t_last", "itl_ms", "preempted", "cached_len",
-                 "draft_len")
+                 "draft_len", "slot")
 
     def __init__(self, prompt, max_new, eos_id, future):
         # small process-wide id: the cid of this request's spans
@@ -286,6 +293,9 @@ class GenRequest:
         self.t_arrival = time.perf_counter()
         self.reset()
         self.preempted = 0
+        # the state slot held from admission to finish or preemption
+        # (a model with per-sequence state; 0: none, the scratch slot)
+        self.slot = 0
 
     def reset(self):
         self.blocks = []
@@ -518,10 +528,13 @@ class _DecodeFlight:
     live rows of the ``bb`` the batch bucket holds.  ``host`` is the
     read-back once ``read`` has made it."""
 
-    __slots__ = ("nxt", "logits", "b", "bb", "host")
+    __slots__ = ("nxt", "logits", "stats", "b", "bb", "host")
 
-    def __init__(self, nxt, logits, b, bb):
+    def __init__(self, nxt, logits, b, bb, stats=None):
         self.nxt, self.logits, self.b, self.bb = nxt, logits, b, bb
+        # what the model counted in the step (a small device array, read
+        # with the tokens), where it counts anything
+        self.stats = stats
         self.host = None
 
     def read(self):
@@ -549,8 +562,13 @@ class GenerativeEngine:
                              % (self.quant,))
         cfg = self.config
         n_blocks = int(kv_blocks or FLAGS.serve_kv_blocks)
+        # a model with per-sequence state says how many slots it keeps
+        # (the scratch slot included); the pool hands them out
+        state_slots = int(getattr(cfg, "state_slots", 0))
+        self.stateful = state_slots > 0
         self.pool = BlockPool(n_blocks, cfg.block_size,
-                              register=_register_pool)
+                              register=_register_pool,
+                              state_slots=state_slots)
         self.prefix_cache = None
         self.draft = None
         self.spec_k = int(FLAGS.serve_spec_k if spec_k is None
@@ -559,10 +577,16 @@ class GenerativeEngine:
             self.pool.close()
             raise ValueError("spec_k must be >= 0 (got %d)"
                              % self.spec_k)
+        prefix_cache = (FLAGS.serve_prefix_cache if prefix_cache is None
+                        else prefix_cache)
         try:
+            if prefix_cache:
+                self._kv_only("the prefix cache (prefix_cache=True)")
+            if self.spec_k > 0:
+                self._kv_only("speculative decoding (spec_k=%d)"
+                              % self.spec_k)
             self._init_device_state(params, n_blocks, place, warm)
-            if (FLAGS.serve_prefix_cache if prefix_cache is None
-                    else prefix_cache):
+            if prefix_cache:
                 self.prefix_cache = PrefixCache(self)
                 if warm:
                     self._prefill_cached.warm(
@@ -594,6 +618,11 @@ class GenerativeEngine:
                 % self.spec_k)
         dcfg, dparams = draft
         dcfg = _as_config(dcfg)
+        if getattr(dcfg, "state_slots", 0):
+            raise ValueError(
+                "a draft model with per-sequence state (%s) cannot share "
+                "the target's block tables: speculation is K/V-only"
+                % type(dcfg).__name__)
         for f in ("vocab", "block_size", "max_blocks", "max_batch"):
             if getattr(dcfg, f) != getattr(cfg, f):
                 raise ValueError(
@@ -633,12 +662,13 @@ class GenerativeEngine:
         self._dev = (place if place is not None
                      else default_place()).jax_device()
         self._params = cfg.stage(params, self.quant, self._dev)
-        shape, dtype = self.page_spec(n_blocks)
-        self._kp = jax.device_put(np.zeros(shape, dtype), self._dev)
-        self._vp = jax.device_put(np.zeros(shape, dtype), self._dev)
+        self._cache = jax.tree_util.tree_map(
+            lambda a: jax.device_put(np.zeros(a.shape, a.dtype), self._dev),
+            cfg.cache_spec(n_blocks))
+        self._cache_tree = jax.tree_util.tree_structure(self._cache)
         self._state_lock = _san.make_lock(
             "serve.generative.state:%s" % self.name)
-        # buffer sanitizer: the page pool is donated through every
+        # buffer sanitizer: the cache tree is donated through every
         # prefill/decode dispatch and re-bound to the returned buffers
         # — the guard names a read of the pool mid-dispatch or through
         # a stale (pre-rebind) epoch
@@ -686,14 +716,25 @@ class GenerativeEngine:
     def page_spec(self, n_blocks):
         """``(shape, dtype)`` of each of the K and V pools for
         ``n_blocks`` blocks — the model's, asked here by everything
-        that allocates, installs or ships pages."""
+        that installs or ships pages."""
         return self.config.page_spec(n_blocks)
+
+    def _kv_only(self, what):
+        """``what`` works on K/V pages alone: a model with per-sequence
+        state is refused by name (its state has no snapshot, copy or
+        wire form yet)."""
+        if self.stateful:
+            raise ValueError(
+                "%s is K/V-only: %s keeps per-sequence state beside its "
+                "pages (state_slots=%d), which it cannot share, copy or "
+                "ship" % (what, type(self.config).__name__,
+                          self.config.state_slots))
 
     # -- compiled steps -------------------------------------------------
 
     def _aot(self, name, step, *specs):
-        """AOT-compile ``step(params, k_pages, v_pages, *args)`` for
-        THIS engine's device (not whatever jax's default is), pages
+        """AOT-compile ``step(params, cache, *args)`` for THIS engine's
+        device (not whatever jax's default is), the whole cache tree
         donated; ``specs`` are the ``(shape, dtype)`` of the trailing
         args.  ``name`` is the executable's stable name in a trace."""
         import jax
@@ -706,42 +747,62 @@ class GenerativeEngine:
         def sds(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=on_dev)
 
+        flat = self._flat(step)
+
         def placed(*args):
             with placed_on(self._dev):
-                return step(*args)
+                return flat(*args)
 
         placed.__name__ = name
-        pspec = jax.tree_util.tree_map(
-            lambda a: sds(a.shape, a.dtype), self._params)
-        return jax.jit(placed, donate_argnums=(1, 2)).lower(
-            pspec, sds(self._kp.shape, self._kp.dtype),
-            sds(self._vp.shape, self._vp.dtype),
+        pspec, cspec = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), (self._params, self._cache))
+        return jax.jit(placed, donate_argnums=(1,)).lower(
+            pspec, cspec,
             *[sds(shape, dtype) for shape, dtype in specs]).compile()
+
+    @staticmethod
+    def _flat(step):
+        """``step`` with the cache tree it returns last flattened into
+        its leaves, as ``_dispatch`` takes them back: the executable's
+        results are then the step's own outputs followed by the cache
+        arrays, whatever tree the model named."""
+        import jax
+
+        def flat(*args):
+            *out, cache = step(*args)
+            return (*out, *jax.tree_util.tree_leaves(cache))
+
+        return flat
 
     def _compile_decode(self, key, with_logits=False):
         """AOT-compile one decode iteration at bucket ``(B, NB)``: one
         token per sequence in, K/V written through the block table,
         paged attention over each sequence's pages, greedy next token
-        out.  Pages are donated — the returned buffers REPLACE the
-        engine's, nothing round-trips the host."""
+        out.  The cache is donated — the returned buffers REPLACE the
+        engine's, nothing round-trips the host.  A model with
+        per-sequence state takes its rows' slot ids as one more operand
+        and may hand back what it counted in the step."""
         import jax.numpy as jnp
 
         model = self.config
         bb, nbb = key
+        slot_spec = (((bb,), jnp.int32),) if self.stateful else ()
 
-        def step(p, kp, vp, tables, lens, toks):
-            h, kp, vp = model.paged_forward(p, kp, vp, toks, lens, tables)
+        def step(p, cache, tables, lens, toks, *slots):
+            h, cache, *stats = model.paged_forward(
+                p, cache, toks, lens, tables,
+                **({"slots": slots[0]} if slots else {}))
             logits = model.head(p, h)                      # [B, V] f32
             nxt = _greedy(logits)
             if with_logits:
-                return nxt, logits, kp, vp
-            return nxt, kp, vp
+                return (nxt, logits, *stats, cache)
+            return (nxt, *stats, cache)
 
         return self._aot(
             "serve_decode", step,
             ((bb, nbb), jnp.int32),
             ((bb,), jnp.int32),
-            ((bb,), jnp.int32))
+            ((bb,), jnp.int32), *slot_spec)
 
     def _compile_prefill(self, key):
         """AOT-compile one prefill at sequence bucket ``(S,)``: the
@@ -754,16 +815,19 @@ class GenerativeEngine:
         (s_len,) = key
         n_ids = max(1, s_len // model.block_size)
 
-        def step(p, kp, vp, toks, length, block_ids):
-            h, kp, vp = model.prompt_forward(p, kp, vp, toks, length,
-                                             block_ids)
-            return _greedy(model.head(p, h, n_live=length)), kp, vp
+        slot_spec = (((), jnp.int32),) if self.stateful else ()
+
+        def step(p, cache, toks, length, block_ids, *slot):
+            h, cache = model.prompt_forward(
+                p, cache, toks, length, block_ids,
+                **({"slot": slot[0]} if slot else {}))
+            return _greedy(model.head(p, h, n_live=length)), cache
 
         return self._aot(
             "serve_prefill", step,
             ((s_len,), jnp.int32),
             ((), jnp.int32),
-            ((n_ids,), jnp.int32))
+            ((n_ids,), jnp.int32), *slot_spec)
 
     def _compile_prefill_cached(self, key):
         """AOT-compile a SUFFIX prefill at suffix bucket ``(S,)``: the
@@ -780,13 +844,13 @@ class GenerativeEngine:
         (s_len,) = key
         n_ids = model.max_blocks
 
-        def step(p, kp, vp, toks, start, count, block_ids):
+        def step(p, cache, toks, start, count, block_ids):
             rows = jnp.arange(s_len, dtype=jnp.int32)
-            h, kp, vp = model.paged_forward(
-                p, kp, vp, toks, start + rows,
+            h, cache = model.paged_forward(
+                p, cache, toks, start + rows,
                 jnp.broadcast_to(block_ids, (s_len, n_ids)),
                 live=rows < count)
-            return _greedy(model.head(p, h, n_live=count)), kp, vp
+            return _greedy(model.head(p, h, n_live=count)), cache
 
         return self._aot(
             "serve_prefill_cached", step,
@@ -810,15 +874,15 @@ class GenerativeEngine:
         model = self.config
         bb, nbb, k = key
 
-        def step(p, kp, vp, tables, lens, toks):
+        def step(p, cache, tables, lens, toks):
             cur = toks                                     # [B]
             props = []
             for j in range(k):
-                h, kp, vp = model.paged_forward(p, kp, vp, cur,
-                                                lens + j, tables)
+                h, cache = model.paged_forward(p, cache, cur,
+                                               lens + j, tables)
                 cur = _greedy(model.head(p, h))
                 props.append(cur)
-            return jnp.stack(props, axis=1), kp, vp      # [B, k]
+            return jnp.stack(props, axis=1), cache       # [B, k]
 
         return self._aot(
             "serve_propose", step,
@@ -843,13 +907,13 @@ class GenerativeEngine:
         model = self.config
         bb, nbb, k1 = key
 
-        def step(p, kp, vp, tables, lens, toks):
+        def step(p, cache, tables, lens, toks):
             pos = (lens[:, None]
                    + jnp.arange(k1, dtype=jnp.int32)[None, :])  # [B,k1]
-            h, kp, vp = model.paged_forward(
-                p, kp, vp, toks.reshape(bb * k1), pos.reshape(bb * k1),
+            h, cache = model.paged_forward(
+                p, cache, toks.reshape(bb * k1), pos.reshape(bb * k1),
                 jnp.repeat(tables, k1, axis=0))            # [B*k1, NB]
-            return _greedy(model.head(p, h)).reshape(bb, k1), kp, vp
+            return _greedy(model.head(p, h)).reshape(bb, k1), cache
 
         return self._aot(
             "serve_verify", step,
@@ -860,15 +924,18 @@ class GenerativeEngine:
     # -- dispatch -------------------------------------------------------
 
     def _dispatch(self, op, run):
-        """The bracket around everything that donates the page pool:
-        under the state lock, count the step, open the epoch guard,
-        ``run(k_pages, v_pages)``, re-bind the pools to the last two
-        of what it returns and close the guard.  Returns the rest and
-        the epoch the re-bind left."""
+        """The bracket around everything that donates the cache: under
+        the state lock, count the step, open the epoch guard,
+        ``run(cache)``, re-bind the cache tree to the arrays that what
+        it returns ends in (one a leaf, in the tree's order) and close
+        the guard.  Returns the rest and the epoch the re-bind left."""
+        tree = self._cache_tree
         with self._state_lock:
             self._kv_steps += 1
             self._kv_guard.begin(op, self._kv_steps)
-            *out, self._kp, self._vp = run(self._kp, self._vp)
+            out = list(run(self._cache))
+            self._cache = tree.unflatten(out[len(out) - tree.num_leaves:])
+            del out[len(out) - tree.num_leaves:]
             self._kv_guard.rebind()
             return out, self._kv_guard.epoch
 
@@ -914,14 +981,15 @@ class GenerativeEngine:
         if self.prefix_cache is None or not 0 < cached < n:
             cached = 0
         tok = self.prefill_tokens(seq.prompt, seq.blocks, _tr,
-                                  getattr(seq, "rid", None), start=cached)
+                                  getattr(seq, "rid", None), start=cached,
+                                  slot=getattr(seq, "slot", 0))
         seq.context_len = n
         if _batcher._METRICS_ON:
             _M_PREFILLS.inc()
         return tok
 
     def prefill_tokens(self, tokens, blocks, _tr=None, _cid=None,
-                       start=0):
+                       start=0, slot=0):
         """Raw prefill: write K/V for positions ``start..n-1`` of
         ``tokens`` into ``blocks`` and return the greedy next token.
         ``start`` > 0 is a prefix-cache hit: the first ``start``
@@ -929,7 +997,9 @@ class GenerativeEngine:
         by SUFFIX length — an 80%-cached prompt runs a bucket ~5x
         narrower than its full prefill.  No sequence bookkeeping — the
         speculative draft's re-prefill path uses this directly (its
-        catch-up feed is tokens, not a GenRequest).  ``serve.prefill``
+        catch-up feed is tokens, not a GenRequest).  ``slot`` is the
+        sequence's state slot, an operand of a stateful model's prefill
+        only (0: the scratch slot).  ``serve.prefill``
         spans staging, dispatch and the wait for the token; its child
         ``serve.prefill.dispatch`` the dispatch."""
         cfg = self.config
@@ -957,10 +1027,12 @@ class GenerativeEngine:
         ids = np.zeros(n_ids, np.int32)
         m = min(len(blocks), n_ids)
         ids[:m] = blocks[:m]
+        tail = (np.int32(slot),) if self.stateful else ()
         spd = (_tr.begin("serve.prefill.dispatch", _cid)
                if sp is not None else None)
         (nxt,), _ = self._dispatch(
-            op, lambda kp, vp: exe(self._params, kp, vp, toks, *lead, ids))
+            op, lambda cache: exe(self._params, cache, toks, *lead, ids,
+                                  *tail))
         if sp is None:
             return int(nxt)
         _tr.end(spd)
@@ -976,14 +1048,15 @@ class GenerativeEngine:
         it so a concurrent reader never sees a half-copied pool."""
         import jax
 
+        self._kv_only("copy_block")
         if not hasattr(self, "_copy_jit"):
-            def _cp(kp, vp, s, d):
-                return (kp.at[:, d].set(kp[:, s]),
-                        vp.at[:, d].set(vp[:, s]))
+            def _cp(cache, s, d):
+                kp, vp = cache
+                return kp.at[:, d].set(kp[:, s]), vp.at[:, d].set(vp[:, s])
 
-            self._copy_jit = jax.jit(_cp, donate_argnums=(0, 1))
-        self._dispatch("cow", lambda kp, vp: self._copy_jit(
-            kp, vp, np.int32(src), np.int32(dst)))
+            self._copy_jit = jax.jit(_cp, donate_argnums=(0,))
+        self._dispatch("cow", lambda cache: self._copy_jit(
+            cache, np.int32(src), np.int32(dst)))
 
     def decode(self, seqs, with_logits=False):
         """One decode iteration over the running sequences, dispatched
@@ -1003,14 +1076,15 @@ class GenerativeEngine:
             for s in seqs]
         flight = self.decode_dispatch(
             [s.blocks for s in seqs], [s.context_len for s in seqs],
-            toks, with_logits=with_logits, _count=True, _tr=_tr)
+            toks, with_logits=with_logits, _count=True, _tr=_tr,
+            slots=[s.slot for s in seqs] if self.stateful else None)
         for s in seqs:
             s.context_len += 1
         return flight
 
     def decode_step(self, blocks_list, lens_list, toks_list,
                     with_logits=False, _count=False, _tr=None,
-                    _times=None):
+                    _times=None, slots=None):
         """Raw single-token decode over parallel lists (one entry per
         row): no sequence bookkeeping, callers own their own length
         accounting — the speculative draft's propose/catch-up steps
@@ -1022,11 +1096,12 @@ class GenerativeEngine:
         return self.decode_read(
             self.decode_dispatch(blocks_list, lens_list, toks_list,
                                  with_logits=with_logits, _count=_count,
-                                 _tr=_tr),
+                                 _tr=_tr, slots=slots),
             _tr=_tr, _times=_times)
 
     def decode_dispatch(self, blocks_list, lens_list, toks,
-                        with_logits=False, _count=False, _tr=None):
+                        with_logits=False, _count=False, _tr=None,
+                        slots=None):
         """Stage and dispatch one decode step; returns its
         ``_DecodeFlight`` without waiting for the device.  Two phases,
         each a span where ``_tr`` is given: ``serve.decode.stage``
@@ -1040,7 +1115,9 @@ class GenerativeEngine:
         the executable as they are (the ``(bb,)`` int32 output is the
         ``toks`` operand's own shape and device): the host never sees
         them in between, so this dispatch does not wait for that step.
-        Any other flight is read here first."""
+        Any other flight is read here first.  ``slots`` is one state
+        slot a row, for a model with per-sequence state (left out, every
+        row aims at the scratch slot)."""
         sp = (_tr.begin("serve.decode.stage")
               if _tr is not None else None)
         b = len(blocks_list)
@@ -1057,15 +1134,22 @@ class GenerativeEngine:
         else:
             operand = np.zeros(bb, np.int32)
             operand[:b] = toks
+        tail = ()
+        if self.stateful:
+            tail = (np.zeros(bb, np.int32),)
+            if slots is not None:
+                tail[0][:b] = slots
         if sp is not None:
             bucket = "%dx%d" % key
             _tr.end(sp, args={"bucket": bucket})
             sp = _tr.begin("serve.decode", None,
                            {"bucket": bucket, "rows": b, "pages": pages,
                             "ahead": int(ahead)})
-        (nxt, *logits), _ = self._dispatch(
-            "decode", lambda kp, vp: exe(self._params, kp, vp,
-                                         tables, lens, operand))
+        (nxt, *rest), _ = self._dispatch(
+            "decode", lambda cache: exe(self._params, cache,
+                                        tables, lens, operand, *tail))
+        logits = rest.pop(0) if with_logits else None
+        stats = rest[0] if rest else None
         if _count and _batcher._METRICS_ON:
             _M_DECODE_STEPS.inc()
             if ahead:
@@ -1077,7 +1161,7 @@ class GenerativeEngine:
             _M_OCC_PCT.observe(100.0 * b / bb)
         if sp is not None:
             _tr.end(sp)
-        return _DecodeFlight(nxt, logits[0] if logits else None, b, bb)
+        return _DecodeFlight(nxt, logits, b, bb, stats)
 
     def decode_read(self, flight, _tr=None, _times=None):
         """The tokens of a dispatched decode step, one a live row (and
@@ -1098,8 +1182,12 @@ class GenerativeEngine:
             now = _clock_ns()
             _times.wait += now - _times.mark
             _times.mark = now
+        # what the model counted in this step rides the same read
+        counted = (self.config.note_decode(np.asarray(flight.stats),
+                                           _batcher._METRICS_ON)
+                   if flight.stats is not None else None)
         if sp is not None:
-            _tr.end(sp)
+            _tr.end(sp, args=counted)
         return nxt if logits is None else (nxt, logits)
 
     # -- speculative decoding -------------------------------------------
@@ -1117,8 +1205,8 @@ class GenerativeEngine:
         with TRACER.span("serve.propose",
                          args={"bucket": "%dx%dx%d" % key, "rows": b}):
             (props,), _ = self._dispatch(
-                "propose", lambda kp, vp: exe(self._params, kp, vp,
-                                              tables, lens, toks))
+                "propose", lambda cache: exe(self._params, cache,
+                                             tables, lens, toks))
         return np.asarray(props)[:b]
 
     def verify_step(self, seqs, props):
@@ -1138,8 +1226,8 @@ class GenerativeEngine:
         with TRACER.span("serve.verify",
                          args={"bucket": "%dx%dx%d" % key, "rows": b}):
             (nxt,), _ = self._dispatch(
-                "verify", lambda kp, vp: exe(self._params, kp, vp,
-                                             tables, lens, toks))
+                "verify", lambda cache: exe(self._params, cache,
+                                            tables, lens, toks))
         return np.asarray(nxt)[:b]
 
     def spec_decode(self, seqs, _times=None):
@@ -1245,9 +1333,18 @@ class GenerativeEngine:
         pages behind a stale epoch were donated and re-bound under the
         caller (the PR 11 rebind contract, lint-checkable via
         analysis.lifetime.check_serving_fetches)."""
+        self._kv_only("kv_pages")
         with self._state_lock:
             self._kv_guard.check()
-            return self._kp, self._vp, self._kv_guard.epoch
+            return (*self._cache, self._kv_guard.epoch)
+
+    def cache_state(self):
+        """Debug access to the whole live cache tree, as the model's
+        ``cache_spec`` names it, with the epoch it was read at:
+        ``(tree, epoch)``, under ``kv_pages``'s contract."""
+        with self._state_lock:
+            self._kv_guard.check()
+            return self._cache, self._kv_guard.epoch
 
     def check_kv_epoch(self, epoch):
         """Raise BufferLifetimeError when pages observed at ``epoch``
@@ -1267,17 +1364,19 @@ class GenerativeEngine:
         returned HOST copies stay valid across later rebinds — the
         epoch rides the MigrateKV frame so the two ends can log the
         handoff, not to revalidate the copy."""
+        self._kv_only("export_blocks")
         ids = np.asarray([int(b) for b in blocks], np.int32)
         with self._state_lock:
             self._kv_guard.check()
+            kp, vp = self._cache
             # gather on a host VIEW of the pool (zero-copy on CPU),
             # not via jax fancy indexing — a traced gather pays full
             # dispatch overhead per export (measured 1.4 ms vs 0.02 ms
             # for the numpy take; at fleet rates that overhead was the
             # single largest migration cost).  numpy's take copies, so
             # the result is host-owned either way.
-            return (np.asarray(self._kp).take(ids, axis=1),
-                    np.asarray(self._vp).take(ids, axis=1),
+            return (np.asarray(kp).take(ids, axis=1),
+                    np.asarray(vp).take(ids, axis=1),
                     self._kv_guard.epoch)
 
     def import_blocks(self, blocks, k_pages, v_pages):
@@ -1290,6 +1389,7 @@ class GenerativeEngine:
         pre- or post-migration epoch, never a half-installed pool.
         Returns the post-install epoch (the MigrateKV handshake
         value)."""
+        self._kv_only("import_blocks")
         ids = np.asarray([int(b) for b in blocks], np.int32)
         if (ids == 0).any():
             raise ValueError("cannot import into reserved block 0")
@@ -1309,14 +1409,14 @@ class GenerativeEngine:
             # an install costs O(migrated pages), not a copy of the
             # whole pool (compiled per distinct block count — prompts
             # cluster on a handful of counts)
-            def _scatter(kp, vp, idx, kpg, vpg):
+            def _scatter(cache, idx, kpg, vpg):
+                kp, vp = cache
                 return kp.at[:, idx].set(kpg), vp.at[:, idx].set(vpg)
 
-            self._scatter_jit = jax.jit(_scatter,
-                                        donate_argnums=(0, 1))
+            self._scatter_jit = jax.jit(_scatter, donate_argnums=(0,))
         _, epoch = self._dispatch(
-            "migrate_in", lambda kp, vp: self._scatter_jit(
-                kp, vp, ids, k_pages, v_pages))
+            "migrate_in", lambda cache: self._scatter_jit(
+                cache, ids, k_pages, v_pages))
         return epoch
 
     def warm_role(self, role):
@@ -1346,9 +1446,15 @@ class GenerativeEngine:
             raise ValueError("unknown role %r" % (role,))
 
     def free_sequence(self, seq):
+        """Hand back what ``seq`` holds of the pool: its blocks and,
+        for a model with per-sequence state, its state slot."""
         seq_blocks, seq.blocks = seq.blocks, []
         if seq_blocks:
             self.pool.free(seq_blocks)
+        slot = getattr(seq, "slot", 0)
+        if slot:
+            seq.slot = 0
+            self.pool.return_slot(slot)
 
     @property
     def device(self):

@@ -40,6 +40,17 @@ Block 0 is RESERVED as the padding scratch block: bucket-padding rows
 of a decode batch point every block-table slot at it and write their
 (discarded) K/V there, so a padded dispatch never touches a live
 sequence's blocks.
+
+**State slots** (the second kind of state in this one manager): a
+model with recurrent layers carries per-sequence state of a fixed size
+(a conv window, an SSM state) beside its pages.  Such a pool is built
+with ``state_slots`` > 0 and hands each admitted sequence ONE slot
+(``take_slot``), held from admission to finish or preemption
+(``return_slot``); a row's index in the batch moves when a neighbour
+finishes, its slot does not.  Slot 0 is reserved like block 0: padding
+and dead rows aim their state reads and writes at it.
+``serve_state_slots_held`` is the live count,
+``serve_state_slot_waits_total`` the admissions that found none free.
 """
 from __future__ import annotations
 
@@ -87,6 +98,14 @@ M_PREEMPT = _metrics.counter(
     "serve_kv_preemptions_total",
     "sequences evicted (blocks freed, request requeued) because the "
     "block pool was exhausted")
+M_SLOTS_HELD = _metrics.gauge(
+    "serve_state_slots_held",
+    "per-sequence state slots (recurrent state of a model that has it) "
+    "held by admitted sequences; the reserved scratch slot not counted")
+M_SLOT_WAITS = _metrics.counter(
+    "serve_state_slot_waits_total",
+    "admissions that found no free state slot and went back to the "
+    "queue front")
 
 
 # live pools; the process gauges are recomputed ABSOLUTELY from this
@@ -100,10 +119,11 @@ _LIVE_LOCK = threading.Lock()
 def _refresh_gauges():
     with _LIVE_LOCK:
         pools = list(_LIVE)
-    used = shared = cached = hits = total = 0
+    used = shared = cached = hits = total = slots = 0
     for p in pools:
         total += p.capacity
-        u, s, c, h = p._gauge_snapshot()
+        u, s, c, h, held = p._gauge_snapshot()
+        slots += held
         used += u
         shared += s
         cached += c
@@ -113,6 +133,7 @@ def _refresh_gauges():
     M_SHARED.set(shared)
     M_CACHED.set(cached)
     M_PREFIX_HITS.set(hits)
+    M_SLOTS_HELD.set(slots)
 
 
 class BlockPool:
@@ -123,7 +144,8 @@ class BlockPool:
     of every live pool (multi-tenant processes read the sum, like
     every serve_* metric)."""
 
-    def __init__(self, num_blocks, block_size, register=True):
+    def __init__(self, num_blocks, block_size, register=True,
+                 state_slots=0):
         if num_blocks < 2:
             raise ValueError("kv pool needs >= 2 blocks (one is the "
                              "reserved padding block)")
@@ -136,6 +158,9 @@ class BlockPool:
         self._cacheable = set()        # park in _cached at refcount 0
         self._evict_cb = None          # prefix index invalidation hook
         self._prefix_hits = 0
+        # slot 0 reserved: the scratch target of padding and dead rows
+        self.state_slots = int(state_slots)
+        self._free_slots = list(range(self.state_slots - 1, 0, -1))
         self._lock = _san.make_lock("serve.kv_pool")
         if register:
             # register=False: a shadow pool (the speculative draft
@@ -156,7 +181,8 @@ class BlockPool:
             shared = sum(1 for r in self._ref.values() if r >= 2)
             cached = len(self._cached)
             hits = self._prefix_hits
-        return used, shared, cached, hits
+            slots = max(0, self.state_slots - 1 - len(self._free_slots))
+        return used, shared, cached, hits, slots
 
     @property
     def capacity(self):
@@ -377,6 +403,37 @@ class BlockPool:
     def note_preemption(self):
         M_PREEMPT.inc()
 
+    # -- per-sequence state slots ---------------------------------------
+
+    @property
+    def slots_held(self):
+        if not self.state_slots:
+            return 0
+        with self._lock:
+            return self.state_slots - 1 - len(self._free_slots)
+
+    def take_slot(self):
+        """A free state slot (>= 1) for a sequence being admitted, or
+        None (counted) when every one is held."""
+        with self._lock:
+            slot = self._free_slots.pop() if self._free_slots else None
+        if slot is None:
+            M_SLOT_WAITS.inc()
+        else:
+            _refresh_gauges()
+        return slot
+
+    def return_slot(self, slot):
+        """Hand ``slot`` back (finish, preemption, a failed admission).
+        What the device holds in it is dead: the next owner's prefill
+        overwrites all of it."""
+        slot = int(slot)
+        with self._lock:
+            if not 0 < slot < self.state_slots or slot in self._free_slots:
+                raise ValueError("state slot %d is not held" % slot)
+            self._free_slots.append(slot)
+        _refresh_gauges()
+
     def close(self):
         """Retire the pool from the process gauges (tenant unload) —
         without this, every load/unload cycle would leave phantom
@@ -388,6 +445,8 @@ class BlockPool:
             self._cacheable = set()
             self._prefix_hits = 0
             self.num_blocks = 1
+            self.state_slots = 0
+            self._free_slots = []
         with _LIVE_LOCK:
             if self in _LIVE:
                 _LIVE.remove(self)

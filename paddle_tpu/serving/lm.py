@@ -2,13 +2,19 @@
 pure jnp.
 
 serving/generative.py is the engine: pages, buckets, donation, the
-scheduler.  Everything that is the MODEL's is here, behind the five
+scheduler.  Everything that is the MODEL's is here, behind the
 things the engine asks of the config object it was given:
 
-- ``page_spec(n_blocks)``: shape and dtype of each of the two cache
-  arrays for ``n_blocks`` blocks.  The engine allocates from it,
-  ``import_blocks`` checks migrated pages against it and the fleet's
-  MigrateKV handshake is filled from it.
+- ``cache_spec(n_blocks)``: the cache as a tree of
+  ``jax.ShapeDtypeStruct``, which the engine allocates, donates whole
+  through every step and re-binds; this model's is the K/V pair.  The
+  forwards take the tree in ONE argument and hand it back.  (A model
+  with per-sequence state also says ``state_slots`` and takes
+  ``slots`` / ``slot`` in its forwards: serving/nemotron_h.py.)
+- ``page_spec(n_blocks)``: shape and dtype of each of the two page
+  arrays for ``n_blocks`` blocks, for a model whose cache is K/V pages
+  alone: ``import_blocks`` checks migrated pages against it and the
+  fleet's MigrateKV handshake is filled from it.
 - ``stage(params, quant, device)``: the parameters as the forwards
   take them, put on ``device``.
 - ``paged_forward``: R rows, each a token, a position and a block
@@ -153,6 +159,14 @@ class LMConfig:
         return ((self.n_layers, int(n_blocks), self.block_size,
                  self.n_heads, self.head_dim), np.float32)
 
+    def cache_spec(self, n_blocks):
+        """The cache the engine holds for this model: the K pool and
+        the V pool."""
+        import jax
+
+        shape, dtype = self.page_spec(n_blocks)
+        return (jax.ShapeDtypeStruct(shape, dtype),) * 2
+
     def stage(self, params, quant, device):
         """``params`` on ``device`` as the forwards take them:
         projection/MLP weights per-chunk int8 ``(q, scales)`` under
@@ -181,7 +195,7 @@ class LMConfig:
                 k.reshape(r, self.n_heads, self.head_dim),
                 v.reshape(r, self.n_heads, self.head_dim))
 
-    def paged_forward(self, p, kp, vp, toks, pos, tables, live=None):
+    def paged_forward(self, p, cache, toks, pos, tables, live=None):
         """R rows through the pages: row i feeds token ``toks[i]`` at
         position ``pos[i]`` of the sequence whose block table is
         ``tables[i]`` [R, NB]; a layer writes its K/V at ``(table[pos
@@ -191,11 +205,12 @@ class LMConfig:
         causality falls out of the page gather.  ``live`` [R] bool
         (None: every row) sends the other rows to position 0 and their
         writes to the reserved scratch block.  Returns hidden [R, D]
-        and the pools."""
+        and the cache."""
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import paged_attention
 
+        kp, vp = cache
         if live is not None:
             pos = jnp.where(live, pos, 0)
         h = p["embed"][toks] + p["pos"][pos]               # [R, D]
@@ -216,18 +231,19 @@ class LMConfig:
 
         for l in range(self.n_layers):
             h = _block_fwd(p, l, h, attend)
-        return h, kp, vp
+        return h, (kp, vp)
 
-    def prompt_forward(self, p, kp, vp, toks, length, block_ids):
+    def prompt_forward(self, p, cache, toks, length, block_ids):
         """A fresh (padded) prompt whole: ``toks`` [S], the first
         ``length`` real; causal flash attention over the in-flight
         K/V, every position's K/V written into the sequence's blocks
         ``block_ids`` [S // bs] (pad positions redirect to the reserved
-        scratch block).  Returns hidden [S, D] and the pools."""
+        scratch block).  Returns hidden [S, D] and the cache."""
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import flash_attention
 
+        kp, vp = cache
         s_len = toks.shape[0]
         pos = jnp.arange(s_len, dtype=jnp.int32)
         h = p["embed"][toks] + p["pos"][pos]               # [S, D]
@@ -251,7 +267,7 @@ class LMConfig:
 
         for l in range(self.n_layers):
             h = _block_fwd(p, l, h, attend)
-        return h, kp, vp
+        return h, (kp, vp)
 
     def head(self, p, h, n_live=None):
         """Final norm + logit layer: hidden [R, D] to fp32 logits

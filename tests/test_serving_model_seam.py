@@ -52,6 +52,9 @@ class ReluLM:
         return ((self.depth, n_blocks, self.block_size, 1, self.width),
                 np.float32)
 
+    def cache_spec(self, n_blocks):
+        return (jax.ShapeDtypeStruct(*self.page_spec(n_blocks)),) * 2
+
     def stage(self, params, quant, device):
         assert not quant
         return {k: jax.device_put(v, device) for k, v in params.items()}
@@ -65,9 +68,10 @@ class ReluLM:
             h = h + _dot(attend(l, q, k, v, kp, vp)[:, 0], p["o%d" % l])
             h = h + _dot(jax.nn.relu(_dot(_rms(h), p["up%d" % l])),
                          p["down%d" % l])
-        return h, kp, vp
+        return h, (kp, vp)
 
-    def paged_forward(self, p, kp, vp, toks, pos, tables, live=None):
+    def paged_forward(self, p, cache, toks, pos, tables, live=None):
+        kp, vp = cache
         if live is not None:
             pos = jnp.where(live, pos, 0)
         blk = tables[jnp.arange(toks.shape[0]), pos // self.block_size]
@@ -78,7 +82,8 @@ class ReluLM:
             lambda l, q, k, v, kp, vp: paged_attention(
                 q, kp, vp, tables, pos + 1, layer=l))
 
-    def prompt_forward(self, p, kp, vp, toks, length, block_ids):
+    def prompt_forward(self, p, cache, toks, length, block_ids):
+        kp, vp = cache
         pos = jnp.arange(toks.shape[0], dtype=jnp.int32)
         blk = jnp.where(pos < length, block_ids[pos // self.block_size], 0)
         return self._forward(p, kp, vp, toks, pos, blk,

@@ -156,14 +156,14 @@ def paged_step_module(compile_step, monkeypatch):
     try:
         # the step as _aot is handed it, before jit: traced here as
         # placed on a TPU, which the engine's own device is not
-        monkeypatch.setattr(eng, "_aot",
-                            lambda name, step, *specs: (step, specs))
+        monkeypatch.setattr(
+            eng, "_aot", lambda name, step, *specs: (eng._flat(step), specs))
         step, specs = getattr(eng, compile_step)(PAGED_STEPS[compile_step])
-        pool = sds(eng._kp.shape, f32)
+        pspec, cspec = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), (eng._params, eng._cache))
         return cfg, tpu_module(
-            step, jax.tree_util.tree_map(
-                lambda a: sds(a.shape, a.dtype), eng._params),
-            pool, pool, *[sds(shape, dtype) for shape, dtype in specs])
+            step, pspec, cspec,
+            *[sds(shape, dtype) for shape, dtype in specs])
     finally:
         eng.close()
 
@@ -190,6 +190,61 @@ def test_engine_steps_hand_the_kernel_the_whole_pool(compile_step,
     assert not re.search(r"tensor<(1x)?24x8x2x128xf32>", text)
 
 
+def stripped_module(text):
+    """A lowered module with what is no computation taken out: source
+    locations, and each Mosaic body decoded from its bytecode and
+    printed without them."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        raw = re.sub(r"\\([0-9A-Fa-f]{2})",
+                     lambda m: chr(int(m.group(1), 16)), match.group(1))
+        code = base64.b64decode(
+            json.loads(raw)["custom_call_config"]["body"])
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(code).operation.get_asm(
+                enable_debug_info=False)
+        return 'backend_config = "%s"' % hashlib.sha256(
+            asm.encode()).hexdigest()
+
+    text = re.sub(r'backend_config = "((?:[^"\\]|\\.)*)"', body, text)
+    text = re.sub(
+        r" ?loc\((?:[^()]|\((?:[^()]|\([^()]*\))*\))*\)", "", text)
+    return "\n".join(ln for ln in text.splitlines()
+                     if not ln.startswith("#loc"))
+
+
+# sha256 of stripped_module() of the two steps the serving cell runs,
+# taken at PR 31's commit (5d240f3) with this file's engine: the engine
+# now holds whatever tree of arrays the model's cache_spec names, and
+# for LMConfig (the K/V pair) its steps must lower to the program they
+# were, operand for operand and line for line
+PARENT_STEPS = {
+    "_compile_decode": ((4, 4), "eb99041b02c0d57a14e0b00b79f640c6"
+                                "62c2889b3caaa66f087b2dd0fbf81d79"),
+    "_compile_prefill": ((16,), "84d51817bea3d3838545bf0caf4bbce1"
+                                "76c98976ba608798b2379136c0367a7a"),
+}
+
+
+@pytest.mark.parametrize("compile_step", sorted(PARENT_STEPS))
+def test_lm_config_steps_lower_as_before_the_cache_tree(compile_step,
+                                                        monkeypatch):
+    import hashlib
+
+    key, want = PARENT_STEPS[compile_step]
+    monkeypatch.setitem(PAGED_STEPS, compile_step, key)
+    _, text = paged_step_module(compile_step, monkeypatch)
+    assert hashlib.sha256(
+        stripped_module(text).encode()).hexdigest() == want
+
+
 def test_decode_step_holds_one_mosaic_call_a_layer(monkeypatch):
     """``paged_attn_roofline.serve`` is read only where every run of
     ``jit_serve_decode`` holds exactly ``n_layers`` custom calls: a
@@ -198,6 +253,81 @@ def test_decode_step_holds_one_mosaic_call_a_layer(monkeypatch):
     cfg, text = paged_step_module("_compile_decode", monkeypatch)
     assert text.count("@tpu_custom_call") == cfg.n_layers
     assert dispatch.counts().get("paged_attention.pallas", 0) > 0
+
+
+@pytest.mark.parametrize("n_tok", [64, 512])
+def test_grouped_expert_matmul_lowers_at_the_hybrid_cells_widths(n_tok):
+    # nemotron3s-ep4-serve-reason-c64: 128 held experts of 1024 x 2688
+    # in 5 layers, top-22; a decode step's 64 rows and a prompt's 512
+    from paddle_tpu.kernels.moe_grouped import grouped_expert_ffn
+
+    assert mosaic_calls(
+        lambda x, ids, g, w1, w2: grouped_expert_ffn(x, ids, g, w1, w2,
+                                                     layer=3),
+        sds((n_tok, 1024), bf16), sds((n_tok, 22), i32),
+        sds((n_tok, 22), f32), sds((5, 128, 1024, 2688), bf16),
+        sds((5, 128, 2688, 1024), bf16)) == ["grouped_expert_ffn"]
+
+
+def test_state_update_lowers_at_the_hybrid_cells_widths():
+    # 65 slots of 128 heads x 64 x 128 in 5 layers, 64 rows, 8 groups
+    from paddle_tpu.kernels.ssm_update import ssm_state_update
+
+    assert mosaic_calls(
+        lambda st, sl, x, dt, a, b, c: ssm_state_update(
+            st, sl, x, dt, a, b, c, layer=4),
+        sds((5, 65, 128, 64, 128), f32), sds((64,), i32),
+        sds((64, 128, 64), f32), sds((64, 128), f32), sds((128,), f32),
+        sds((64, 8, 128), f32), sds((64, 8, 128), f32)) == [
+            "ssm_state_update"]
+
+
+@pytest.mark.parametrize("compile_step,key", [("_compile_decode", (4, 4)),
+                                              ("_compile_prefill", (16,))])
+def test_hybrid_steps_hand_their_kernels_the_whole_stacks(compile_step, key,
+                                                          monkeypatch):
+    """The served hybrid's two steps lowered for a TPU: one state update
+    a Mamba layer (decode), one grouped expert matmul an expert layer,
+    each on the whole stacked operand with the layer a scalar: nothing
+    in the module has one layer's experts or one layer's state as its
+    shape (a slice in front of a Mosaic call is a copy)."""
+    from paddle_tpu.serving import GenerativeEngine
+    from paddle_tpu.serving.nemotron_h import tiny_nemotron_h
+
+    cfg, params = tiny_nemotron_h(
+        3, pattern="MEM*E", vocab=64, hidden=256, n_heads=2, n_kv_heads=1,
+        head_dim=128, mamba_heads=4, mamba_head_dim=64, n_groups=2,
+        state=128, latent=128, expert_ff=256, shared_ff=256, n_experts=8,
+        experts_held=(4, 7), top_k=3, chunk=8, block_size=8, max_blocks=4,
+        max_batch=4)
+    eng = GenerativeEngine(cfg, params, kv_blocks=24, warm=False,
+                           prefix_cache=False, spec_k=0)
+    try:
+        monkeypatch.setattr(
+            eng, "_aot", lambda name, step, *specs: (eng._flat(step), specs))
+        step, specs = getattr(eng, compile_step)(key)
+        pspec, cspec = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), (eng._params, eng._cache))
+        text = tpu_module(step, pspec, cspec,
+                          *[sds(shape, dtype) for shape, dtype in specs])
+    finally:
+        eng.close()
+    names = re.findall(r'@tpu_custom_call\(.*?kernel_name = "(\w+)"', text)
+    decode = compile_step == "_compile_decode"
+    assert names.count("grouped_expert_ffn") == 2
+    assert names.count("ssm_state_update") == (2 if decode else 0)
+    assert names.count("flash_fwd") == (0 if decode else 1)
+    for ln in text.splitlines():
+        if "@tpu_custom_call" not in ln:
+            continue
+        if 'kernel_name = "grouped_expert_ffn"' in ln:
+            assert "tensor<2x4x128x256xbf16>" in ln
+            assert "tensor<2x4x256x128xbf16>" in ln
+        if 'kernel_name = "ssm_state_update"' in ln:
+            assert "tensor<2x5x4x64x128xf32>" in ln
+    assert not re.search(r"tensor<(1x)?4x128x256xbf16>", text)
+    assert not re.search(r"tensor<(1x)?5x4x64x128xf32>", text)
+    assert dispatch.counts().get("grouped_expert_ffn.pallas", 0) > 0
 
 
 def test_matmul_epilogue_and_add_ln_lower():
