@@ -376,10 +376,13 @@ def phase_serve_generate(target, meter, lm_kw, prompt_lens, new_tokens,
 
             # which kernels each compiled bucket took (the engine pads
             # matmul rows to 8, so the int8 kernel tiles in every bucket)
+            # (a fresh prompt's program is a decode step that carries
+            # it: a paged call and a flash call a layer)
             decode = engine.warm_decode_buckets
-            prefill = engine.prefill_ladder
+            prefill = engine.prompt_ladder
             kernels = kernel_delta(k0)
-            want = {"paged_attention.pallas": layers * len(decode),
+            want = {"paged_attention.pallas": layers * (len(decode)
+                                                         + len(prefill)),
                     "flash_attention.pallas": layers * len(prefill)}
             if quant:
                 want["matmul_int8_dequant.pallas"] = 4 * layers * (

@@ -334,6 +334,7 @@ def serve_rows(dumps):
             "gen_requests": val("serve_gen_requests_total"),
             "tokens": val("serve_tokens_total"),
             "prefills": val("serve_prefills_total"),
+            "prefill_rides": val("serve_prefill_rides_total"),
             "decode_steps": val("serve_decode_steps_total"),
             "decode_occupancy_pct": round(
                 100.0 * val("serve_decode_rows_total") / slots, 1)

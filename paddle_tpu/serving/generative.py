@@ -2,8 +2,9 @@
 
 The engine of the generate plane.  What it serves is the model behind
 the config object it was given (serving/lm.py: the page spec, the
-staging rule, the forward over paged rows, the forward over a fresh
-prompt, the logit layer); everything here is the engine's:
+staging rule, the forward over paged rows, the forward of a decode step
+that carries a fresh prompt, the logit layer); everything here is the
+engine's:
 
 - **Paged KV cache** — the tenant's cache lives as ONE device-
   resident tree of arrays that the model's ``cache_spec`` names
@@ -27,9 +28,12 @@ prompt, the logit layer); everything here is the engine's:
   step is always in flight: where the next step runs over the same
   rows it is dispatched on the device tokens of the one before, ahead
   of their read, so the device does not wait for the host between
-  steps (``DecodeLoop``).  Prefill is a
-  separate dispatch (its own power-of-2 sequence-length bucket ladder);
-  decode batches compile per power-of-2 ``(batch, block-count)`` bucket
+  steps (``DecodeLoop``).  An admitted prompt RIDES the decode step
+  dispatched next (one program and one weight stream for the running
+  rows and the newcomer, on a power-of-2 sequence-length ladder of its
+  own), so no row waits a prefill out; with no row running the same
+  program is a prefill alone.  Decode batches compile per power-of-2
+  ``(batch, block-count)`` bucket
   on the engine.StepCache ladder — ahead-of-time ``lower().compile()``
   at load for the warm set, background compile on a miss with traffic
   falling to the smallest covering bucket, exactly the ModelEngine
@@ -38,8 +42,10 @@ prompt, the logit layer); everything here is the engine's:
   (paged rows at each sequence's length), verify (the same on
   batch x (k+1) flattened rows), propose (k decodes chained in one
   program), the suffix prefill (rows ``start + i`` of one table) and
-  prefill (the prompt forward); each adds the logit layer and a greedy
-  argmax.
+  the decode step that carries a prompt (the riding forward; a
+  ``serve_decode`` like the plain one); each adds the logit layer and a
+  greedy argmax.  A model without the riding forward gets a prefill
+  step over its prompt forward in that one's place.
 
 Greedy decode is deterministic: a request admitted into a running
 decode batch produces bit-identical tokens to the same request run
@@ -110,6 +116,11 @@ _M_DECODE_AHEAD = _metrics.counter(
     "decode iterations dispatched while the one before was unread: "
     "their input tokens never left the device (ahead / steps is the "
     "share of steps whose dispatch the device did not wait for)")
+_M_PREFILL_RIDES = _metrics.counter(
+    "serve_prefill_rides_total",
+    "admitted prompts that rode a decode step: prefilled in the program "
+    "that decoded the running rows (rides / admissions is the share of "
+    "admissions no row waited a prefill out for)")
 _M_DECODE_ROWS = _metrics.counter("serve_decode_rows_total",
                                   "live sequences summed over decode "
                                   "iterations")
@@ -184,6 +195,10 @@ _M_ADMISSIONS = _metrics.counter(
     "preemption included)")
 
 _REQUEST_IDS = itertools.count(1)
+# the least bucket of a prompt that rides: a carrying step streams the
+# weights whatever it carries, so under this many rows a tighter bucket
+# would save nothing of the step and cost a program at every load
+_RIDE_FLOOR = 64
 _clock_ns = time.perf_counter_ns
 _FLUSH_NS = 10_000_000      # of loop wall time between counter flushes
 
@@ -525,13 +540,15 @@ class _DecodeFlight:
     """A dispatched decode step whose tokens the host has not read:
     ``nxt`` is the executable's ``(bb,)`` int32 output on the engine's
     device (``logits`` its ``[bb, V]`` one, where asked for), ``b`` the
-    live rows of the ``bb`` the batch bucket holds.  ``host`` is the
-    read-back once ``read`` has made it."""
+    live rows of the ``bb`` the batch bucket holds (a prompt that rode
+    the step is the last of them: its first token comes back in the row
+    it takes).  ``host`` is the read-back once ``read`` has made it."""
 
-    __slots__ = ("nxt", "logits", "stats", "b", "bb", "host")
+    __slots__ = ("nxt", "logits", "stats", "b", "bb", "host", "riding")
 
-    def __init__(self, nxt, logits, b, bb, stats=None):
+    def __init__(self, nxt, logits, b, bb, stats=None, riding=0):
         self.nxt, self.logits, self.b, self.bb = nxt, logits, b, bb
+        self.riding = riding    # the bucket of the prompt it carried
         # what the model counted in the step (a small device array, read
         # with the tokens), where it counts anything
         self.stats = stats
@@ -644,7 +661,7 @@ class GenerativeEngine:
                 [(b, self.draft.nb_top, self.spec_k)
                  for b in self.batch_ladder])
             self.draft._prefill.warm(
-                [(s,) for s in self.draft.prefill_ladder])
+                [(s,) for s in self.draft.prompt_ladder])
             self._verify.warm([(b, self.nb_top, self.spec_k + 1)
                                for b in self.batch_ladder])
 
@@ -689,8 +706,18 @@ class GenerativeEngine:
         self._decode_logits = StepCache(
             lambda key: self._compile_decode(key, with_logits=True),
             name=self.name + ".decode_logits")
-        self._prefill = StepCache(self._compile_prefill,
-                                  name=self.name + ".prefill")
+        # a model that offers the forward of a decode step carrying a
+        # prompt has its fresh prompts served by that one program, with
+        # rows running or none; one that does not, by the prompt forward
+        self.rides = hasattr(cfg, "riding_forward")
+        # `_prefill`'s buckets: of the sequence ladder, those a fresh
+        # prompt is staged in
+        self.prompt_ladder = [
+            s for s in self.prefill_ladder
+            if not self.rides or s >= min(_RIDE_FLOOR, cfg.max_seq)]
+        self._prefill = StepCache(
+            self._compile_ride if self.rides else self._compile_prefill,
+            name=self.name + ".prefill")
         # warmed only when their feature is on: suffix-only prefill
         # for prefix-cache hits, keyed by suffix bucket; batched verify
         # and the draft's fused proposal for speculative decode, keyed
@@ -711,7 +738,7 @@ class GenerativeEngine:
             # would pay a synchronous compile in some request's TTFT
             self._decode.warm([(b, self.nb_top)
                                for b in self.batch_ladder])
-            self._prefill.warm([(s,) for s in self.prefill_ladder])
+            self._prefill.warm([(s,) for s in self.prompt_ladder])
 
     def page_spec(self, n_blocks):
         """``(shape, dtype)`` of each of the K and V pools for
@@ -804,8 +831,50 @@ class GenerativeEngine:
             ((bb,), jnp.int32),
             ((bb,), jnp.int32), *slot_spec)
 
+    def _compile_ride(self, key):
+        """AOT-compile a decode step that carries a fresh prompt, at
+        the prompt's bucket ``(S,)``: the decode half is always the top
+        bucket ``(max_batch, nb_top)`` (the paged attention walks the
+        pages a row holds, so the table's width costs nothing; a dead
+        row aims at the scratch block and slot), the prompt's K/V goes
+        into its blocks, and its first token comes back in row ``row``
+        of the one ``[max_batch]`` vector of next tokens: the prompt's
+        last real position is that row of the logit layer's input.  The
+        same program with no live row is a prefill alone.  It is a
+        ``serve_decode`` like the plain step: its paged kernels are
+        ``%serve_decode.N`` in a trace, where the decode tokens it makes
+        are counted."""
+        import jax.numpy as jnp
+
+        model = self.config
+        (s_len,) = key
+        bb, nbb = model.max_batch, self.nb_top
+        n_ids = max(1, s_len // model.block_size)
+        slot_spec = ((((bb,), jnp.int32), ((), jnp.int32))
+                     if self.stateful else ())
+
+        def step(p, cache, tables, lens, toks, prompt, length, block_ids,
+                 row, *slots):
+            h, cache, *stats = model.riding_forward(
+                p, cache, toks, lens, tables, prompt, length, block_ids,
+                **({"slots": slots[0], "slot": slots[1]} if slots else {}))
+            last = jnp.take(h, bb + length - 1, axis=0)
+            nxt = _greedy(model.head(p, h[:bb].at[row].set(last)))
+            return (nxt, *stats, cache)
+
+        return self._aot(
+            "serve_decode", step,
+            ((bb, nbb), jnp.int32),
+            ((bb,), jnp.int32),
+            ((bb,), jnp.int32),
+            ((s_len,), jnp.int32),
+            ((), jnp.int32),
+            ((n_ids,), jnp.int32),
+            ((), jnp.int32), *slot_spec)
+
     def _compile_prefill(self, key):
-        """AOT-compile one prefill at sequence bucket ``(S,)``: the
+        """AOT-compile one prefill at sequence bucket ``(S,)``, for a
+        model without the riding forward: the
         whole (padded) prompt forward in one dispatch, every position's
         K/V written into the sequence's blocks, greedy first token from
         the last real position."""
@@ -941,25 +1010,63 @@ class GenerativeEngine:
 
     def _stage_rows(self, cache, blocks_list, lens_list, *tail):
         """Pick ``cache``'s ``(batch, block-count) + tail`` bucket for
-        these rows and pad their block tables and lengths up to it
-        (padding rows aim every table slot at the reserved scratch
-        block).  Returns ``(key, exe, tables, lens, pages held)``."""
+        these rows and pad them up to it (``_pad_rows``).  Returns
+        ``(key, exe, tables, lens, pages held)``."""
         cfg = self.config
-        b = len(blocks_list)
         nb = max(len(bl) for bl in blocks_list)
-        key, exe = cache.pick((pow2_bucket(b, cfg.max_batch),
+        key, exe = cache.pick((pow2_bucket(len(blocks_list), cfg.max_batch),
                                pow2_bucket(nb, self.nb_top)) + tail)
         if key[2:] != tail:
             raise RuntimeError("%s bucket %r does not end in %r"
                                % (cache.name, key, tail))
+        return (key, exe, *self._pad_rows(key, blocks_list, lens_list))
+
+    @staticmethod
+    def _pad_rows(key, blocks_list, lens_list):
+        """The rows' block tables and lengths padded up to bucket
+        ``key`` (padding rows aim every table slot at the reserved
+        scratch block): ``(tables, lens, pages held)``."""
         tables = np.zeros(key[:2], np.int32)
         lens = np.zeros(key[0], np.int32)
-        lens[:b] = lens_list
+        lens[:len(lens_list)] = lens_list
         pages = 0
         for i, bl in enumerate(blocks_list):
             tables[i, :len(bl)] = bl
             pages += len(bl)
-        return key, exe, tables, lens, pages
+        return tables, lens, pages
+
+    def _stage_prompt(self, cache, least, tokens, blocks, n_ids=None):
+        """Pick ``cache``'s sequence bucket for ``tokens``, of ``least``
+        rows or more, and pad them up to it; ``blocks`` fill a table of
+        ``n_ids`` slots (None: the bucket's own).  Returns ``(bucket,
+        exe, tokens, table)``."""
+        cfg = self.config
+        (s_len,), exe = cache.pick(
+            (pow2_bucket(max(len(tokens), least), cfg.max_seq),))
+        toks = np.zeros(s_len, np.int32)
+        toks[:len(tokens)] = tokens
+        # the sequence may hold MORE blocks than the table's slots
+        # (growth provisions ahead for speculative rounds); the extras
+        # hold positions past this prompt's writes
+        ids = np.zeros(n_ids or max(1, s_len // cfg.block_size), np.int32)
+        m = min(len(blocks), len(ids))
+        ids[:m] = blocks[:m]
+        return s_len, exe, toks, ids
+
+    def _check_prompt(self, n):
+        if n < 1:
+            raise ValueError("empty prompt")
+        if n > self.config.max_seq:
+            raise ValueError("prompt length %d exceeds max_seq %d "
+                             "(block_size x max_blocks)"
+                             % (n, self.config.max_seq))
+
+    def fresh_prefill(self, seq):
+        """Whether ``seq``'s prompt is to be run whole (no part of it
+        comes from the prefix cache): such a prompt can ride a decode
+        step."""
+        return not (self.prefix_cache is not None
+                    and 0 < getattr(seq, "cached_len", 0) < len(seq.prompt))
 
     def prefill(self, seq, _tr=None):
         """Run ``seq``'s prompt through the prefill bucket that fits it;
@@ -970,16 +1077,9 @@ class GenerativeEngine:
         where the caller found its spans live (DecodeLoop asks once an
         iteration), else None: the ``serve.prefill`` span then carries
         the request's id as its cid."""
-        cfg = self.config
         n = len(seq.prompt)
-        if n < 1:
-            raise ValueError("empty prompt")
-        if n > cfg.max_seq:
-            raise ValueError("prompt length %d exceeds max_seq %d "
-                             "(block_size x max_blocks)" % (n, cfg.max_seq))
-        cached = getattr(seq, "cached_len", 0)
-        if self.prefix_cache is None or not 0 < cached < n:
-            cached = 0
+        self._check_prompt(n)
+        cached = 0 if self.fresh_prefill(seq) else seq.cached_len
         tok = self.prefill_tokens(seq.prompt, seq.blocks, _tr,
                                   getattr(seq, "rid", None), start=cached,
                                   slot=getattr(seq, "slot", 0))
@@ -999,45 +1099,43 @@ class GenerativeEngine:
         speculative draft's re-prefill path uses this directly (its
         catch-up feed is tokens, not a GenRequest).  ``slot`` is the
         sequence's state slot, an operand of a stateful model's prefill
-        only (0: the scratch slot).  ``serve.prefill``
+        only (0: the scratch slot).  A whole prompt of a model with the
+        riding forward goes through that program with no live row
+        (``decode_dispatch``).  ``serve.prefill``
         spans staging, dispatch and the wait for the token; its child
         ``serve.prefill.dispatch`` the dispatch."""
         cfg = self.config
         n = len(tokens)
-        count = n - start
-        if start:
-            cache, op = self._prefill_cached, "prefill_cached"
-            span_args = {"tokens": n, "cached": start}
-        else:
-            cache, op, span_args = self._prefill, "prefill", {"tokens": n}
-        sp = (_tr.begin("serve.prefill", _cid, span_args)
+        sp = (_tr.begin("serve.prefill", _cid,
+                        {"tokens": n, "cached": start} if start
+                        else {"tokens": n})
               if _tr is not None else None)
-        want = pow2_bucket(max(count, cfg.block_size), cfg.max_seq)
-        key, exe = cache.pick((want,))
-        (s_len,) = key
-        toks = np.zeros(s_len, np.int32)
-        toks[:count] = tokens[start:]
-        if start:   # reads the whole table, through the cached prefix
-            n_ids, lead = cfg.max_blocks, (np.int32(start), np.int32(count))
-        else:       # writes its bucket's blocks
-            n_ids, lead = max(1, s_len // cfg.block_size), (np.int32(n),)
-        # the sequence may hold MORE blocks than the table's slots
-        # (growth provisions ahead for speculative rounds); the extras
-        # hold positions past this prefill's writes
-        ids = np.zeros(n_ids, np.int32)
-        m = min(len(blocks), n_ids)
-        ids[:m] = blocks[:m]
-        tail = (np.int32(slot),) if self.stateful else ()
         spd = (_tr.begin("serve.prefill.dispatch", _cid)
                if sp is not None else None)
-        (nxt,), _ = self._dispatch(
-            op, lambda cache: exe(self._params, cache, toks, *lead, ids,
-                                  *tail))
-        if sp is None:
-            return int(nxt)
-        _tr.end(spd)
-        tok = int(nxt)      # blocks until the device has the token
-        _tr.end(sp, args={"bucket": s_len})
+        if self.rides and not start:
+            flight = self.decode_dispatch((), (), (),
+                                          rider=(tokens, blocks, slot))
+            s_len, nxt = flight.riding, flight.nxt     # row 0 is its token
+        else:
+            if start:   # reads the whole table, through the cached prefix
+                s_len, exe, toks, ids = self._stage_prompt(
+                    self._prefill_cached, cfg.block_size, tokens[start:],
+                    blocks, cfg.max_blocks)
+                op = "prefill_cached"
+                lead = (np.int32(start), np.int32(n - start))
+            else:       # writes its bucket's blocks
+                s_len, exe, toks, ids = self._stage_prompt(
+                    self._prefill, self.prompt_ladder[0], tokens, blocks)
+                op, lead = "prefill", (np.int32(n),)
+            tail = (np.int32(slot),) if self.stateful else ()
+            (nxt,), _ = self._dispatch(
+                op, lambda cache: exe(self._params, cache, toks, *lead, ids,
+                                      *tail))
+        if sp is not None:
+            _tr.end(spd)
+        tok = int(np.ravel(nxt)[0])     # blocks until the device has it
+        if sp is not None:
+            _tr.end(sp, args={"bucket": s_len})
         return tok
 
     def copy_block(self, src, dst):
@@ -1064,22 +1162,34 @@ class GenerativeEngine:
         return self.decode_read(
             self.decode_start(seqs, with_logits=with_logits))
 
-    def decode_start(self, seqs, after=None, with_logits=False, _tr=None):
+    def decode_start(self, seqs, after=None, with_logits=False, _tr=None,
+                     rider=None):
         """Dispatch one decode iteration over ``seqs`` and advance their
         contexts; the tokens stay on the device behind the returned
         flight (``decode_read``).  ``after`` is the unread flight of the
         step before, over these sequences in this order: its tokens are
         this step's input where they are.  Without it the input is each
-        sequence's last token as the host knows it."""
+        sequence's last token as the host knows it.  ``rider`` is a
+        sequence admitted with its blocks whose fresh prompt rides this
+        step: its first token is the flight's row ``len(seqs)``, the row
+        it takes once the caller has put it after ``seqs``."""
         toks = after if after is not None else [
             s.out[-1] if s.out else (s.prompt[-1] if s.prompt else 0)
             for s in seqs]
+        if rider is not None:
+            self._check_prompt(len(rider.prompt))
         flight = self.decode_dispatch(
             [s.blocks for s in seqs], [s.context_len for s in seqs],
             toks, with_logits=with_logits, _count=True, _tr=_tr,
-            slots=[s.slot for s in seqs] if self.stateful else None)
+            slots=[s.slot for s in seqs] if self.stateful else None,
+            rider=rider and (rider.prompt, rider.blocks, rider.slot),
+            _cid=rider and rider.rid)
         for s in seqs:
             s.context_len += 1
+        if rider is not None:
+            rider.context_len = len(rider.prompt)
+            if _batcher._METRICS_ON:
+                _M_PREFILLS.inc()
         return flight
 
     def decode_step(self, blocks_list, lens_list, toks_list,
@@ -1101,7 +1211,7 @@ class GenerativeEngine:
 
     def decode_dispatch(self, blocks_list, lens_list, toks,
                         with_logits=False, _count=False, _tr=None,
-                        slots=None):
+                        slots=None, rider=None, _cid=None):
         """Stage and dispatch one decode step; returns its
         ``_DecodeFlight`` without waiting for the device.  Two phases,
         each a span where ``_tr`` is given: ``serve.decode.stage``
@@ -1117,13 +1227,30 @@ class GenerativeEngine:
         them in between, so this dispatch does not wait for that step.
         Any other flight is read here first.  ``slots`` is one state
         slot a row, for a model with per-sequence state (left out, every
-        row aims at the scratch slot)."""
-        sp = (_tr.begin("serve.decode.stage")
+        row aims at the scratch slot).
+
+        ``rider`` is a fresh prompt carried by the step, ``(tokens,
+        blocks, state slot)``: the step is then the riding program of
+        the prompt's bucket, its decode half the top bucket whatever the
+        rows (there may be none: a prefill alone), and the prompt's
+        first token is one more live row of the flight, after the
+        others.  The spans then say ``riding``, the prompt's bucket,
+        and carry ``_cid``, its request's id."""
+        sp = (_tr.begin("serve.decode.stage", _cid)
               if _tr is not None else None)
         b = len(blocks_list)
-        key, exe, tables, lens, pages = self._stage_rows(
-            self._decode_logits if with_logits else self._decode,
-            blocks_list, lens_list)
+        riding, carried = 0, ()
+        if rider is None:
+            key, exe, tables, lens, pages = self._stage_rows(
+                self._decode_logits if with_logits else self._decode,
+                blocks_list, lens_list)
+        else:
+            prompt, p_blocks, p_slot = rider
+            riding, exe, p_toks, p_ids = self._stage_prompt(
+                self._prefill, self.prompt_ladder[0], prompt, p_blocks)
+            key = (self.config.max_batch, self.nb_top)
+            tables, lens, pages = self._pad_rows(key, blocks_list, lens_list)
+            carried = (p_toks, np.int32(len(prompt)), p_ids, np.int32(b))
         bb, nbb = key
         ahead = False
         if isinstance(toks, _DecodeFlight):
@@ -1139,21 +1266,27 @@ class GenerativeEngine:
             tail = (np.zeros(bb, np.int32),)
             if slots is not None:
                 tail[0][:b] = slots
+            if rider is not None:
+                tail += (np.int32(p_slot),)
         if sp is not None:
-            bucket = "%dx%d" % key
-            _tr.end(sp, args={"bucket": bucket})
-            sp = _tr.begin("serve.decode", None,
-                           {"bucket": bucket, "rows": b, "pages": pages,
-                            "ahead": int(ahead)})
+            args = {"bucket": "%dx%d" % key}
+            if riding:
+                args["riding"] = riding
+            _tr.end(sp, args=args)
+            sp = _tr.begin("serve.decode", _cid,
+                           dict(args, rows=b, pages=pages,
+                                ahead=int(ahead)))
         (nxt, *rest), _ = self._dispatch(
-            "decode", lambda cache: exe(self._params, cache,
-                                        tables, lens, operand, *tail))
+            "decode", lambda cache: exe(self._params, cache, tables, lens,
+                                        operand, *carried, *tail))
         logits = rest.pop(0) if with_logits else None
         stats = rest[0] if rest else None
         if _count and _batcher._METRICS_ON:
             _M_DECODE_STEPS.inc()
             if ahead:
                 _M_DECODE_AHEAD.inc()
+            if riding:
+                _M_PREFILL_RIDES.inc()
             _M_DECODE_ROWS.inc(b)
             _M_DECODE_SLOTS.inc(bb)
             _M_DECODE_PAGES.inc(pages)
@@ -1161,7 +1294,9 @@ class GenerativeEngine:
             _M_OCC_PCT.observe(100.0 * b / bb)
         if sp is not None:
             _tr.end(sp)
-        return _DecodeFlight(nxt, logits, b, bb, stats)
+        # a rider's first token is one more row to read, the row it takes
+        return _DecodeFlight(nxt, logits, b + (rider is not None), bb, stats,
+                             riding)
 
     def decode_read(self, flight, _tr=None, _times=None):
         """The tokens of a dispatched decode step, one a live row (and
@@ -1425,7 +1560,7 @@ class GenerativeEngine:
         the other ladder just slows its start.  ``role`` is
         ``'prefill'`` or ``'decode'``."""
         if role == "prefill":
-            self._prefill.warm([(s,) for s in self.prefill_ladder])
+            self._prefill.warm([(s,) for s in self.prompt_ladder])
         elif role == "decode":
             # the FULL (batch, block-count) grid, not just the top
             # block-count bucket: a fleet decode worker serves whatever
@@ -1441,7 +1576,7 @@ class GenerativeEngine:
             # fallback runs a full local generate, and the solo-floor
             # worker serves whole requests — a cold prefill bucket there
             # is a synchronous compile inside someone's TTFT
-            self._prefill.warm([(s,) for s in self.prefill_ladder])
+            self._prefill.warm([(s,) for s in self.prompt_ladder])
         else:
             raise ValueError("unknown role %r" % (role,))
 
@@ -1495,13 +1630,24 @@ class DecodeLoop:
       straight into step n+1; the host's work between two steps runs
       beside it.
     - **drain**, anything else: read step n and emit first, then admit
-      queued prefills the block pool can hold (TokenScheduler),
+      the queued requests the block pool can hold (TokenScheduler),
       grow/preempt for sequences crossing a block boundary and dispatch
       the step from the host's tokens.  A speculative round is read
       inside its own iteration and leaves nothing in flight.
+    - **carry**: an admitted request whose prompt is to be run whole,
+      of a model with the riding forward, is not prefilled while rows
+      are running: it waits in admission order (``_waiting``, holding
+      its blocks and slot) and its prompt RIDES the next drained step,
+      one prompt a step: one program, one weight stream, the running
+      rows' next tokens and the newcomer's first token in one vector,
+      in flight like any step, and the step after it can run ahead.  No
+      row waits a prefill out.  What does not ride is prefilled inside
+      the iteration, the loop waiting for its token: a prompt that
+      meets no running row, a prefix-cache hit's suffix, a speculative
+      tenant's prompts, a model without that forward.
 
-    Tokens are delivered one a step, when the device has them, in both
-    orders.  The loop must survive anything — a dead loop wedges the
+    Tokens are delivered one a step, when the device has them, in all
+    three.  The loop must survive anything — a dead loop wedges the
     tenant with unresolved futures (the PR 9 dispatcher rule)."""
 
     def __init__(self, engine, queue, label=""):
@@ -1515,6 +1661,9 @@ class DecodeLoop:
         # the decode step dispatched and not yet read, with the rows it
         # runs over in its order: (_DecodeFlight, [GenRequest]) or None
         self._flight = None
+        # admitted requests whose prompts have not been run, oldest
+        # first: each holds its blocks (and slot) and a row of the batch
+        self._waiting = []
         self._stop = _san.make_event("generative.decode.stop")
         self._thread = threading.Thread(
             target=self._loop, daemon=True,
@@ -1532,7 +1681,7 @@ class DecodeLoop:
     def _loop(self):
         running = []
         while True:
-            if not running:
+            if not running and not self._waiting:
                 self._times.flush()
                 with TRACER.span("serve.idle"):
                     req = self.queue.get(timeout=0.25)
@@ -1607,16 +1756,16 @@ class DecodeLoop:
         # just-allocated blocks — it must not leak pool capacity or
         # take the rest of the batch down with it
         sp = trc.begin("serve.admit") if trc is not None else None
-        admitted = self.scheduler.try_admit(self.queue, len(running))
+        admitted = self.scheduler.try_admit(
+            self.queue, len(running) + len(self._waiting))
         if admitted and times is not None:
             now = time.perf_counter()
             _M_ADMISSIONS.inc(len(admitted))
             _M_QUEUE_WAIT_US.inc(int(1e6 * sum(
                 now - req.t_arrival for req in admitted)))
-        joining = []    # (request, still needs its prefill), in order
         for req in admitted:
             if not (req.blocks and req.context_len):
-                joining.append((req, True))
+                self._waiting.append(req)
                 continue
             # migrated-in (serving/fleet.py): the prompt's KV pages
             # are already resident and `out` holds the first token
@@ -1628,34 +1777,30 @@ class DecodeLoop:
                 if not req.future.done():
                     req.future.set_result(req.result())
                 continue
-            joining.append((req, False))
+            running.append(req)
         if sp is not None:
             trc.end(sp, args={"admitted": len(admitted)})
-        for req, needs_prefill in joining:
-            if needs_prefill:
+        # the oldest waiting prompt rides this iteration's step where
+        # it can; one that cannot is prefilled here, and waited for
+        while self._waiting and not (running
+                                     and self._rides(self._waiting[0])):
+            req = self._waiting.pop(0)
+            if times is not None:
+                now = _clock_ns()
+                times.host += now - times.mark
+                times.mark = now
+            try:
+                tok = self.engine.prefill(req, trc)
+            except Exception as e:
+                self._fail(req, e)
+                continue
+            finally:
                 if times is not None:
                     now = _clock_ns()
-                    times.host += now - times.mark
+                    times.prefill += now - times.mark
                     times.mark = now
-                try:
-                    tok = self.engine.prefill(req, trc)
-                except Exception as e:
-                    self.engine.free_sequence(req)
-                    if not req.future.done():
-                        req.future.set_exception(e)
-                    continue
-                finally:
-                    if times is not None:
-                        now = _clock_ns()
-                        times.prefill += now - times.mark
-                        times.mark = now
-                if self.engine.prefix_cache is not None:
-                    # index the fully-written prompt blocks — the NEXT
-                    # request sharing this prefix admits against them
-                    self.engine.prefix_cache.insert(req)
-            running.append(req)
-            if needs_prefill:
-                self._emit_all(((req, tok),), running, trc)
+            self._joined(req, running)
+            self._emit_all(((req, tok),), running, trc)
         if not running:
             return
         # this iteration runs speculatively when every sequence has
@@ -1687,12 +1832,45 @@ class DecodeLoop:
                 seq.context_len -= len(toks)
                 pairs.extend((seq, int(tok)) for tok in toks)
             self._emit_all(pairs, running, trc, replay=True)
-        else:
-            self._flight = (self.engine.decode_start(running, _tr=trc),
-                            list(running))
+            return
+        step = None
+        if self._waiting:
+            rider = self._waiting.pop(0)
+            try:
+                step = self.engine.decode_start(running, rider=rider,
+                                                _tr=trc)
+            except Exception as e:
+                self._fail(rider, e)    # alone: the rows step without it
+            else:
+                self._joined(rider, running)
+        if step is None:
+            step = self.engine.decode_start(running, _tr=trc)
+        self._flight = (step, list(running))
 
     def _speculative(self):
         return self.engine.spec_k > 0 and self.engine.draft is not None
+
+    def _rides(self, req):
+        """Whether ``req``'s prompt can ride a decode step: the model
+        offers the forward, the prompt is to be run whole, and steps
+        are plain decode steps."""
+        return (self.engine.rides and not self._speculative()
+                and self.engine.fresh_prefill(req))
+
+    def _joined(self, req, running):
+        """``req``'s prompt is dispatched: it takes the next row."""
+        running.append(req)
+        if self.engine.prefix_cache is not None:
+            # index the fully-written prompt blocks — the NEXT
+            # request sharing this prefix admits against them
+            self.engine.prefix_cache.insert(req)
+
+    def _fail(self, req, e):
+        """``req``'s prefill failed: that request alone, and what it
+        held of the pool comes back."""
+        self.engine.free_sequence(req)
+        if not req.future.done():
+            req.future.set_exception(e)
 
     def _may_run_ahead(self, rows, running, trc):
         """Whether the step after the one in flight over ``rows`` can be
@@ -1701,13 +1879,15 @@ class DecodeLoop:
         comes between the two.  So not when the step in flight ends a
         row by count (``max_new``, ``max_seq``: known before the read;
         an EOS is not, and costs one dead row), a request waits for a
-        free row, the rounds are speculative (acceptance reads the
+        free row, an admitted prompt waits for a step to ride (it rides
+        a drained one), the rounds are speculative (acceptance reads the
         tokens), the loop is stopping, or a row cannot grow without a
         preemption.  Rows that could grow keep their new blocks: the
         drained step writes the same positions."""
         if (len(rows) != len(running)
                 or any(a is not b for a, b in zip(rows, running))
                 or self._speculative() or self._stop.is_set()
+                or self._waiting
                 or (len(running) < self.scheduler.max_batch
                     and len(self.queue))):
             return False
@@ -1720,9 +1900,9 @@ class DecodeLoop:
     def _grow(self, running, need, trc, preempt=True):
         """Provision every running sequence for the ``need`` positions
         its next step writes.  With ``preempt`` a sequence the pool
-        cannot grow evicts the youngest (or fails, alone in an empty
-        pool); without, the first such sequence ends the attempt and
-        False comes back."""
+        cannot grow evicts the youngest, a waiting prompt before a row
+        (or fails, alone in an empty pool); without, the first such
+        sequence ends the attempt and False comes back."""
         sp = trc.begin("serve.grow") if trc is not None else None
         preempted = 0
         grown = True
@@ -1738,7 +1918,10 @@ class DecodeLoop:
                 if not preempt:
                     grown = False
                     break
-                victim = self.scheduler.pick_victim(running, seq)
+                # a prompt that waits for its step is younger than
+                # every row
+                victim = self.scheduler.pick_victim(
+                    running + self._waiting, seq)
                 if victim is None:
                     self.engine.free_sequence(seq)
                     running.remove(seq)
@@ -1823,7 +2006,7 @@ class DecodeLoop:
         it at the FRONT (it keeps its arrival stamp and re-admits
         before newer requests), and let greedy determinism regenerate
         its tokens on re-admission."""
-        running.remove(victim)
+        (running if victim in running else self._waiting).remove(victim)
         self.engine.free_sequence(victim)
         victim.reset()
         victim.preempted += 1

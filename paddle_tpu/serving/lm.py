@@ -22,17 +22,29 @@ things the engine asks of the config object it was given:
   Decode is R = batch, a speculative verify R = batch x (k+1), the
   draft's proposal k of them chained, a prefix-cache hit's suffix
   prefill R rows of one sequence.
-- ``prompt_forward``: a fresh prompt whole, causal flash attention over
-  the in-flight K/V.
+- ``riding_forward``: a decode step that carries a fresh prompt:
+  ``paged_forward``'s R rows and the S rows of one prompt through every
+  weight product together, parted only at the attention (the R rows
+  through the pages, the S rows through causal flash attention over
+  the in-flight K/V), the K/V of both written in the same call; hidden
+  ``[R + S, D]``.  The engine compiles it by the prompt's bucket with
+  the decode half at its top bucket, under the decode step's name, and
+  serves every fresh prompt with it: riding the step of the running
+  rows (``DecodeLoop``), or with no live row, a prefill alone.  A model
+  may leave it out and offer ``prompt_forward(p, cache, toks, length,
+  block_ids)``, a fresh prompt whole, instead: the engine then compiles
+  that as its prefill and the loop waits every prefill out
+  (tests/test_serving_model_seam.py serves such a model).
 - ``head``: hidden rows to logits.
 
 and the paging geometry the engine reads as plain attributes
 (``vocab``, ``block_size``, ``max_blocks``, ``max_batch``,
-``max_seq``).  Neither forward is a ``jax.jit`` or a ``named_scope`` of
+``max_seq``).  No forward is a ``jax.jit`` or a ``named_scope`` of
 its own: they trace into the engine's step, whose name the executable
 and every Mosaic call in it carry (the benchmark's
 ``paged_attn_roofline.serve`` finds the decode kernel as
-``%serve_decode.N``).
+``%serve_decode.N``, in the plain step and in the one that carries a
+prompt; the flash kernel has its own name, ``%flash_fwd.N``).
 
 **int8 weight-quantized decode** is gated per tenant at load
 (``quant='int8'``): the projection and MLP weights (``_QUANT_SLOTS``)
@@ -233,37 +245,46 @@ class LMConfig:
             h = _block_fwd(p, l, h, attend)
         return h, (kp, vp)
 
-    def prompt_forward(self, p, cache, toks, length, block_ids):
-        """A fresh (padded) prompt whole: ``toks`` [S], the first
-        ``length`` real; causal flash attention over the in-flight
-        K/V, every position's K/V written into the sequence's blocks
-        ``block_ids`` [S // bs] (pad positions redirect to the reserved
-        scratch block).  Returns hidden [S, D] and the cache."""
+    def riding_forward(self, p, cache, toks, pos, tables, prompt, length,
+                       block_ids):
+        """A decode step that carries a fresh prompt: the R rows of
+        ``paged_forward`` (``toks``, ``pos``, ``tables``; a dead row
+        sits at position 0 of a table of scratch blocks) and the S rows
+        of one (padded) prompt, the first ``length`` real, whose K/V
+        goes into the blocks ``block_ids`` [S // bs] (pad positions to
+        the scratch block).  Every weight product runs once over the
+        ``R + S`` rows; they part only at the attention: the R rows go
+        through the pages, the S rows through causal flash attention
+        over the in-flight K/V, both written in the same scatter.
+        Returns hidden [R + S, D], the rows' then the prompt's, and the
+        cache."""
         import jax.numpy as jnp
 
-        from paddle_tpu.kernels.flash_attention import flash_attention
+        from paddle_tpu.kernels.flash_attention import (flash_attention,
+                                                        paged_attention)
 
         kp, vp = cache
-        s_len = toks.shape[0]
-        pos = jnp.arange(s_len, dtype=jnp.int32)
-        h = p["embed"][toks] + p["pos"][pos]               # [S, D]
-        live = pos < length
-        blk = jnp.where(live, block_ids[pos // self.block_size], 0)
-        off = pos % self.block_size
+        r, s_len = toks.shape[0], prompt.shape[0]
+        at = jnp.arange(s_len, dtype=jnp.int32)
+        both = jnp.concatenate([pos, at])
+        h = p["embed"][jnp.concatenate([toks, prompt])] + p["pos"][both]
+        blk = jnp.concatenate([
+            tables[jnp.arange(r), pos // self.block_size],
+            jnp.where(at < length, block_ids[at // self.block_size], 0)])
+        off = both % self.block_size
 
         def attend(l, qkv):
             nonlocal kp, vp
             q, k, v = self._split_heads(qkv)
             kp = kp.at[l, blk, off].set(k)
             vp = vp.at[l, blk, off].set(v)
-            # causal attention over the in-flight K/V (same values
-            # just written to the pages): rows < length only see
-            # columns <= their own position, all real
-            q4 = q.transpose(1, 0, 2)[None]
-            k4 = k.transpose(1, 0, 2)[None]
-            v4 = v.transpose(1, 0, 2)[None]
-            att = flash_attention(q4, k4, v4, causal=True)[0]
-            return att.transpose(1, 0, 2).reshape(s_len, self.d_model)
+            paged = paged_attention(q[:r], kp, vp, tables, pos + 1, layer=l)
+            # the prompt's rows see the columns up to their own, in
+            # flight: the values just written to its pages
+            q4, k4, v4 = (x[r:].transpose(1, 0, 2)[None] for x in (q, k, v))
+            fresh = flash_attention(q4, k4, v4, causal=True)[0]
+            return jnp.concatenate(
+                [paged, fresh.transpose(1, 0, 2)]).reshape(-1, self.d_model)
 
         for l in range(self.n_layers):
             h = _block_fwd(p, l, h, attend)

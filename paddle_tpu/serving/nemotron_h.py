@@ -22,8 +22,13 @@ the whole of it between the two on every step.
 
 A sequence holds ONE slot from admission to finish or preemption
 (kv_cache.BlockPool hands it out); the engine passes the rows' slots to
-``paged_forward`` and the sequence's to ``prompt_forward``.  Slot 0 is
-scratch, as block 0 is: padding rows aim at it.
+``paged_forward`` and, with them, the newcomer's to ``riding_forward``
+(the decode step that carries a fresh prompt: one ``in_proj`` and one
+``out_proj`` a Mamba layer, one router, one pair of latent projections,
+one grouped-expert call and one shared expert an expert layer, one
+``wqkv`` and ``wo`` an attention layer, over the rows of both kinds;
+they part between ``_mamba_in`` and ``_mamba_out`` and at the
+attention).  Slot 0 is scratch, as block 0 is: padding rows aim at it.
 
 Block ``i`` of kind ``pattern[i]``: ``h <- h + mixer_i(RMSNorm_i(h))``
 (benchmark/lib/reference_nemotron_h.py has the equations in plain
@@ -45,7 +50,8 @@ accumulation in every product; float32 norm statistics, router (logits,
 sigmoid, top-k, weights), softplus / exp / decay, conv window, SSM state
 and its update, softmax and logits.
 
-Counters, a decode step (counted on the device, returned beside the
+Counters, a decode step, a carried prompt's rows with the others (counted
+on the device, returned beside the
 step's tokens and read with them, so that dispatch-ahead keeps running):
 ``serve_moe_pairs_total`` (token-expert pairs computed here),
 ``serve_moe_experts_hit_total`` (held experts that got a token, summed
@@ -215,16 +221,15 @@ class NemotronHConfig:
         y = _rms(y * jax.nn.silu(z), p["M.gnorm"][l], groups=self.n_groups)
         return _mm(y, p["M.out_proj"][l])
 
-    def _mamba_decode(self, p, l, u, cache, slots):
+    def _mamba_step(self, p, l, xbc, dt, cache, slots):
         """One token a row: the window and the state of each row's slot
-        read, advanced and written back."""
+        read, advanced and written back.  Returns (y, x) [R, H, P]."""
         import jax
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.ssm_update import ssm_state_update
 
-        z, xbc, dt = self._mamba_in(p, l, u)
-        r = u.shape[0]
+        r = xbc.shape[0]
         win = cache["conv"][l, slots].reshape(r, -1, self.conv_dim)
         w = p["M.conv_w"][l]
         conv = (jnp.einsum("rkc,kc->rc", win, w[:-1])
@@ -237,19 +242,19 @@ class NemotronHConfig:
         y, cache["ssm"] = ssm_state_update(
             cache["ssm"], slots, x, dt, -jnp.exp(p["M.A_log"][l]), b, c,
             layer=l)
-        return self._mamba_out(p, l, y, x, z)
+        return y, x
 
-    def _mamba_prompt(self, p, l, u, cache, length, slot):
+    def _mamba_scan(self, p, l, xbc, dt, cache, length, slot):
         """A fresh prompt [S] as a chunked scan; the slot gets the window
-        and the state after ``length`` tokens."""
+        and the state after ``length`` tokens.  Returns (y, x)
+        [S, H, P]."""
         import jax
         import jax.numpy as jnp
         from jax import lax
 
         hi = lax.Precision.HIGHEST
-        s_len = u.shape[0]
+        s_len = xbc.shape[0]
         k = self.conv_kernel
-        z, xbc, dt = self._mamba_in(p, l, u)
         padded = jnp.pad(xbc, ((k - 1, 0), (0, 0)))
         w = p["M.conv_w"][l]
         conv = sum(padded[j:j + s_len] * w[j] for j in range(k))
@@ -293,8 +298,7 @@ class NemotronHConfig:
             "cihn,chpn->cihp", jnp.repeat(cc, per, axis=2), before,
             precision=hi)
         cache["ssm"] = cache["ssm"].at[l, slot].set(last)
-        return self._mamba_out(
-            p, l, y.reshape(s_len, h, self.mamba_head_dim), x, z)
+        return y.reshape(s_len, h, self.mamba_head_dim), x
 
     def _qkv(self, p, l, u):
         t = u.shape[0]
@@ -329,11 +333,13 @@ class NemotronHConfig:
                                          p["E.s2"][l])
         return out, jnp.sum(held).astype(jnp.int32), hit
 
-    def _blocks(self, p, h, cache, mamba, attend):
+    def _blocks(self, p, h, cache, mix, attend):
         """Every block in order on the residual stream ``h`` [T, d]
-        (bfloat16); ``mamba(l, u, cache)`` and ``attend(l, q, k, v,
-        cache)`` are the mode's own.  Returns (h, cache, [pairs, experts
-        hit])."""
+        (bfloat16).  The weight products run over all T rows; what lies
+        between them is the mode's own: ``mix(l, xbc, dt, cache)`` ->
+        (y, x) between a Mamba layer's in_proj and out_proj, ``attend(l,
+        q, k, v, cache)`` between ``wqkv`` and ``wo``.  Returns (h,
+        cache, [pairs, experts hit])."""
         import jax
         import jax.numpy as jnp
 
@@ -342,7 +348,9 @@ class NemotronHConfig:
             u = _rms(h, p[kind + ".norm"][l]).astype(h.dtype)
             if kind == "M":
                 with jax.named_scope("layer"), jax.named_scope("mamba"):
-                    out = mamba(l, u, cache)
+                    z, xbc, dt = self._mamba_in(p, l, u)
+                    y, x = mix(l, xbc, dt, cache)
+                    out = self._mamba_out(p, l, y, x, z)
             elif kind == "*":
                 with jax.named_scope("layer"), jax.named_scope("attention"):
                     q, k, v = self._qkv(p, l, u)
@@ -355,6 +363,53 @@ class NemotronHConfig:
             h = (h.astype(jnp.float32) + out).astype(h.dtype)
         return h, cache, jnp.stack([pairs, hit])
 
+    def _write_kv(self, cache, l, blk, off, k, v):
+        """Rows' K/V [T, kv, hd] into the pages at ``(blk, off)``, a
+        position's heads side by side; returns them in the pages'
+        dtype."""
+        pages = cache["k"].dtype
+        k, v = k.astype(pages), v.astype(pages)
+        cache["k"] = cache["k"].at[l, blk, off].set(k.reshape(len(blk), -1))
+        cache["v"] = cache["v"].at[l, blk, off].set(v.reshape(len(blk), -1))
+        return k, v
+
+    def _attend_pages(self, l, q, cache, tables, pos):
+        """Rows ``q`` [R, nh, hd], each over the ``pos + 1`` positions
+        its table holds, the pages gathered by XLA: [R, nh * hd]."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        bs, r = self.block_size, q.shape[0]
+        pages = cache["k"].dtype
+        rep = self.n_heads // self.n_kv_heads
+        scale = 1.0 / math.sqrt(self.head_dim)
+        qg = q.reshape(r, self.n_kv_heads, rep, self.head_dim).astype(pages)
+
+        def over(width):
+            """Attention through the first ``width`` table slots."""
+            def run(kp, vp):
+                t = tables[:, :width]
+                # each row's pages, gathered: [R, width * bs, kv, hd]
+                kk = kp[l][t].reshape(r, -1, self.n_kv_heads, self.head_dim)
+                vv = vp[l][t].reshape(r, -1, self.n_kv_heads, self.head_dim)
+                s = jnp.einsum("rgid,rsgd->rgis", qg, kk,
+                               preferred_element_type=jnp.float32) * scale
+                seen = jnp.arange(kk.shape[1])[None, :] <= pos[:, None]
+                s = jnp.where(seen[:, None, None, :], s, -jnp.inf)
+                w = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+                w = w / jnp.sum(w, axis=-1, keepdims=True)
+                return jnp.einsum("rgis,rsgd->rgid", w.astype(pages), vv,
+                                  preferred_element_type=jnp.float32)
+            return run
+
+        # the gather follows the pages the longest row holds, in
+        # steps of _TABLE_STEP, not the bucket the tables came in
+        nb = tables.shape[1]
+        widths = list(range(_TABLE_STEP, nb, _TABLE_STEP)) + [nb]
+        return lax.switch(jnp.max(pos) // (bs * _TABLE_STEP),
+                          [over(w) for w in widths],
+                          cache["k"], cache["v"]).reshape(r, -1)
+
     def paged_forward(self, p, cache, toks, pos, tables, slots):
         """R rows, each a token at position ``pos[i]`` of the sequence
         whose block table is ``tables[i]`` and whose state slot is
@@ -364,92 +419,72 @@ class NemotronHConfig:
         positions the table holds.  Returns hidden [R, d], the cache and
         the step's counts."""
         import jax.numpy as jnp
-        from jax import lax
 
         cache = dict(cache)
-        bs = self.block_size
-        blk = tables[jnp.arange(toks.shape[0]), pos // bs]
-        off = pos % bs
-        rep = self.n_heads // self.n_kv_heads
-        scale = 1.0 / math.sqrt(self.head_dim)
+        blk = tables[jnp.arange(toks.shape[0]), pos // self.block_size]
+        off = pos % self.block_size
 
         def attend(l, q, k, v, cache):
-            pages = cache["k"].dtype
-            r = q.shape[0]
-            cache["k"] = cache["k"].at[l, blk, off].set(
-                k.reshape(r, -1).astype(pages))
-            cache["v"] = cache["v"].at[l, blk, off].set(
-                v.reshape(r, -1).astype(pages))
-            qg = q.reshape(r, self.n_kv_heads, rep, self.head_dim).astype(
-                pages)
-
-            def over(width):
-                """Attention through the first ``width`` table slots."""
-                def run(kp, vp):
-                    t = tables[:, :width]
-                    # each row's pages, gathered: [R, width * bs, kv, hd]
-                    kk = kp[l][t].reshape(r, -1, self.n_kv_heads,
-                                          self.head_dim)
-                    vv = vp[l][t].reshape(r, -1, self.n_kv_heads,
-                                          self.head_dim)
-                    s = jnp.einsum("rgid,rsgd->rgis", qg, kk,
-                                   preferred_element_type=jnp.float32) * scale
-                    seen = jnp.arange(kk.shape[1])[None, :] <= pos[:, None]
-                    s = jnp.where(seen[:, None, None, :], s, -jnp.inf)
-                    w = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-                    w = w / jnp.sum(w, axis=-1, keepdims=True)
-                    return jnp.einsum("rgis,rsgd->rgid", w.astype(pages), vv,
-                                      preferred_element_type=jnp.float32)
-                return run
-
-            # the gather follows the pages the longest row holds, in
-            # steps of _TABLE_STEP, not the bucket the tables came in
-            nb = tables.shape[1]
-            widths = list(range(_TABLE_STEP, nb, _TABLE_STEP)) + [nb]
-            return lax.switch(jnp.max(pos) // (bs * _TABLE_STEP),
-                              [over(w) for w in widths],
-                              cache["k"], cache["v"])
+            self._write_kv(cache, l, blk, off, k, v)
+            return self._attend_pages(l, q, cache, tables, pos)
 
         return self._blocks(
             p, p["embed"][toks], cache,
-            lambda l, u, cache: self._mamba_decode(p, l, u, cache, slots),
+            lambda l, xbc, dt, cache: self._mamba_step(p, l, xbc, dt, cache,
+                                                       slots),
             attend)
 
-    def prompt_forward(self, p, cache, toks, length, block_ids, slot):
-        """A fresh (padded) prompt whole: ``toks`` [S], the first
-        ``length`` real.  The sequence's slot gets the window and the
-        state after ``length`` tokens; every position's K/V goes into
-        the sequence's blocks ``block_ids`` [S // bs] (padding to the
-        scratch block), attention is causal over the in-flight K/V."""
+    def riding_forward(self, p, cache, toks, pos, tables, prompt, length,
+                       block_ids, slots, slot):
+        """A decode step that carries a fresh prompt: ``paged_forward``'s
+        R rows (a dead row sits at position 0 of a table of scratch
+        blocks, in the scratch slot) and the S rows of one (padded)
+        prompt, the first ``length`` real.  Every weight product (the
+        mixers' projections, the router, the latent projections, the
+        grouped and the shared experts) runs once over the ``R + S``
+        rows; they part only inside the mixers: the R rows advance
+        their slots by a token and attend through the pages, the S rows
+        run the chunked scan into ``slot`` (which gets the window and
+        the state after ``length`` tokens) and causal flash attention
+        over the in-flight K/V, written with the rows' into
+        ``block_ids`` [S // bs] (padding to the scratch block).  Returns
+        hidden [R + S, d], the rows' then the prompt's, the cache and
+        the step's counts, the prompt's pairs and experts with the
+        rows'."""
         import jax.numpy as jnp
 
         from paddle_tpu.kernels.flash_attention import flash_attention
 
         cache = dict(cache)
-        s_len = toks.shape[0]
-        pos = jnp.arange(s_len, dtype=jnp.int32)
-        blk = jnp.where(pos < length, block_ids[pos // self.block_size], 0)
-        off = pos % self.block_size
+        r, s_len = toks.shape[0], prompt.shape[0]
+        at = jnp.arange(s_len, dtype=jnp.int32)
+        blk = jnp.concatenate([
+            tables[jnp.arange(r), pos // self.block_size],
+            jnp.where(at < length, block_ids[at // self.block_size], 0)])
+        off = jnp.concatenate([pos, at]) % self.block_size
         rep = self.n_heads // self.n_kv_heads
 
-        def attend(l, q, k, v, cache):
-            pages = cache["k"].dtype
-            k, v = k.astype(pages), v.astype(pages)
-            cache["k"] = cache["k"].at[l, blk, off].set(k.reshape(s_len, -1))
-            cache["v"] = cache["v"].at[l, blk, off].set(v.reshape(s_len, -1))
-            # each K/V head serves its `rep` query heads
-            q4 = q.astype(pages).transpose(1, 0, 2)[None]
-            k4 = jnp.repeat(k, rep, axis=1).transpose(1, 0, 2)[None]
-            v4 = jnp.repeat(v, rep, axis=1).transpose(1, 0, 2)[None]
-            att = flash_attention(q4, k4, v4, causal=True)[0]
-            return att.transpose(1, 0, 2)
+        def mix(l, xbc, dt, cache):
+            stepped = self._mamba_step(p, l, xbc[:r], dt[:r], cache, slots)
+            scanned = self._mamba_scan(p, l, xbc[r:], dt[r:], cache, length,
+                                       slot)
+            return tuple(jnp.concatenate(both)
+                         for both in zip(stepped, scanned))
 
-        h, cache, _ = self._blocks(
-            p, p["embed"][toks], cache,
-            lambda l, u, cache: self._mamba_prompt(p, l, u, cache, length,
-                                                   slot),
-            attend)
-        return h, cache
+        def attend(l, q, k, v, cache):
+            k, v = self._write_kv(cache, l, blk, off, k, v)
+            # each K/V head serves its `rep` query heads
+            q4 = q[r:].astype(k.dtype).transpose(1, 0, 2)[None]
+            k4 = jnp.repeat(k[r:], rep, axis=1).transpose(1, 0, 2)[None]
+            v4 = jnp.repeat(v[r:], rep, axis=1).transpose(1, 0, 2)[None]
+            fresh = flash_attention(q4, k4, v4, causal=True)[0]
+            return jnp.concatenate([
+                self._attend_pages(l, q[:r], cache, tables, pos),
+                fresh.transpose(1, 0, 2).reshape(s_len, -1)])
+
+        return self._blocks(
+            p, p["embed"][jnp.concatenate([toks, prompt])], cache,
+            mix, attend)
 
     def head(self, p, h, n_live=None):
         """Final norm and the logit layer: hidden [R, d] to float32

@@ -195,16 +195,28 @@ def test_decode_counts_the_pages_held_against_the_page_slots(ring, server):
 
 
 def test_a_requests_prefill_and_finishing_emit_share_its_cid(ring):
-    prefills = [s for s in ring if s["name"] == "serve.prefill"]
-    assert len(prefills) == 2
+    # the first prompt met no running row: a prefill the loop waited for
+    (waited,) = [s for s in ring if s["name"] == "serve.prefill"]
+    # (one bucket here: a prompt rides in 64 rows or more, and this
+    # model's longest sequence is 64)
+    assert waited["args"]["tokens"] == 4 and waited["args"]["bucket"] == 64
+    child = [s for s in ring if s["name"] == "serve.prefill.dispatch"
+             and s.get("cid") == waited["cid"]]
+    assert len(child) == 1 and child[0]["depth"] == waited["depth"] + 1
+    # the second rode the decode step dispatched next: that step's spans
+    # say so, under the request's id
+    carried = [s for s in ring if s["name"] in ("serve.decode.stage",
+                                                "serve.decode")
+               and "riding" in s["args"]]
+    assert [s["name"] for s in carried] == ["serve.decode.stage",
+                                            "serve.decode"]
+    assert {s["args"]["riding"] for s in carried} == {64}
+    assert carried[1]["args"]["rows"] == 1 and len(
+        {s["cid"] for s in carried}) == 1
     finished = [i for s in ring if s["name"] == "serve.emit"
                 for i in s["args"]["finished_ids"]]
-    for p in prefills:
-        assert p["args"]["tokens"] == 4 and p["args"]["bucket"] == 8
-        assert p["cid"] in finished
-        child = [s for s in ring if s["name"] == "serve.prefill.dispatch"
-                 and s.get("cid") == p["cid"]]
-        assert len(child) == 1 and child[0]["depth"] == p["depth"] + 1
+    assert waited["cid"] in finished and carried[0]["cid"] in finished
+    assert waited["cid"] != carried[0]["cid"]
     done = [s for s in ring if s["name"] == "serve.emit"
             and s["args"]["finished"]]
     assert done and all(s["cid"] == s["args"]["finished_ids"][0]

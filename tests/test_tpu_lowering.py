@@ -145,8 +145,9 @@ PAGED_STEPS = {"_compile_decode": (4, 4),
                "_compile_verify": (4, 4, 3)}
 
 
-def paged_step_module(compile_step, monkeypatch):
-    """(config, StableHLO text) of one engine step lowered for a TPU."""
+def paged_step_module(compile_step, monkeypatch, named=None):
+    """(config, StableHLO text) of one engine step lowered for a TPU;
+    ``named`` gets the name the engine compiles it under."""
     from paddle_tpu.serving import GenerativeEngine, tiny_lm
 
     cfg, params = tiny_lm(3, vocab=64, d_model=256, n_heads=2, n_layers=3,
@@ -157,8 +158,12 @@ def paged_step_module(compile_step, monkeypatch):
         # the step as _aot is handed it, before jit: traced here as
         # placed on a TPU, which the engine's own device is not
         monkeypatch.setattr(
-            eng, "_aot", lambda name, step, *specs: (eng._flat(step), specs))
-        step, specs = getattr(eng, compile_step)(PAGED_STEPS[compile_step])
+            eng, "_aot", lambda name, step, *specs: (name, eng._flat(step),
+                                                     specs))
+        name, step, specs = getattr(eng, compile_step)(
+            PAGED_STEPS[compile_step])
+        if named is not None:
+            named.append(name)
         pspec, cspec = jax.tree_util.tree_map(
             lambda a: sds(a.shape, a.dtype), (eng._params, eng._cache))
         return cfg, tpu_module(
@@ -220,16 +225,15 @@ def stripped_module(text):
                      if not ln.startswith("#loc"))
 
 
-# sha256 of stripped_module() of the two steps the serving cell runs,
-# taken at PR 31's commit (5d240f3) with this file's engine: the engine
-# now holds whatever tree of arrays the model's cache_spec names, and
-# for LMConfig (the K/V pair) its steps must lower to the program they
-# were, operand for operand and line for line
+# sha256 of stripped_module() of the plain decode step the serving cell
+# runs, taken at PR 31's commit (5d240f3) with this file's engine: the
+# engine now holds whatever tree of arrays the model's cache_spec names
+# and carries an admitted prompt in a program of its own (PR 33), and
+# for LMConfig (the K/V pair) the step that carries nothing must lower
+# to the program it was, operand for operand and line for line
 PARENT_STEPS = {
     "_compile_decode": ((4, 4), "eb99041b02c0d57a14e0b00b79f640c6"
                                 "62c2889b3caaa66f087b2dd0fbf81d79"),
-    "_compile_prefill": ((16,), "84d51817bea3d3838545bf0caf4bbce1"
-                                "76c98976ba608798b2379136c0367a7a"),
 }
 
 
@@ -243,6 +247,31 @@ def test_lm_config_steps_lower_as_before_the_cache_tree(compile_step,
     _, text = paged_step_module(compile_step, monkeypatch)
     assert hashlib.sha256(
         stripped_module(text).encode()).hexdigest() == want
+
+
+def test_riding_step_is_a_serve_decode_with_a_flash_call_a_layer_more(
+        monkeypatch):
+    """The step that carries a prompt is compiled under the plain step's
+    name, so that in a trace its paged kernels are ``%serve_decode.N``
+    like the plain step's (the decode tokens it makes are counted as
+    ``paged_attn_roofline.serve``'s work) and every run of
+    ``jit_serve_decode`` holds ``n_layers`` of them: its Mosaic calls
+    are the ``n_layers`` unnamed paged kernels, each on the whole pool,
+    and ``n_layers`` of ``flash_fwd``, which carry their own name."""
+    named = []
+    monkeypatch.setitem(PAGED_STEPS, "_compile_ride", (16,))
+    cfg, text = paged_step_module("_compile_ride", monkeypatch, named)
+    assert named == ["serve_decode"]
+    calls = [ln for ln in text.splitlines() if "@tpu_custom_call" in ln]
+    names = [re.search(r'kernel_name = "(\w+)"', ln).group(1) for ln in calls]
+    assert sorted(names) == sorted(
+        ["_paged_kernel", "flash_fwd"] * cfg.n_layers)
+    whole = "tensor<3x24x8x2x128xf32>"
+    for ln, name in zip(calls, names):
+        if name == "_paged_kernel":
+            operands = ln[ln.rindex(": (") + 3:ln.rindex(") -> ")]
+            assert operands.split(", ")[-2:] == [whole, whole], operands
+    assert not re.search(r"tensor<(1x)?24x8x2x128xf32>", text)
 
 
 def test_decode_step_holds_one_mosaic_call_a_layer(monkeypatch):
@@ -283,14 +312,17 @@ def test_state_update_lowers_at_the_hybrid_cells_widths():
 
 
 @pytest.mark.parametrize("compile_step,key", [("_compile_decode", (4, 4)),
-                                              ("_compile_prefill", (16,))])
+                                              ("_compile_ride", (16,))])
 def test_hybrid_steps_hand_their_kernels_the_whole_stacks(compile_step, key,
                                                           monkeypatch):
-    """The served hybrid's two steps lowered for a TPU: one state update
-    a Mamba layer (decode), one grouped expert matmul an expert layer,
-    each on the whole stacked operand with the layer a scalar: nothing
-    in the module has one layer's experts or one layer's state as its
-    shape (a slice in front of a Mosaic call is a copy)."""
+    """The served hybrid's two steps lowered for a TPU, the plain decode
+    step and the one that carries a prompt: one state update a Mamba
+    layer (the decode rows'), one grouped expert matmul an expert layer
+    (over the rows of both kinds), each on the whole stacked operand
+    with the layer a scalar: nothing in the module has one layer's
+    experts or one layer's state as its shape (a slice in front of a
+    Mosaic call is a copy).  The riding step adds one flash call an
+    attention layer, the prompt's."""
     from paddle_tpu.serving import GenerativeEngine
     from paddle_tpu.serving.nemotron_h import tiny_nemotron_h
 
@@ -313,10 +345,10 @@ def test_hybrid_steps_hand_their_kernels_the_whole_stacks(compile_step, key,
     finally:
         eng.close()
     names = re.findall(r'@tpu_custom_call\(.*?kernel_name = "(\w+)"', text)
-    decode = compile_step == "_compile_decode"
     assert names.count("grouped_expert_ffn") == 2
-    assert names.count("ssm_state_update") == (2 if decode else 0)
-    assert names.count("flash_fwd") == (0 if decode else 1)
+    assert names.count("ssm_state_update") == 2
+    assert names.count("flash_fwd") == (
+        0 if compile_step == "_compile_decode" else 1)
     for ln in text.splitlines():
         if "@tpu_custom_call" not in ln:
             continue
