@@ -124,6 +124,11 @@ _M_PREFILL_RIDES = _metrics.counter(
 _M_DECODE_ROWS = _metrics.counter("serve_decode_rows_total",
                                   "live sequences summed over decode "
                                   "iterations")
+_M_DECODE_DEAD_ROWS = _metrics.counter(
+    "serve_decode_dead_rows_total",
+    "dead rows inside decode iterations' row layouts (a finished row's "
+    "index until a newcomer takes it; the padding past the layout not "
+    "counted)")
 _M_DECODE_SLOTS = _metrics.counter("serve_decode_slots_total",
                                    "bucket rows summed over decode "
                                    "iterations (occupancy denominator)")
@@ -261,6 +266,7 @@ def token_metrics_probe(iters):
         _M_DECODE_STEPS.inc()
         _M_DECODE_AHEAD.inc()
         _M_DECODE_ROWS.inc(1)
+        _M_DECODE_DEAD_ROWS.inc(0)
         _M_DECODE_SLOTS.inc(1)
         _M_DECODE_PAGES.inc(1)
         _M_DECODE_PAGE_SLOTS.inc(1)
@@ -540,9 +546,10 @@ class _DecodeFlight:
     """A dispatched decode step whose tokens the host has not read:
     ``nxt`` is the executable's ``(bb,)`` int32 output on the engine's
     device (``logits`` its ``[bb, V]`` one, where asked for), ``b`` the
-    live rows of the ``bb`` the batch bucket holds (a prompt that rode
-    the step is the last of them: its first token comes back in the row
-    it takes).  ``host`` is the read-back once ``read`` has made it."""
+    rows of the layout of the ``bb`` the batch bucket holds, dead ones
+    and a riding prompt's (its first token comes back in the row it
+    takes) included.  ``host`` is the read-back once ``read`` has made
+    it."""
 
     __slots__ = ("nxt", "logits", "stats", "b", "bb", "host", "riding")
 
@@ -555,8 +562,9 @@ class _DecodeFlight:
         self.host = None
 
     def read(self):
-        """The live rows' tokens on the host; the first call blocks
-        until the device has them."""
+        """The layout's tokens on the host, by row index (a dead row's
+        is meaningless); the first call blocks until the device has
+        them."""
         if self.host is None:
             self.host = np.asarray(self.nxt)[:self.b]
         return self.host
@@ -1114,7 +1122,7 @@ class GenerativeEngine:
                if sp is not None else None)
         if self.rides and not start:
             flight = self.decode_dispatch((), (), (),
-                                          rider=(tokens, blocks, slot))
+                                          rider=(tokens, blocks, slot, 0))
             s_len, nxt = flight.riding, flight.nxt     # row 0 is its token
         else:
             if start:   # reads the whole table, through the cached prefix
@@ -1164,28 +1172,37 @@ class GenerativeEngine:
 
     def decode_start(self, seqs, after=None, with_logits=False, _tr=None,
                      rider=None):
-        """Dispatch one decode iteration over ``seqs`` and advance their
-        contexts; the tokens stay on the device behind the returned
-        flight (``decode_read``).  ``after`` is the unread flight of the
-        step before, over these sequences in this order: its tokens are
-        this step's input where they are.  Without it the input is each
-        sequence's last token as the host knows it.  ``rider`` is a
-        sequence admitted with its blocks whose fresh prompt rides this
-        step: its first token is the flight's row ``len(seqs)``, the row
-        it takes once the caller has put it after ``seqs``."""
+        """Dispatch one decode iteration over the row layout ``seqs`` and
+        advance its sequences' contexts; the tokens stay on the device
+        behind the returned flight (``decode_read``), one a row index.
+        A row is a sequence or None, a dead row: length 0, the scratch
+        block and slot, its token never read.  ``after`` is the unread
+        flight of the step before: its token at a row's index is that
+        row's input where it is (a row keeps its index while it lives).
+        Without it the input is each sequence's last token as the host
+        knows it.  ``rider`` is a sequence admitted with its blocks whose
+        fresh prompt rides this step: it is dead in the decode half, and
+        its first token comes back at its index in ``seqs``, or at
+        ``len(seqs)`` where it is not among them."""
+        row = next((i for i, s in enumerate(seqs) if s is rider),
+                   len(seqs))
+        live = [None if s is rider else s for s in seqs]
         toks = after if after is not None else [
-            s.out[-1] if s.out else (s.prompt[-1] if s.prompt else 0)
-            for s in seqs]
+            (s.out[-1] if s.out else s.prompt[-1]) if s is not None else 0
+            for s in live]
         if rider is not None:
             self._check_prompt(len(rider.prompt))
         flight = self.decode_dispatch(
-            [s.blocks for s in seqs], [s.context_len for s in seqs],
+            [s.blocks if s is not None else () for s in live],
+            [s.context_len if s is not None else 0 for s in live],
             toks, with_logits=with_logits, _count=True, _tr=_tr,
-            slots=[s.slot for s in seqs] if self.stateful else None,
-            rider=rider and (rider.prompt, rider.blocks, rider.slot),
+            slots=([s.slot if s is not None else 0 for s in live]
+                   if self.stateful else None),
+            rider=rider and (rider.prompt, rider.blocks, rider.slot, row),
             _cid=rider and rider.rid)
-        for s in seqs:
-            s.context_len += 1
+        for s in live:
+            if s is not None:
+                s.context_len += 1
         if rider is not None:
             rider.context_len = len(rider.prompt)
             if _batcher._METRICS_ON:
@@ -1219,23 +1236,27 @@ class GenerativeEngine:
         dispatch under the state lock; ``ahead`` says where the input
         tokens were).
 
-        ``toks`` is one token a row from the host, or the flight of the
-        step before over the same rows in the same order.  An unread
-        flight of this step's batch bucket hands its device tokens to
-        the executable as they are (the ``(bb,)`` int32 output is the
-        ``toks`` operand's own shape and device): the host never sees
-        them in between, so this dispatch does not wait for that step.
-        Any other flight is read here first.  ``slots`` is one state
-        slot a row, for a model with per-sequence state (left out, every
-        row aims at the scratch slot).
+        A row of length 0 is dead (empty table, the scratch block and
+        slot): it is counted in ``serve_decode_dead_rows_total`` and the
+        span's ``dead``, not as a row.  ``toks`` is one token a row from
+        the host, or the flight of the step before, whose token at an
+        index is that row's input.  An unread flight of this step's
+        batch bucket hands its device tokens to the executable as they
+        are (the ``(bb,)`` int32 output is the ``toks`` operand's own
+        shape and device): the host never sees them in between, so this
+        dispatch does not wait for that step.  Any other flight is read
+        here first.  ``slots`` is one state slot a row, for a model with
+        per-sequence state (left out, every row aims at the scratch
+        slot).
 
         ``rider`` is a fresh prompt carried by the step, ``(tokens,
-        blocks, state slot)``: the step is then the riding program of
-        the prompt's bucket, its decode half the top bucket whatever the
-        rows (there may be none: a prefill alone), and the prompt's
-        first token is one more live row of the flight, after the
-        others.  The spans then say ``riding``, the prompt's bucket,
-        and carry ``_cid``, its request's id."""
+        blocks, state slot, row)``: the step is then the riding program
+        of the prompt's bucket, its decode half the top bucket whatever
+        the rows (there may be none: a prefill alone), and the prompt's
+        first token comes back at index ``row``, a dead row of the
+        decode half or the one after the rows.  The spans then say
+        ``riding``, the prompt's bucket, and carry ``_cid``, its
+        request's id."""
         sp = (_tr.begin("serve.decode.stage", _cid)
               if _tr is not None else None)
         b = len(blocks_list)
@@ -1245,13 +1266,15 @@ class GenerativeEngine:
                 self._decode_logits if with_logits else self._decode,
                 blocks_list, lens_list)
         else:
-            prompt, p_blocks, p_slot = rider
+            prompt, p_blocks, p_slot, row = rider
             riding, exe, p_toks, p_ids = self._stage_prompt(
                 self._prefill, self.prompt_ladder[0], prompt, p_blocks)
             key = (self.config.max_batch, self.nb_top)
             tables, lens, pages = self._pad_rows(key, blocks_list, lens_list)
-            carried = (p_toks, np.int32(len(prompt)), p_ids, np.int32(b))
+            carried = (p_toks, np.int32(len(prompt)), p_ids, np.int32(row))
         bb, nbb = key
+        live = int(np.count_nonzero(lens[:b]))
+        dead = b - live - (rider is not None and row < b)
         ahead = False
         if isinstance(toks, _DecodeFlight):
             ahead = toks.host is None and toks.bb == bb
@@ -1260,7 +1283,8 @@ class GenerativeEngine:
             operand = toks
         else:
             operand = np.zeros(bb, np.int32)
-            operand[:b] = toks
+            n = min(b, len(toks))
+            operand[:n] = toks[:n]
         tail = ()
         if self.stateful:
             tail = (np.zeros(bb, np.int32),)
@@ -1274,7 +1298,7 @@ class GenerativeEngine:
                 args["riding"] = riding
             _tr.end(sp, args=args)
             sp = _tr.begin("serve.decode", _cid,
-                           dict(args, rows=b, pages=pages,
+                           dict(args, rows=live, dead=dead, pages=pages,
                                 ahead=int(ahead)))
         (nxt, *rest), _ = self._dispatch(
             "decode", lambda cache: exe(self._params, cache, tables, lens,
@@ -1287,19 +1311,21 @@ class GenerativeEngine:
                 _M_DECODE_AHEAD.inc()
             if riding:
                 _M_PREFILL_RIDES.inc()
-            _M_DECODE_ROWS.inc(b)
+            _M_DECODE_ROWS.inc(live)
+            _M_DECODE_DEAD_ROWS.inc(dead)
             _M_DECODE_SLOTS.inc(bb)
             _M_DECODE_PAGES.inc(pages)
             _M_DECODE_PAGE_SLOTS.inc(bb * nbb)
-            _M_OCC_PCT.observe(100.0 * b / bb)
+            _M_OCC_PCT.observe(100.0 * live / bb)
         if sp is not None:
             _tr.end(sp)
-        # a rider's first token is one more row to read, the row it takes
-        return _DecodeFlight(nxt, logits, b + (rider is not None), bb, stats,
-                             riding)
+        # a rider's first token is read at the row it takes
+        return _DecodeFlight(nxt, logits,
+                             b if rider is None else max(b, row + 1), bb,
+                             stats, riding)
 
     def decode_read(self, flight, _tr=None, _times=None):
-        """The tokens of a dispatched decode step, one a live row (and
+        """The tokens of a dispatched decode step, one a row index (and
         its logits, where it was dispatched with them): blocks until
         the device has them.  A span where ``_tr`` is given,
         ``serve.decode.wait``; ``_times`` gets the time up to here as
@@ -1619,36 +1645,45 @@ class GenerativeEngine:
 # ---------------------------------------------------------------------------
 
 class DecodeLoop:
-    """One thread per generative tenant.  An iteration ends with ONE
-    decode step over the whole running set dispatched and unread (in
-    flight), and the next one starts from it:
+    """One thread per generative tenant.  The batch is a row layout
+    (``_layout``): a sequence keeps its row index from the step that
+    carries or first decodes it to the one that makes its last token,
+    and a finished row stays as a DEAD row (length 0, the scratch block
+    and slot, its token never read) until a newcomer takes its index.
+    An iteration ends with ONE decode step over the layout dispatched
+    and unread (in flight), and the next one starts from it:
 
-    - **ahead**, where the next step runs over the same rows and the
-      host already knows it (``_may_run_ahead``): grow, dispatch step
-      n+1 on step n's device tokens, THEN read step n, emit its tokens
-      and retire finished sequences.  The device goes from step n
-      straight into step n+1; the host's work between two steps runs
-      beside it.
-    - **drain**, anything else: read step n and emit first, then admit
-      the queued requests the block pool can hold (TokenScheduler),
-      grow/preempt for sequences crossing a block boundary and dispatch
-      the step from the host's tokens.  A speculative round is read
-      inside its own iteration and leaves nothing in flight.
-    - **carry**: an admitted request whose prompt is to be run whole,
-      of a model with the riding forward, is not prefilled while rows
-      are running: it waits in admission order (``_waiting``, holding
-      its blocks and slot) and its prompt RIDES the next drained step,
-      one prompt a step: one program, one weight stream, the running
-      rows' next tokens and the newcomer's first token in one vector,
-      in flight like any step, and the step after it can run ahead.  No
-      row waits a prefill out.  What does not ride is prefilled inside
-      the iteration, the loop waiting for its token: a prompt that
-      meets no running row, a prefix-cache hit's suffix, a speculative
-      tenant's prompts, a model without that forward.
+    - **ahead** (``_may_run_ahead``): a row that the step in flight ends
+      by count is dead in the next one; the live rows grow, the queue is
+      admitted, and step n+1 goes out on step n's device tokens (a row's
+      index holds its input), the oldest waiting prompt riding it at a
+      dead index; THEN step n is read, its tokens emitted and finished
+      sequences retired.  The device goes from step n straight into
+      step n+1 through finishes and admissions alike; the host's work
+      between two steps runs beside it.  An EOS the host could not
+      foresee runs its row dead in the step already out, and its index
+      is dead in the one after.
+    - **drain**, where the device must wait for the host: the first step
+      after an empty batch; a prompt that meets no running row or a
+      prefix-cache hit's suffix (prefilled inside the iteration and
+      waited for); speculative rounds (acceptance reads the tokens, and
+      a round is read inside its own iteration); a row that cannot grow
+      without a preemption (the evicted row's blocks are freed only
+      once nothing in flight writes them); a request migrated in with
+      its pages (its input token is the host's); a stopping loop.  Step
+      n is read and emitted first, then the queue is admitted, rows
+      grow or preempt, and the step goes out from the host's tokens.
 
-    Tokens are delivered one a step, when the device has them, in all
-    three.  The loop must survive anything — a dead loop wedges the
-    tenant with unresolved futures (the PR 9 dispatcher rule)."""
+    An admitted request whose prompt is to be run whole, of a model
+    with the riding forward, is not prefilled while rows are running:
+    it waits in admission order (``_waiting``, holding its blocks and
+    slot) and its prompt RIDES the next step, one prompt a step: one
+    program, one weight stream, the running rows' next tokens and the
+    newcomer's first token in one vector.  No row waits a prefill out.
+
+    Tokens are delivered one a step, when the device has them.  The
+    loop must survive anything — a dead loop wedges the tenant with
+    unresolved futures (the PR 9 dispatcher rule)."""
 
     def __init__(self, engine, queue, label=""):
         self.engine = engine
@@ -1658,9 +1693,13 @@ class DecodeLoop:
                                         prefix_cache=engine.prefix_cache)
         self.label = label
         self._times = _LoopTimes()
-        # the decode step dispatched and not yet read, with the rows it
-        # runs over in its order: (_DecodeFlight, [GenRequest]) or None
+        # the decode step dispatched and not yet read, with the row
+        # layout it runs over: (_DecodeFlight, [GenRequest | None]) or
+        # None
         self._flight = None
+        # the layout of the next step as it stands: a running sequence
+        # at the index it keeps, None for a dead row
+        self._layout = []
         # admitted requests whose prompts have not been run, oldest
         # first: each holds its blocks (and slot) and a row of the batch
         self._waiting = []
@@ -1694,6 +1733,7 @@ class DecodeLoop:
                 self._iterate(running)
             except Exception as e:
                 self._flight = None     # its rows fail with the rest
+                del self._layout[:]
                 for seq in running:
                     self.engine.free_sequence(seq)
                     if not seq.future.done():
@@ -1731,57 +1771,27 @@ class DecodeLoop:
         flight, self._flight = self._flight, None
         if flight is not None:
             step, rows = flight
-            ahead = self._may_run_ahead(rows, running, trc)
-            if ahead:
-                flight = (self.engine.decode_start(running, after=step,
-                                                   _tr=trc),
-                          list(running))
-            pairs = zip(rows, self.engine.decode_read(step, _tr=trc,
-                                                      _times=times))
-            if len(rows) != len(running):
-                # a row that finished under this step (an EOS the host
-                # could not foresee) ran dead in it: its token is dropped
-                live = {id(seq) for seq in running}
-                pairs = (p for p in pairs if id(p[0]) in live)
-            self._emit_all([(seq, int(tok)) for seq, tok in pairs],
-                           running, trc, ahead=int(ahead))
-            if ahead:
+            nxt = (self._dispatch(running, trc, after=step)
+                   if self._may_run_ahead(running, trc, times) else None)
+            toks = self.engine.decode_read(step, _tr=trc, _times=times)
+            # a row that finished under this step (an EOS the host
+            # could not foresee) ran dead in it: its token is dropped
+            live = set(running)
+            self._emit_all([(seq, int(tok)) for seq, tok in zip(rows, toks)
+                            if seq in live], running, trc,
+                           advanced=set(nxt[1]) if nxt else ())
+            if nxt is not None:
                 # with no row left the step ahead runs dead rows only:
                 # nothing of it is ever read
-                self._flight = flight if running else None
+                self._flight = nxt if running else None
                 return
-        # 1. admission: stream prefills into the running batch.  A
-        # prefill failure (over-long prompt that slipped validation, a
-        # cold-bucket compile error) fails THAT request and returns its
-        # just-allocated blocks — it must not leak pool capacity or
-        # take the rest of the batch down with it
-        sp = trc.begin("serve.admit") if trc is not None else None
-        admitted = self.scheduler.try_admit(
-            self.queue, len(running) + len(self._waiting))
-        if admitted and times is not None:
-            now = time.perf_counter()
-            _M_ADMISSIONS.inc(len(admitted))
-            _M_QUEUE_WAIT_US.inc(int(1e6 * sum(
-                now - req.t_arrival for req in admitted)))
-        for req in admitted:
-            if not (req.blocks and req.context_len):
-                self._waiting.append(req)
-                continue
-            # migrated-in (serving/fleet.py): the prompt's KV pages
-            # are already resident and `out` holds the first token
-            # — joining the batch IS the admission, no prefill
-            if len(req.out) >= req.max_new or (
-                    req.eos_id is not None and req.out
-                    and req.out[-1] == req.eos_id):
-                self.engine.free_sequence(req)
-                if not req.future.done():
-                    req.future.set_result(req.result())
-                continue
-            running.append(req)
-        if sp is not None:
-            trc.end(sp, args={"admitted": len(admitted)})
-        # the oldest waiting prompt rides this iteration's step where
-        # it can; one that cannot is prefilled here, and waited for
+        # 1. admission, then the prompts that cannot ride: each is
+        # prefilled here and waited for.  A prefill failure (over-long
+        # prompt that slipped validation, a cold-bucket compile error)
+        # fails THAT request and returns its just-allocated blocks — it
+        # must not leak pool capacity or take the rest of the batch
+        # down with it
+        self._admit(running, trc, times, len(running))
         while self._waiting and not (running
                                      and self._rides(self._waiting[0])):
             req = self._waiting.pop(0)
@@ -1799,6 +1809,7 @@ class DecodeLoop:
                     now = _clock_ns()
                     times.prefill += now - times.mark
                     times.mark = now
+            self._place(req)
             self._joined(req, running)
             self._emit_all(((req, tok),), running, trc)
         if not running:
@@ -1833,19 +1844,96 @@ class DecodeLoop:
                 pairs.extend((seq, int(tok)) for tok in toks)
             self._emit_all(pairs, running, trc, replay=True)
             return
+        self._flight = self._dispatch(running, trc)
+
+    def _dispatch(self, running, trc, after=None):
+        """Dispatch the next step over the layout, on the device tokens
+        of ``after``, the unread flight before it (None: the host's), the
+        oldest waiting prompt riding it at the first dead index.  Returns
+        the new flight and its layout, or None where a rider failed and
+        no row is left to step."""
         step = None
         if self._waiting:
             rider = self._waiting.pop(0)
+            self._place(rider)
             try:
-                step = self.engine.decode_start(running, rider=rider,
-                                                _tr=trc)
+                step = self.engine.decode_start(
+                    self._rows(after), after=after, rider=rider, _tr=trc)
             except Exception as e:
+                self._vacate(rider)
                 self._fail(rider, e)    # alone: the rows step without it
             else:
                 self._joined(rider, running)
         if step is None:
-            step = self.engine.decode_start(running, _tr=trc)
-        self._flight = (step, list(running))
+            if not any(self._layout):
+                return None
+            step = self.engine.decode_start(self._rows(after), after=after,
+                                            _tr=trc)
+        return step, list(self._layout)
+
+    def _rows(self, after):
+        """The layout, its trailing dead rows dropped: any of them where
+        the step's input comes from the host, else only while the batch
+        bucket stays (the hand-off of device tokens needs it)."""
+        rows, top = self._layout, self.scheduler.max_batch
+        while rows and rows[-1] is None and (
+                after is None or pow2_bucket(len(rows) - 1, top)
+                == pow2_bucket(len(rows), top)):
+            rows.pop()
+        return rows
+
+    def _place(self, req):
+        """``req`` takes a row: the first dead one, else a new one."""
+        rows = self._layout
+        for i, seq in enumerate(rows):
+            if seq is None:
+                rows[i] = req
+                return
+        rows.append(req)
+
+    def _vacate(self, seq):
+        """``seq``'s row, where it holds one, is dead from the next
+        step on."""
+        rows = self._layout
+        for i, held in enumerate(rows):
+            if held is seq:
+                rows[i] = None
+                return
+
+    def _admit(self, running, trc, times, n_rows):
+        """Admit what the pool holds beside ``n_rows`` rows and the
+        waiting prompts: a fresh prompt waits for its step; a request
+        migrated in with its pages (serving/fleet.py) takes a row at
+        once.  True when one did."""
+        sp = trc.begin("serve.admit") if trc is not None else None
+        admitted = self.scheduler.try_admit(
+            self.queue, n_rows + len(self._waiting))
+        if admitted and times is not None:
+            now = time.perf_counter()
+            _M_ADMISSIONS.inc(len(admitted))
+            _M_QUEUE_WAIT_US.inc(int(1e6 * sum(
+                now - req.t_arrival for req in admitted)))
+        joined = False
+        for req in admitted:
+            if not (req.blocks and req.context_len):
+                self._waiting.append(req)
+                continue
+            # migrated-in: the prompt's KV pages are already resident
+            # and `out` holds the first token — joining the batch IS the
+            # admission, no prefill
+            if len(req.out) >= req.max_new or (
+                    req.eos_id is not None and req.out
+                    and req.out[-1] == req.eos_id):
+                self.engine.free_sequence(req)
+                if not req.future.done():
+                    req.future.set_result(req.result())
+                continue
+            self._place(req)
+            running.append(req)
+            joined = True
+        if sp is not None:
+            trc.end(sp, args={"admitted": len(admitted)})
+        return joined
 
     def _speculative(self):
         return self.engine.spec_k > 0 and self.engine.draft is not None
@@ -1858,7 +1946,7 @@ class DecodeLoop:
                 and self.engine.fresh_prefill(req))
 
     def _joined(self, req, running):
-        """``req``'s prompt is dispatched: it takes the next row."""
+        """``req``'s prompt is dispatched, from the row it holds."""
         running.append(req)
         if self.engine.prefix_cache is not None:
             # index the fully-written prompt blocks — the NEXT
@@ -1872,30 +1960,38 @@ class DecodeLoop:
         if not req.future.done():
             req.future.set_exception(e)
 
-    def _may_run_ahead(self, rows, running, trc):
-        """Whether the step after the one in flight over ``rows`` can be
-        dispatched before that one is read: it runs over the same rows
-        in the same order, and nothing the host has yet to learn or do
-        comes between the two.  So not when the step in flight ends a
-        row by count (``max_new``, ``max_seq``: known before the read;
-        an EOS is not, and costs one dead row), a request waits for a
-        free row, an admitted prompt waits for a step to ride (it rides
-        a drained one), the rounds are speculative (acceptance reads the
-        tokens), the loop is stopping, or a row cannot grow without a
-        preemption.  Rows that could grow keep their new blocks: the
-        drained step writes the same positions."""
-        if (len(rows) != len(running)
-                or any(a is not b for a, b in zip(rows, running))
-                or self._speculative() or self._stop.is_set()
-                or self._waiting
-                or (len(running) < self.scheduler.max_batch
-                    and len(self.queue))):
+    def _may_run_ahead(self, running, trc, times):
+        """Whether the step after the one in flight can be dispatched
+        before that one is read, the layout made ready for it where it
+        can.  A row that the step in flight ends by count (``max_new``,
+        ``max_seq``: known before the read) is dead in the next step,
+        which aims it at the scratch block and slot, and its blocks and
+        slot go back to the pool at once: a newcomer given them writes
+        there in a later step, which takes the cache the step in flight
+        leaves.  The live rows then grow and the queue is admitted, the
+        oldest waiting prompt to ride.
+        It cannot where the rounds are speculative (acceptance reads the
+        tokens), the loop is stopping, a row cannot grow without a
+        preemption, a request migrated in takes a row (its input token is
+        the host's), the oldest waiting prompt cannot ride (it is
+        prefilled and waited for), or nothing would run.  Rows that could
+        grow keep their new blocks: the drained step writes the same
+        positions."""
+        if self._speculative() or self._stop.is_set():
             return False
         max_seq = self.engine.config.max_seq
-        if any(len(seq.out) + 1 >= seq.max_new
-               or seq.context_len >= max_seq for seq in running):
+        for seq in running:
+            if len(seq.out) + 1 >= seq.max_new or seq.context_len >= max_seq:
+                self._vacate(seq)
+                self.engine.free_sequence(seq)
+        live = [seq for seq in self._layout if seq is not None]
+        if not self._grow(live, 1, trc, preempt=False):
             return False
-        return self._grow(running, 1, trc, preempt=False)
+        if self._admit(running, trc, times, len(live)):
+            return False
+        if self._waiting:
+            return self._rides(self._waiting[0])
+        return bool(live)
 
     def _grow(self, running, need, trc, preempt=True):
         """Provision every running sequence for the ``need`` positions
@@ -1925,6 +2021,7 @@ class DecodeLoop:
                 if victim is None:
                     self.engine.free_sequence(seq)
                     running.remove(seq)
+                    self._vacate(seq)
                     seq.future.set_exception(RuntimeError(
                         "KV block pool too small for this sequence "
                         "(%d blocks total; raise FLAGS_serve_kv_blocks "
@@ -1939,16 +2036,16 @@ class DecodeLoop:
             trc.end(sp, args={"preempted": preempted})
         return grown
 
-    def _emit_all(self, pairs, running, trc, replay=False, ahead=0):
+    def _emit_all(self, pairs, running, trc, replay=False, advanced=()):
         """Emit ``(sequence, token)`` pairs under ONE ``serve.emit``
         span (not one a token).  The span's cid
         is the first finished request's id (``finished_ids`` has all),
         so a request's prefill and its finishing emit share a cid.
         ``replay``: a speculative round's tokens, each advancing the
         context by one; a sequence that finishes mid-round drops the
-        rest.  ``ahead``: how many steps past these tokens' own the
-        sequences' contexts already stand (1 where the next step went
-        out before these were read)."""
+        rest.  ``advanced``: the rows whose contexts already stand one
+        step past these tokens' own (those of the step that went out
+        before these were read)."""
         sp = trc.begin("serve.emit") if trc is not None else None
         finished = []
         tokens = 0
@@ -1958,7 +2055,7 @@ class DecodeLoop:
                     continue    # finished mid-round; discard the rest
                 seq.context_len += 1
             tokens += 1
-            if self._emit(seq, tok, running, ahead):
+            if self._emit(seq, tok, running, int(seq in advanced)):
                 finished.append(seq.rid)
         if sp is not None:
             trc.end(sp, cid=finished[0] if finished else None,
@@ -1994,6 +2091,7 @@ class DecodeLoop:
         if done:
             if seq in running:
                 running.remove(seq)
+            self._vacate(seq)
             self.engine.free_sequence(seq)
             if metrics_on:
                 _M_GEN_MS.observe((now - seq.t_arrival) * 1e3)
@@ -2007,6 +2105,7 @@ class DecodeLoop:
         before newer requests), and let greedy determinism regenerate
         its tokens on re-admission."""
         (running if victim in running else self._waiting).remove(victim)
+        self._vacate(victim)
         self.engine.free_sequence(victim)
         victim.reset()
         victim.preempted += 1

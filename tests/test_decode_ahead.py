@@ -1,11 +1,14 @@
 """One decode step in flight (ISSUE 31): DecodeLoop dispatches step n+1
-on step n's device tokens before it reads them, wherever the next step
-runs over the same rows.  Whatever the order, the delivered tokens are
-those of the engine's synchronous ``decode_step`` chain over the same
-request alone — for finishes by count at mixed lengths, an EOS under a
-step already dispatched, an arrival, a preemption, the prefix cache and
-speculative rounds — and a failed dispatch or a stop with a step in
-flight leaves no future, block or thread behind."""
+on step n's device tokens before it reads them.  The batch is a stable
+row layout (ISSUE 36): a finished row stays as a dead row until a
+newcomer takes its index, so the loop runs ahead through finishes by
+count and through admissions.  Whatever the order, the delivered tokens
+are those of the engine's synchronous ``decode_step`` chain over the
+same request alone — for finishes by count at mixed lengths, an EOS
+under a step already dispatched, an arrival, a preemption, the prefix
+cache and speculative rounds — what a live row holds of the cache is
+what a synchronous run wrote, and a failed dispatch or a stop with a
+step in flight leaves no future, block or thread behind."""
 import threading
 import time
 from concurrent.futures import Future
@@ -20,6 +23,7 @@ from paddle_tpu.serving import GenerativeEngine, InferenceServer, tiny_lm
 from paddle_tpu.serving.batcher import RequestQueue
 from paddle_tpu.serving.generative import (DecodeLoop, GenRequest,
                                            _DecodeFlight)
+from paddle_tpu.serving.nemotron_h import tiny_nemotron_h
 
 CFG_KW = dict(vocab=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
               block_size=8, max_blocks=8, max_batch=4)
@@ -53,33 +57,74 @@ def _prompts(seed, n, lo=3, hi=15):
 _CHAINS = {}
 
 
+def _synchronous(eng, prompt, max_new, upto=None):
+    """``prompt`` alone through ``eng``'s ``prefill`` and then
+    ``decode_step``, each step read before the next is staged, its input
+    the host's: its tokens, and where ``upto`` is given, what it holds
+    of the cache (``_held``) once ``upto`` positions are written."""
+    req = GenRequest(prompt, max_new, None, Future())
+    try:
+        req.blocks = eng.pool.alloc(eng.pool.blocks_for(len(prompt)
+                                                        + max_new))
+        if eng.stateful:
+            req.slot = eng.pool.take_slot()
+        slots = {"slots": [req.slot]} if eng.stateful else {}
+        out, n = [eng.prefill(req)], len(prompt)
+        while (len(out) < max_new and n < eng.config.max_seq
+               and (upto is None or n < upto)):
+            out.append(int(eng.decode_step([req.blocks], [n], [out[-1]],
+                                           **slots)[0]))
+            n += 1
+        held = None
+        if upto is not None:
+            assert n == upto
+            held = _held(_leaves(eng), eng.pool.num_blocks, req.blocks,
+                         req.slot, n, eng.config.block_size)
+        return out, held
+    finally:
+        eng.free_sequence(req)
+
+
 def _chain(model, prompt, max_new, eos_id=None):
-    """The request alone through ``prefill`` and then ``decode_step``,
-    each step read before the next is staged, its input the host's."""
-    key = (tuple(prompt), max_new)
+    """The request alone, through ``_synchronous`` on an engine of its
+    own."""
+    key = (id(model[0]), tuple(prompt), max_new)
     if key not in _CHAINS:
         cfg, params = model
         eng = GenerativeEngine(cfg, params, kv_blocks=16, warm=False)
-        req = GenRequest(prompt, max_new, None, Future())
         try:
-            req.blocks = eng.pool.alloc(
-                eng.pool.blocks_for(len(prompt) + max_new))
-            out, n = [eng.prefill(req)], len(prompt)
-            while len(out) < max_new and n < cfg.max_seq:
-                out.append(int(eng.decode_step([req.blocks], [n],
-                                               [out[-1]])[0]))
-                n += 1
+            _CHAINS[key] = _synchronous(eng, prompt, max_new)[0]
         finally:
-            eng.free_sequence(req)
             eng.close()
-        _CHAINS[key] = out
     out = _CHAINS[key]
     return out[:out.index(eos_id) + 1] if eos_id in out else out
 
 
+def _leaves(eng):
+    """The engine's cache arrays on the host, in the tree's order."""
+    import jax
+
+    tree, _ = eng.cache_state()
+    return [np.asarray(a, np.float32) for a in jax.tree_util.tree_leaves(tree)]
+
+
+def _held(leaves, n_blocks, blocks, slot, n, block_size):
+    """What a sequence holds of the cache: its first ``n`` positions of
+    every page array (axis 1 the block, axis 2 the position in it) and
+    its slot of every state array (axis 1 the slot)."""
+    out = []
+    for a in leaves:
+        if a.shape[1] == n_blocks:
+            out.append(np.stack([a[:, blocks[p // block_size],
+                                   p % block_size] for p in range(n)], 1))
+        else:
+            out.append(a[:, slot])
+    return out
+
+
 def _counts():
     return {name: metrics.counter("serve_decode_%s_total" % name).value
-            for name in ("ahead", "steps", "rows")}
+            for name in ("ahead", "steps", "rows", "dead_rows")}
 
 
 def _since(c0):
@@ -91,7 +136,7 @@ def _since(c0):
 
 def test_mixed_lengths_finish_by_count(model, sanitizer):
     """(a) rows that end at different steps: each ending is known before
-    its step is read and drains; between them the loop runs ahead."""
+    its step is read, and the row is dead in the step after it."""
     prompts = _prompts(3, 4)
     lengths = [5, 9, 16, 12]
     c0 = _counts()
@@ -148,8 +193,9 @@ def test_eos_under_a_step_already_dispatched(model, sanitizer, neighbour):
 
 
 def test_a_request_arrives_under_a_step_in_flight(model, sanitizer):
-    """(c) the arrival drains: it is admitted at the next iteration, and
-    both requests' tokens are their own."""
+    """(c) the arrival is admitted at the next iteration, in front of
+    the read, and rides the step dispatched there; both requests' tokens
+    are their own."""
     first, second = _prompts(7, 2)
     sent = []
     c0 = _counts()
@@ -249,8 +295,10 @@ def test_a_dispatch_that_raises_under_a_step_in_flight(model):
         raised = []
 
         def failing(blocks_list, lens_list, toks, **kw):
+            # a plain step: a carrying one that raises fails its rider
+            # alone (tests/test_prefill_rides.py)
             if (isinstance(toks, _DecodeFlight) and len(blocks_list) == 3
-                    and not raised):
+                    and kw.get("rider") is None and not raised):
                 raised.append(True)
                 raise RuntimeError("planted dispatch failure")
             return dispatch(blocks_list, lens_list, toks, **kw)
@@ -340,4 +388,143 @@ def test_a_flight_feeds_the_next_dispatch_or_is_read_first(model):
     finally:
         for r in reqs:
             eng.free_sequence(r)
+        eng.close()
+
+
+# ---------------------------------------------- the stable row layout
+
+LAYOUT_MODELS = {"lm": lambda: tiny_lm(SEED, **CFG_KW),
+                 "hybrid": lambda: tiny_nemotron_h(5)}
+
+
+@pytest.fixture(scope="module", params=sorted(LAYOUT_MODELS))
+def layout_model(request):
+    return LAYOUT_MODELS[request.param]()
+
+
+def _run_queued(model, prompts, lengths, snapshot_at=()):
+    """Every request in the queue before the loop starts, so that what
+    it admits when does not depend on the clock: a full batch that a
+    newcomer joins as a row finishes.  Returns the tokens, each step's
+    ``(layout as request ids, rider's id, dispatched behind a flight)``,
+    the counters' change and, at the steps ``snapshot_at``, what each
+    row of the layout holds of the cache with what it was fed."""
+    cfg, params = model
+    eng = GenerativeEngine(cfg, params, kv_blocks=64, warm=False)
+    queue = RequestQueue()
+    reqs = [GenRequest(p, n, None, Future())
+            for p, n in zip(prompts, lengths)]
+    for r in reqs:
+        queue.put(r)
+    steps, snaps = [], []
+    try:
+        start = eng.decode_start
+
+        def hooked(seqs, after=None, rider=None, **kw):
+            flight = start(seqs, after=after, rider=rider, **kw)
+            steps.append(([s and s.rid for s in seqs], rider and rider.rid,
+                          after is not None))
+            if len(steps) in snapshot_at:
+                leaves = _leaves(eng)
+                snaps.append([
+                    (s.prompt, s.max_new, s.context_len,
+                     _held(leaves, eng.pool.num_blocks, s.blocks, s.slot,
+                           s.context_len, cfg.block_size))
+                    for s in seqs if s is not None])
+            return flight
+
+        eng.decode_start = hooked
+        c0 = _counts()
+        loop = DecodeLoop(eng, queue, label="layout")
+        res = [r.future.result(300)["tokens"] for r in reqs]
+        loop.stop()
+        d = _since(c0)
+        # (e) everything a row held came back
+        assert eng.pool.used_blocks == 0 and eng.pool.slots_held == 0
+        assert not loop._waiting and loop._flight is None
+    finally:
+        eng.close()
+    return res, [r.rid for r in reqs], steps, d, snaps
+
+
+def test_the_layout_runs_ahead_through_finishes_and_admissions(
+        layout_model):
+    """(a) across finishes by count every step after the first goes out
+    on the device tokens of the one before; (b) a newcomer rides a step
+    dispatched so, at the index a finished row left; (c) every request's
+    tokens are its synchronous chain's.  A row keeps its index from the
+    step that carries it to its last, and the dead rows are counted."""
+    prompts = _prompts(71, 8)
+    lengths = [5, 9, 3, 12, 7, 4, 10, 6]
+    res, rids, steps, d, _ = _run_queued(layout_model, prompts, lengths)
+    ref = GenerativeEngine(*layout_model, kv_blocks=64, warm=False)
+    try:
+        for p, n, r in zip(prompts, lengths, res):
+            assert r == _synchronous(ref, p, n)[0]
+    finally:
+        ref.close()
+    # the first step follows the prefill of a prompt that met no row;
+    # the batch never empties after it, and no step drained
+    assert d["steps"] == len(steps) and d["ahead"] == d["steps"] - 1
+    assert [after for _, _, after in steps] == [False] + [True] * (
+        len(steps) - 1)
+    assert d["rows"] == sum(lengths) - len(lengths)
+    assert d["dead_rows"] == sum(rows.count(None) for rows, _, _ in steps)
+    assert d["dead_rows"] > 0
+    index = {}
+    for rows, rider, _ in steps:
+        for i, rid in enumerate(rows):
+            if rid is not None:
+                assert index.setdefault(rid, i) == i    # kept its row
+    riders = [(k, rows.index(rider)) for k, (rows, rider, _) in
+              enumerate(steps) if rider is not None]
+    assert [steps[k][1] for k, _ in riders] == rids[1:]
+    # after the first three ride into the empty rows, each newcomer takes
+    # the row of one that ended: in the step right after its last, or
+    # after it ran dead
+    took = [steps[k - 1][0][i] for k, i in riders[3:]]
+    assert all(i < len(steps[k - 1][0]) for k, i in riders[3:])
+    assert all(rid is None or rid not in steps[k][0]
+               for rid, (k, _) in zip(took, riders[3:]))
+    assert any(rid is not None for rid in took)
+
+
+def test_live_rows_hold_what_a_synchronous_run_wrote(layout_model):
+    """(d) after mixed finishes and admissions, every page position and
+    state slot a live row holds (a rider's prompt included) equals what
+    the request alone wrote through ``prefill`` and ``decode_step``: no
+    dead row wrote into a block or slot a newcomer was given."""
+    prompts = _prompts(73, 8)
+    lengths = [4, 11, 3, 9, 6, 3, 8, 5]
+    cfg, params = layout_model
+    res, _, steps, _, snaps = _run_queued(
+        layout_model, prompts, lengths, snapshot_at=range(4, 40, 3))
+    assert len(snaps) >= 4
+    ref = GenerativeEngine(cfg, params, kv_blocks=64, warm=False)
+    try:
+        for rows in snaps:
+            for prompt, max_new, n, held in rows:
+                want = _synchronous(ref, prompt, max_new, upto=n)[1]
+                for a, b in zip(held, want):
+                    np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+        for p, n, r in zip(prompts, lengths, res):
+            assert r == _synchronous(ref, p, n)[0]
+    finally:
+        ref.close()
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUT_MODELS))
+def test_the_warm_keys_a_tenant_compiles_at_load(name):
+    """The stable layout adds no program: a tenant warms the decode
+    ladder at the top block count and one riding program a prompt
+    bucket, and nothing else."""
+    cfg, params = LAYOUT_MODELS[name]()
+    eng = GenerativeEngine(cfg, params, kv_blocks=16)
+    try:
+        assert eng._decode.warm_keys == [(1, 8), (2, 8), (4, 8)]
+        assert eng._prefill.warm_keys == [(64,)]
+        for cache in (eng._decode_logits, eng._prefill_cached, eng._verify,
+                      eng._propose):
+            assert cache.warm_keys == []
+    finally:
         eng.close()

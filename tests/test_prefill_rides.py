@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.observability import metrics
+from paddle_tpu.observability.trace import TRACER
 from paddle_tpu.serving import GenerativeEngine, InferenceServer, tiny_lm
 from paddle_tpu.serving.batcher import RequestQueue
 from paddle_tpu.serving.generative import DecodeLoop, GenRequest
@@ -158,8 +159,9 @@ def test_mixed_arrivals_generate_as_one_at_a_time(model):
 
 def test_prompts_admitted_together_ride_consecutive_steps(model):
     """Two requests admitted in one iteration: the first rides the step
-    dispatched next, the second the one after it, and the step between
-    the two is not dispatched ahead (a prompt waits)."""
+    dispatched next, the second the one after it, each in the row after
+    the running ones, and both steps go out behind the one before (a
+    waiting prompt rides a step dispatched ahead)."""
     _, cfg, params = model
     first, second, third = _prompts(31, 3)
     eng = GenerativeEngine(cfg, params, kv_blocks=64, warm=False)
@@ -189,11 +191,43 @@ def test_prompts_admitted_together_ride_consecutive_steps(model):
         eng.close()
     for p, toks in zip((first, second, third), out):
         assert toks == _alone(model, p, 12)
-    # a step from the host, one ahead (the arrivals), then the two rides
+    # a step from the host, one ahead (the arrivals), then the two rides,
+    # each rider among its step's rows
     assert steps[:2] == [(1, None, False, 0), (1, None, True, 0)]
-    assert steps[2:4] == [(1, reqs[0].rid, False, 1),
-                          (2, reqs[1].rid, False, 0)]
+    assert steps[2:4] == [(2, reqs[0].rid, True, 1),
+                          (3, reqs[1].rid, True, 0)]
     assert steps[4] == (3, None, True, 0)       # and on ahead, all three
+
+
+def test_a_carrying_step_goes_out_ahead_where_rows_run(model):
+    """A prompt admitted while rows run rides a step dispatched on the
+    device tokens of the one before: its ``serve.decode`` span says
+    ``ahead=1``, its ``rows`` are the live decode rows and ``dead`` 0.
+    The step after a prefill (no row ran) goes out from the host."""
+    _, cfg, params = model
+    prompts = _prompts(35, 4)
+    eng = GenerativeEngine(cfg, params, kv_blocks=64, warm=False)
+    queue = RequestQueue()
+    reqs = [GenRequest(p, 6, None, Future()) for p in prompts]
+    for r in reqs:      # all four are there at the first admission
+        queue.put(r)
+    assert not TRACER.on
+    TRACER.clear()
+    TRACER.enable()
+    try:
+        loop = DecodeLoop(eng, queue, label="ahead")
+        out = [r.future.result(300)["tokens"] for r in reqs]
+        loop.stop()
+    finally:
+        TRACER.disable()
+        eng.close()
+    spans = TRACER.completed()
+    TRACER.clear()
+    assert out == [_alone(model, p, 6) for p in prompts]
+    carried = [s["args"] for s in spans if s["name"] == "serve.decode"
+               and "riding" in s["args"]]
+    assert [(a["ahead"], a["rows"], a["dead"]) for a in carried] == [
+        (0, 1, 0), (1, 2, 0), (1, 3, 0)]
 
 
 # --------------------------------------------- (d) a riding prompt fails
