@@ -153,6 +153,19 @@ class Histogram:
             self._n += 1
             self._recent.append(v)
 
+    def observe_many(self, values):
+        """``observe`` each of ``values`` under one acquire of the lock
+        (a loop that gathers a step's samples and flushes them)."""
+        vals = list(map(float, values))
+        bounds, where = self.bounds, bisect.bisect_left
+        with self._lock:
+            counts = self._counts
+            for v in vals:
+                counts[where(bounds, v)] += 1
+            self._sum += sum(vals)
+            self._n += len(vals)
+            self._recent.extend(vals)
+
     @property
     def count(self):
         return self._n

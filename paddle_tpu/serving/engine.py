@@ -30,6 +30,7 @@ import numpy as np
 from paddle_tpu.core import sanitizer as _san
 from paddle_tpu.core.flags import FLAGS
 from paddle_tpu.observability import metrics as _metrics
+from paddle_tpu.observability.trace import TRACER
 
 __all__ = ["ModelEngine", "bucket_ladder", "StepCache", "pow2_bucket"]
 
@@ -146,6 +147,13 @@ class StepCache:
             self._compiling.add(key)
 
         def _bg():
+            # a compile holds the interpreter beside the serving loop:
+            # under a capture its span says when
+            trc = TRACER if TRACER.live() else None
+            sp = (trc.begin("serve.compile", None,
+                            {"key": "x".join(map(str, key)),
+                             "cache": self.name})
+                  if trc is not None else None)
             try:
                 exe = self._compile_fn(key)
                 _M_COMPILES.inc()
@@ -161,6 +169,8 @@ class StepCache:
             finally:
                 with self._lock:
                     self._compiling.discard(key)
+                if sp is not None:
+                    trc.end(sp)
 
         t = threading.Thread(target=_bg, daemon=True,
                              name="serve-stepcompile-%s" % (self.name,))

@@ -124,11 +124,6 @@ _M_PREFILL_RIDES = _metrics.counter(
 _M_DECODE_ROWS = _metrics.counter("serve_decode_rows_total",
                                   "live sequences summed over decode "
                                   "iterations")
-_M_DECODE_DEAD_ROWS = _metrics.counter(
-    "serve_decode_dead_rows_total",
-    "dead rows inside decode iterations' row layouts (a finished row's "
-    "index until a newcomer takes it; the padding past the layout not "
-    "counted)")
 _M_DECODE_SLOTS = _metrics.counter("serve_decode_slots_total",
                                    "bucket rows summed over decode "
                                    "iterations (occupancy denominator)")
@@ -144,8 +139,6 @@ _M_OCC_PCT = _metrics.histogram(
     "serve_decode_occupancy_pct",
     "live rows / bucket rows per decode iteration, percent",
     bounds=(10, 20, 30, 40, 50, 60, 70, 80, 90, 100))
-_M_GEN_MS = _metrics.histogram("serve_gen_request_ms",
-                               "generate request arrival -> finished")
 # speculative decoding (ISSUE 19).  Counters, not histograms: the
 # export/trace_report accept-rate and draft-overhead columns are
 # ratios of counter snapshots (accepted/proposed,
@@ -199,6 +192,39 @@ _M_ADMISSIONS = _metrics.counter(
     "requests admitted into the running batch (re-admissions after "
     "preemption included)")
 
+# what a decode step costs, seen from the loop (the decode_step_ms /
+# ride_step_ms / decode_late_share / dispatch_ms / ride_wait_ms metrics
+# of the benchmark read these)
+_M_STEP_INTERVAL_US = _metrics.counter(
+    "serve_step_interval_us_total",
+    "micros from one decode read's return to the next one's, where the "
+    "step read second carried no prompt and the read before it is of "
+    "the same run of the loop: the device time of a step dispatched "
+    "ahead, plus any lateness of the host")
+_M_STEP_READS = _metrics.counter(
+    "serve_step_reads_total",
+    "decode reads that closed a serve_step_interval_us_total interval")
+_M_RIDE_INTERVAL_US = _metrics.counter(
+    "serve_ride_interval_us_total",
+    "the same interval, for a step that carried a prompt")
+_M_RIDE_READS = _metrics.counter(
+    "serve_ride_reads_total",
+    "decode reads that closed a serve_ride_interval_us_total interval")
+_M_DECODE_LATE = _metrics.counter(
+    "serve_decode_late_total",
+    "decode steps dispatched ahead whose step before had already ended "
+    "on the device: the host made the device wait (a drained dispatch, "
+    "steps - ahead, is late by construction and not counted here)")
+_M_DISPATCH_US = _metrics.counter(
+    "serve_dispatch_us_total",
+    "micros inside the runtime call of the loop's decode steps: the "
+    "engine's dispatch bracket, the enqueue included")
+_M_RIDE_WAIT_US = _metrics.counter(
+    "serve_ride_wait_us_total",
+    "admission -> the return of the dispatch that carries the prompt, "
+    "micros summed over the prompts that rode (over "
+    "serve_prefill_rides_total: a prompt's mean wait to ride)")
+
 _REQUEST_IDS = itertools.count(1)
 # the least bucket of a prompt that rides: a carrying step streams the
 # weights whatever it carries, so under this many rows a tighter bucket
@@ -209,31 +235,77 @@ _FLUSH_NS = 10_000_000      # of loop wall time between counter flushes
 
 
 class _LoopTimes:
-    """Nanoseconds of DecodeLoop iterations by phase, not yet counted.
-    ``mark`` is the clock at the last phase boundary: a boundary adds
-    the time since to the phase that ends there and moves the mark, so
-    nothing of an iteration goes uncounted.  The loop flushes whole micros to the ``serve_loop_*`` counters (and
-    keeps the remainder) at the end of an iteration once ``_FLUSH_NS``
-    of wall time have gathered, and whenever it goes idle — four
-    locked counter increments an iteration would be most of the
-    phase clock's cost on a sub-millisecond step.  So the counters lag
-    a running loop by under ``_FLUSH_NS`` and are exact for an idle
+    """What DecodeLoop iterations counted and did not yet flush:
+    nanoseconds by phase and of the step clocks, the per-step and
+    per-token counts, and the samples of the occupancy and inter-token
+    histograms.  ``mark`` is the clock at the last phase boundary: a
+    boundary adds the time since to the phase that ends there and moves
+    the mark, so nothing of an iteration goes uncounted.  ``last_read``
+    is the clock at the return of this run's last decode read (0: none
+    since the loop went idle or ran a speculative round).  The loop
+    flushes whole micros, the counts and the samples to their metrics
+    (keeping the remainders) at the end of an iteration once
+    ``_FLUSH_NS`` of wall time have gathered, and whenever it goes idle:
+    a dozen locked metric updates a step would be most of what the
+    counting costs on a sub-millisecond step.  So the metrics lag a
+    running loop by under ``_FLUSH_NS`` and are exact for an idle
     one."""
 
-    __slots__ = ("wall", "host", "prefill", "wait", "mark")
+    _NS = (("wall", _M_LOOP_WALL_US), ("host", _M_LOOP_HOST_US),
+           ("prefill", _M_LOOP_PREFILL_US), ("wait", _M_LOOP_WAIT_US),
+           ("interval", _M_STEP_INTERVAL_US),
+           ("ride_interval", _M_RIDE_INTERVAL_US),
+           ("dispatch", _M_DISPATCH_US), ("ride_wait", _M_RIDE_WAIT_US))
+    _COUNTS = (("steps", _M_DECODE_STEPS), ("ahead", _M_DECODE_AHEAD),
+               ("late", _M_DECODE_LATE), ("rides", _M_PREFILL_RIDES),
+               ("rows", _M_DECODE_ROWS), ("slots", _M_DECODE_SLOTS),
+               ("pages", _M_DECODE_PAGES),
+               ("page_slots", _M_DECODE_PAGE_SLOTS),
+               ("reads", _M_STEP_READS), ("ride_reads", _M_RIDE_READS),
+               ("tokens", _M_TOKENS))
+    # samples of a histogram, a step's or a token's
+    _SAMPLES = (("occupancy", _M_OCC_PCT), ("itl", _M_ITL))
+    __slots__ = tuple(n for n, _ in _NS + _COUNTS + _SAMPLES) + (
+        "mark", "last_read")
 
     def __init__(self):
-        self.wall = self.host = self.prefill = self.wait = self.mark = 0
+        for name, _ in self._NS + self._COUNTS:
+            setattr(self, name, 0)
+        self.mark = self.last_read = 0
+        self.occupancy, self.itl = [], []
+
+    def read_at(self, now, riding):
+        """A decode step's tokens came back at ``now``: the time since
+        the read before, where this run holds one, is the step's
+        interval, counted by whether it carried a prompt.  Returns it
+        in nanoseconds, or None."""
+        last, self.last_read = self.last_read, now
+        if not last:
+            return None
+        if riding:
+            self.ride_interval += now - last
+            self.ride_reads += 1
+        else:
+            self.interval += now - last
+            self.reads += 1
+        return now - last
 
     def flush(self):
-        us, self.wall = divmod(self.wall, 1000)
-        _M_LOOP_WALL_US.inc(us)
-        us, self.host = divmod(self.host, 1000)
-        _M_LOOP_HOST_US.inc(us)
-        us, self.prefill = divmod(self.prefill, 1000)
-        _M_LOOP_PREFILL_US.inc(us)
-        us, self.wait = divmod(self.wait, 1000)
-        _M_LOOP_WAIT_US.inc(us)
+        for name, counter in self._NS:
+            us, rest = divmod(getattr(self, name), 1000)
+            if us:
+                counter.inc(us)
+                setattr(self, name, rest)
+        for name, counter in self._COUNTS:
+            n = getattr(self, name)
+            if n:
+                counter.inc(n)
+                setattr(self, name, 0)
+        for name, histogram in self._SAMPLES:
+            values = getattr(self, name)
+            if values:
+                histogram.observe_many(values)
+                del values[:]
 
 
 def token_metrics_probe(iters):
@@ -241,39 +313,53 @@ def token_metrics_probe(iters):
     telemetry_overhead.py decode gate (batcher.metrics_probe's rule):
     every operation ``_METRICS_ON`` gates per emitted token in the
     single-sequence worst case, where the per-iteration ops are not
-    amortized across batch neighbours — the iteration's liveness check
-    and phase clock reads with the ``serve_loop_*`` flush included."""
+    amortized across batch neighbours — the iteration's liveness check,
+    the phase and step clock reads, the dispatch's readiness check and
+    the step's counts, with their flush included."""
+    import jax.numpy as jnp
+
+    flight = jnp.zeros(8, jnp.int32)
     times = _LoopTimes()
+    ahead, riding, live, bb, nbb, pages = True, 0, 1, 8, 1, 1
     for _ in range(iters):
-        # per-iteration (worst case: one live row): the dead-path
-        # sites of DecodeLoop._iterate and decode_read — one liveness
-        # check, four clock reads (start, around the wait, end), the
-        # phase adds, a flush every _FLUSH_NS of (here, 0.2-ms)
-        # iterations
+        # per-iteration (worst case: one live row, every step ahead):
+        # the dead-path sites of DecodeLoop._iterate, decode_dispatch
+        # and decode_read — one liveness check, six clock reads (start,
+        # around the dispatch, around the wait, end), the readiness of
+        # the step before, the phase and step adds, a flush every
+        # _FLUSH_NS of (here, 0.2-ms) iterations
         TRACER.live()
         times.mark = t_iter = _clock_ns()
+        t0 = _clock_ns()
+        late = flight.is_ready()
+        times.dispatch += _clock_ns() - t0
+        times.steps += 1
+        if ahead:
+            times.ahead += 1
+            if late:
+                times.late += 1
+        if riding:
+            times.rides += 1
+        times.rows += live
+        times.slots += bb
+        times.pages += pages
+        times.page_slots += bb * nbb
+        times.occupancy.append(100.0 * live / bb)
         now = _clock_ns()
         times.host += now - times.mark              # up to the wait
         times.mark = now
         now = _clock_ns()
         times.wait += now - times.mark              # the wait
         times.mark = now
+        times.read_at(now, 0)
         now = _clock_ns()
         times.host += now - times.mark              # emit
         times.wall += now - t_iter + 200000
+        # per-token
+        times.itl.append(0.4)
+        times.tokens += 1
         if times.wall >= _FLUSH_NS:
             times.flush()
-        _M_DECODE_STEPS.inc()
-        _M_DECODE_AHEAD.inc()
-        _M_DECODE_ROWS.inc(1)
-        _M_DECODE_DEAD_ROWS.inc(0)
-        _M_DECODE_SLOTS.inc(1)
-        _M_DECODE_PAGES.inc(1)
-        _M_DECODE_PAGE_SLOTS.inc(1)
-        _M_OCC_PCT.observe(100.0)
-        # per-token
-        _M_TOKENS.inc()
-        _M_ITL.observe(0.4)
 
 
 def spec_metrics_probe(iters):
@@ -302,7 +388,7 @@ class GenRequest:
     __slots__ = ("rid", "prompt", "max_new", "eos_id", "future",
                  "t_arrival", "blocks", "context_len", "out", "t_first",
                  "t_last", "itl_ms", "preempted", "cached_len",
-                 "draft_len", "slot")
+                 "draft_len", "slot", "t_admitted")
 
     def __init__(self, prompt, max_new, eos_id, future):
         # small process-wide id: the cid of this request's spans
@@ -312,6 +398,9 @@ class GenRequest:
         self.eos_id = eos_id
         self.future = future
         self.t_arrival = time.perf_counter()
+        # the clock (ns) at the admission that put its prompt in the
+        # loop's wait to ride; 0 where it was not stamped
+        self.t_admitted = 0
         self.reset()
         self.preempted = 0
         # the state slot held from admission to finish or preemption
@@ -1171,7 +1260,7 @@ class GenerativeEngine:
             self.decode_start(seqs, with_logits=with_logits))
 
     def decode_start(self, seqs, after=None, with_logits=False, _tr=None,
-                     rider=None):
+                     rider=None, _times=None):
         """Dispatch one decode iteration over the row layout ``seqs`` and
         advance its sequences' contexts; the tokens stay on the device
         behind the returned flight (``decode_read``), one a row index.
@@ -1183,7 +1272,9 @@ class GenerativeEngine:
         knows it.  ``rider`` is a sequence admitted with its blocks whose
         fresh prompt rides this step: it is dead in the decode half, and
         its first token comes back at its index in ``seqs``, or at
-        ``len(seqs)`` where it is not among them."""
+        ``len(seqs)`` where it is not among them.  The step is counted
+        into ``_times``, DecodeLoop's record, or with metrics on and no
+        record given, at once."""
         row = next((i for i, s in enumerate(seqs) if s is rider),
                    len(seqs))
         live = [None if s is rider else s for s in seqs]
@@ -1192,10 +1283,13 @@ class GenerativeEngine:
             for s in live]
         if rider is not None:
             self._check_prompt(len(rider.prompt))
+        times = _times
+        if times is None and _batcher._METRICS_ON:
+            times = _LoopTimes()
         flight = self.decode_dispatch(
             [s.blocks if s is not None else () for s in live],
             [s.context_len if s is not None else 0 for s in live],
-            toks, with_logits=with_logits, _count=True, _tr=_tr,
+            toks, with_logits=with_logits, _tr=_tr, _times=times,
             slots=([s.slot if s is not None else 0 for s in live]
                    if self.stateful else None),
             rider=rider and (rider.prompt, rider.blocks, rider.slot, row),
@@ -1205,13 +1299,16 @@ class GenerativeEngine:
                 s.context_len += 1
         if rider is not None:
             rider.context_len = len(rider.prompt)
-            if _batcher._METRICS_ON:
+            if times is not None:
                 _M_PREFILLS.inc()
+                if rider.t_admitted:
+                    times.ride_wait += _clock_ns() - rider.t_admitted
+        if times is not _times:
+            times.flush()
         return flight
 
     def decode_step(self, blocks_list, lens_list, toks_list,
-                    with_logits=False, _count=False, _tr=None,
-                    _times=None, slots=None):
+                    with_logits=False, _tr=None, slots=None):
         """Raw single-token decode over parallel lists (one entry per
         row): no sequence bookkeeping, callers own their own length
         accounting — the speculative draft's propose/catch-up steps
@@ -1222,12 +1319,12 @@ class GenerativeEngine:
         itself and dispatches the next step between them."""
         return self.decode_read(
             self.decode_dispatch(blocks_list, lens_list, toks_list,
-                                 with_logits=with_logits, _count=_count,
-                                 _tr=_tr, slots=slots),
-            _tr=_tr, _times=_times)
+                                 with_logits=with_logits, _tr=_tr,
+                                 slots=slots),
+            _tr=_tr)
 
     def decode_dispatch(self, blocks_list, lens_list, toks,
-                        with_logits=False, _count=False, _tr=None,
+                        with_logits=False, _tr=None, _times=None,
                         slots=None, rider=None, _cid=None):
         """Stage and dispatch one decode step; returns its
         ``_DecodeFlight`` without waiting for the device.  Two phases,
@@ -1237,10 +1334,9 @@ class GenerativeEngine:
         tokens were).
 
         A row of length 0 is dead (empty table, the scratch block and
-        slot): it is counted in ``serve_decode_dead_rows_total`` and the
-        span's ``dead``, not as a row.  ``toks`` is one token a row from
-        the host, or the flight of the step before, whose token at an
-        index is that row's input.  An unread flight of this step's
+        slot): the span counts it in ``dead``, not as a row.  ``toks`` is
+        one token a row from the host, or the flight of the step before,
+        whose token at an index is that row's input.  An unread flight of this step's
         batch bucket hands its device tokens to the executable as they
         are (the ``(bb,)`` int32 output is the ``toks`` operand's own
         shape and device): the host never sees them in between, so this
@@ -1256,7 +1352,13 @@ class GenerativeEngine:
         first token comes back at index ``row``, a dead row of the
         decode half or the one after the rows.  The spans then say
         ``riding``, the prompt's bucket, and carry ``_cid``, its
-        request's id."""
+        request's id.
+
+        ``_times`` (a ``_LoopTimes``) counts the step: its rows, slots
+        and pages, whether it went out ahead and, if so, whether the
+        step before had already ended on the device (``late``), and the
+        time inside the runtime call (``dispatch_us``); the
+        ``serve.decode`` span then says both."""
         sp = (_tr.begin("serve.decode.stage", _cid)
               if _tr is not None else None)
         b = len(blocks_list)
@@ -1275,7 +1377,7 @@ class GenerativeEngine:
         bb, nbb = key
         live = int(np.count_nonzero(lens[:b]))
         dead = b - live - (rider is not None and row < b)
-        ahead = False
+        ahead = late = False
         if isinstance(toks, _DecodeFlight):
             ahead = toks.host is None and toks.bb == bb
             toks = toks.nxt if ahead else toks.read()
@@ -1300,25 +1402,33 @@ class GenerativeEngine:
             sp = _tr.begin("serve.decode", _cid,
                            dict(args, rows=live, dead=dead, pages=pages,
                                 ahead=int(ahead)))
+        if _times is not None:
+            t0 = _clock_ns()
+            # nothing queued behind the step before: the device waits
+            late = ahead and operand.is_ready()
         (nxt, *rest), _ = self._dispatch(
             "decode", lambda cache: exe(self._params, cache, tables, lens,
                                         operand, *carried, *tail))
         logits = rest.pop(0) if with_logits else None
         stats = rest[0] if rest else None
-        if _count and _batcher._METRICS_ON:
-            _M_DECODE_STEPS.inc()
+        if _times is not None:
+            spent = _clock_ns() - t0
+            _times.dispatch += spent
+            _times.steps += 1
             if ahead:
-                _M_DECODE_AHEAD.inc()
+                _times.ahead += 1
+                if late:
+                    _times.late += 1
             if riding:
-                _M_PREFILL_RIDES.inc()
-            _M_DECODE_ROWS.inc(live)
-            _M_DECODE_DEAD_ROWS.inc(dead)
-            _M_DECODE_SLOTS.inc(bb)
-            _M_DECODE_PAGES.inc(pages)
-            _M_DECODE_PAGE_SLOTS.inc(bb * nbb)
-            _M_OCC_PCT.observe(100.0 * live / bb)
+                _times.rides += 1
+            _times.rows += live
+            _times.slots += bb
+            _times.pages += pages
+            _times.page_slots += bb * nbb
+            _times.occupancy.append(100.0 * live / bb)
         if sp is not None:
-            _tr.end(sp)
+            _tr.end(sp, args=None if _times is None else {
+                "dispatch_us": spent // 1000, "late": int(late)})
         # a rider's first token is read at the row it takes
         return _DecodeFlight(nxt, logits,
                              b if rider is None else max(b, row + 1), bb,
@@ -1329,7 +1439,9 @@ class GenerativeEngine:
         its logits, where it was dispatched with them): blocks until
         the device has them.  A span where ``_tr`` is given,
         ``serve.decode.wait``; ``_times`` gets the time up to here as
-        host time and the read-back as wait."""
+        host time, the read-back as wait and the step's interval
+        (``_LoopTimes.read_at``), which the span then gives as
+        ``interval_us``."""
         sp = (_tr.begin("serve.decode.wait")
               if _tr is not None else None)
         if _times is not None:
@@ -1339,15 +1451,19 @@ class GenerativeEngine:
         nxt = flight.read()
         logits = (None if flight.logits is None
                   else np.asarray(flight.logits)[:flight.b])
+        interval = None
         if _times is not None:
             now = _clock_ns()
             _times.wait += now - _times.mark
             _times.mark = now
+            interval = _times.read_at(now, flight.riding)
         # what the model counted in this step rides the same read
         counted = (self.config.note_decode(np.asarray(flight.stats),
                                            _batcher._METRICS_ON)
                    if flight.stats is not None else None)
         if sp is not None:
+            if interval is not None:
+                counted = dict(counted or (), interval_us=interval // 1000)
             _tr.end(sp, args=counted)
         return nxt if logits is None else (nxt, logits)
 
@@ -1409,9 +1525,9 @@ class GenerativeEngine:
         exactly the new context minus one when m < k — only a full
         accept leaves the draft one catch-up step behind.
 
-        ``_times`` (DecodeLoop's phase clock) gets the draft and verify
-        dispatches this round already meters as wait, the rest of the
-        round as host."""
+        ``_times`` (DecodeLoop's record) counts the round as a step of
+        all its rows, and gets the draft and verify dispatches this
+        round already meters as wait, the rest of the round as host."""
         k = self.spec_k
         d = self.draft
         t_draft = 0.0
@@ -1466,11 +1582,11 @@ class GenerativeEngine:
             _M_SPEC_ACCEPTED.inc(accepted)
             _M_SPEC_DRAFT_US.inc(int(t_draft * 1e6))
             _M_SPEC_VERIFY_US.inc(int(t_verify * 1e6))
-            _M_DECODE_STEPS.inc()
-            _M_DECODE_ROWS.inc(b)
-            _M_DECODE_SLOTS.inc(b)
-            _M_OCC_PCT.observe(100.0)
         if _times is not None:
+            _times.steps += 1
+            _times.rows += b
+            _times.slots += b
+            _times.occupancy.append(100.0)
             now = _clock_ns()
             busy = int((t_draft + t_verify) * 1e9)
             _times.wait += busy
@@ -1722,6 +1838,7 @@ class DecodeLoop:
         while True:
             if not running and not self._waiting:
                 self._times.flush()
+                self._times.last_read = 0   # the next read starts a run
                 with TRACER.span("serve.idle"):
                     req = self.queue.get(timeout=0.25)
                 if req is None:
@@ -1771,14 +1888,14 @@ class DecodeLoop:
         flight, self._flight = self._flight, None
         if flight is not None:
             step, rows = flight
-            nxt = (self._dispatch(running, trc, after=step)
+            nxt = (self._dispatch(running, trc, times, after=step)
                    if self._may_run_ahead(running, trc, times) else None)
             toks = self.engine.decode_read(step, _tr=trc, _times=times)
             # a row that finished under this step (an EOS the host
             # could not foresee) ran dead in it: its token is dropped
             live = set(running)
             self._emit_all([(seq, int(tok)) for seq, tok in zip(rows, toks)
-                            if seq in live], running, trc,
+                            if seq in live], running, trc, times,
                            advanced=set(nxt[1]) if nxt else ())
             if nxt is not None:
                 # with no row left the step ahead runs dead rows only:
@@ -1811,7 +1928,7 @@ class DecodeLoop:
                     times.mark = now
             self._place(req)
             self._joined(req, running)
-            self._emit_all(((req, tok),), running, trc)
+            self._emit_all(((req, tok),), running, trc, times)
         if not running:
             return
         # this iteration runs speculatively when every sequence has
@@ -1834,6 +1951,8 @@ class DecodeLoop:
         # speculative round emits up to k+1 tokens per sequence; a
         # plain step stays in flight for the next iteration
         if spec:
+            if times is not None:
+                times.last_read = 0     # a round is no step interval
             pairs = []
             for seq, toks in zip(list(running),
                                  self.engine.spec_decode(running, times)):
@@ -1842,11 +1961,11 @@ class DecodeLoop:
                 # intermediate context a plain-decode iteration would
                 seq.context_len -= len(toks)
                 pairs.extend((seq, int(tok)) for tok in toks)
-            self._emit_all(pairs, running, trc, replay=True)
+            self._emit_all(pairs, running, trc, times, replay=True)
             return
-        self._flight = self._dispatch(running, trc)
+        self._flight = self._dispatch(running, trc, times)
 
-    def _dispatch(self, running, trc, after=None):
+    def _dispatch(self, running, trc, times, after=None):
         """Dispatch the next step over the layout, on the device tokens
         of ``after``, the unread flight before it (None: the host's), the
         oldest waiting prompt riding it at the first dead index.  Returns
@@ -1858,7 +1977,8 @@ class DecodeLoop:
             self._place(rider)
             try:
                 step = self.engine.decode_start(
-                    self._rows(after), after=after, rider=rider, _tr=trc)
+                    self._rows(after), after=after, rider=rider, _tr=trc,
+                    _times=times)
             except Exception as e:
                 self._vacate(rider)
                 self._fail(rider, e)    # alone: the rows step without it
@@ -1868,7 +1988,7 @@ class DecodeLoop:
             if not any(self._layout):
                 return None
             step = self.engine.decode_start(self._rows(after), after=after,
-                                            _tr=trc)
+                                            _tr=trc, _times=times)
         return step, list(self._layout)
 
     def _rows(self, after):
@@ -1908,14 +2028,17 @@ class DecodeLoop:
         sp = trc.begin("serve.admit") if trc is not None else None
         admitted = self.scheduler.try_admit(
             self.queue, n_rows + len(self._waiting))
+        stamp = 0
         if admitted and times is not None:
-            now = time.perf_counter()
+            stamp = _clock_ns()
+            now = stamp / 1e9       # perf_counter's clock, in seconds
             _M_ADMISSIONS.inc(len(admitted))
             _M_QUEUE_WAIT_US.inc(int(1e6 * sum(
                 now - req.t_arrival for req in admitted)))
         joined = False
         for req in admitted:
             if not (req.blocks and req.context_len):
+                req.t_admitted = stamp      # its wait to ride starts
                 self._waiting.append(req)
                 continue
             # migrated-in: the prompt's KV pages are already resident
@@ -2036,7 +2159,8 @@ class DecodeLoop:
             trc.end(sp, args={"preempted": preempted})
         return grown
 
-    def _emit_all(self, pairs, running, trc, replay=False, advanced=()):
+    def _emit_all(self, pairs, running, trc, times, replay=False,
+                  advanced=()):
         """Emit ``(sequence, token)`` pairs under ONE ``serve.emit``
         span (not one a token).  The span's cid
         is the first finished request's id (``finished_ids`` has all),
@@ -2055,7 +2179,7 @@ class DecodeLoop:
                     continue    # finished mid-round; discard the rest
                 seq.context_len += 1
             tokens += 1
-            if self._emit(seq, tok, running, int(seq in advanced)):
+            if self._emit(seq, tok, running, times, int(seq in advanced)):
                 finished.append(seq.rid)
         if sp is not None:
             trc.end(sp, cid=finished[0] if finished else None,
@@ -2063,12 +2187,12 @@ class DecodeLoop:
                           "finished": len(finished),
                           "finished_ids": finished})
 
-    def _emit(self, seq, tok, running, ahead=0):
-        """Deliver one token; True when it finished the sequence.  The
-        done-checks see the context of the step that made the token:
-        ``ahead`` steps behind where the sequence stands."""
+    def _emit(self, seq, tok, running, times, ahead=0):
+        """Deliver one token, counted into ``times`` where metrics are
+        on; True when it finished the sequence.  The done-checks see the
+        context of the step that made the token: ``ahead`` steps behind
+        where the sequence stands."""
         now = time.perf_counter()
-        metrics_on = _batcher._METRICS_ON
         if seq.t_first is None:
             seq.t_first = now
             # serve_ttft_ms is observed at COMPLETION, not here: a
@@ -2079,12 +2203,12 @@ class DecodeLoop:
         else:
             itl = (now - seq.t_last) * 1e3
             seq.itl_ms.append(itl)
-            if metrics_on:
-                _M_ITL.observe(itl)
+            if times is not None:
+                times.itl.append(itl)
         seq.t_last = now
         seq.out.append(tok)
-        if metrics_on:
-            _M_TOKENS.inc()
+        if times is not None:
+            times.tokens += 1
         done = (len(seq.out) >= seq.max_new
                 or (seq.eos_id is not None and tok == seq.eos_id)
                 or seq.context_len - ahead >= self.engine.config.max_seq)
@@ -2093,8 +2217,7 @@ class DecodeLoop:
                 running.remove(seq)
             self._vacate(seq)
             self.engine.free_sequence(seq)
-            if metrics_on:
-                _M_GEN_MS.observe((now - seq.t_arrival) * 1e3)
+            if times is not None:
                 _M_TTFT.observe((seq.t_first - seq.t_arrival) * 1e3)
             seq.future.set_result(seq.result())
         return done

@@ -124,7 +124,7 @@ def _held(leaves, n_blocks, blocks, slot, n, block_size):
 
 def _counts():
     return {name: metrics.counter("serve_decode_%s_total" % name).value
-            for name in ("ahead", "steps", "rows", "dead_rows")}
+            for name in ("ahead", "steps", "rows")}
 
 
 def _since(c0):
@@ -469,8 +469,7 @@ def test_the_layout_runs_ahead_through_finishes_and_admissions(
     assert [after for _, _, after in steps] == [False] + [True] * (
         len(steps) - 1)
     assert d["rows"] == sum(lengths) - len(lengths)
-    assert d["dead_rows"] == sum(rows.count(None) for rows, _, _ in steps)
-    assert d["dead_rows"] > 0
+    assert any(None in rows for rows, _, _ in steps)
     index = {}
     for rows, rider, _ in steps:
         for i, rid in enumerate(rows):
