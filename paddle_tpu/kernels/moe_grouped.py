@@ -17,12 +17,15 @@ Mosaic call would copy the layer's experts: PR 26's lesson).
 
 On a TPU-placed computation (or under ``interpret``) the pairs are
 sorted by expert, each expert's tokens padded to whole row tiles, and a
-Pallas kernel walks the tiles: a tile's weights are the blocks
-``(layer, tile's expert, d_ff chunk)``, found through scalar prefetch,
-so an expert nobody chose is never read and consecutive tiles of one
-expert share a fetch; tiles past the last real one repeat its block
-index and skip their work.  One entry serves decode (a few rows: 16-row
-tiles) and prefill (128-row tiles): it adapts to the row count.
+Pallas kernel walks the tiles, ``d_ff`` chunk by chunk inside each: a
+tile's weights are the blocks ``(layer, tile's expert, d_ff chunk)``,
+found through scalar prefetch, so an expert nobody chose is never read;
+tiles past the last real one repeat its block index and skip their
+work.  Consecutive tiles of one expert share a fetch only where ``d_ff``
+is one chunk: with more, the next tile starts again at chunk 0, so an
+expert whose pairs span two tiles streams its weights twice.  One entry
+serves a decode step, a decode step that carries a prompt and a prefill:
+the row tile follows the pairs a held expert can expect (``row_tile``).
 Elsewhere the identical-math XLA path runs, one expert at a time.
 
 Activations enter each product in the weights' dtype (bfloat16) and
@@ -39,9 +42,37 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .dispatch import take_pallas
 
-__all__ = ["grouped_expert_ffn"]
+__all__ = ["grouped_expert_ffn", "row_tile"]
 
 _W_BLOCK_BYTES = 2 << 20       # one weight block in VMEM, at most
+
+# (row tile, the most pairs a held expert may expect for it): where a
+# v5e sweep at 128 held experts of 1024 x 2688, top-22 of 512, found the
+# next tile faster (between 2.75 and 5.5 pairs; between 13.75 and 22);
+# the last doubles the one before: the sweep reached 24.75 pairs
+_TILES = ((16, 4.0), (32, 16.0), (64, 32.0))
+_TILE_MAX = 128                # the MXU's own
+
+
+def row_tile(pairs, n_held, n_routed=None):
+    """The row tile for ``pairs`` token-expert pairs routed over
+    ``n_routed`` experts (default ``n_held``: every expert held here).
+
+    A held expert expects ``pairs / n_routed`` of them.  A tile much
+    larger than that is padding the MXU computes and the kernel writes
+    out; one smaller splits an expert over two tiles, which streams its
+    weights twice (the module's docstring); and every tile the worst
+    case allows, ``ceil(pairs / tm) + n_held``, is a grid step even when
+    it is empty, so a small tile also costs more empty steps.  Routing
+    concentrates (a few experts get several times the mean), so the
+    best tile is two to six times the expectation: the smallest a
+    bfloat16 operand fills, 16 rows, at a decode step's few pairs, 64
+    rows over a 512-token prompt."""
+    expect = pairs / float(n_routed or n_held)
+    for tm, most in _TILES:
+        if expect <= most:
+            return tm
+    return _TILE_MAX
 
 
 def _relu2(x):
@@ -184,13 +215,16 @@ def _xla(x, expert_ids, gates, w1, w2):
     return total
 
 
-def grouped_expert_ffn(x, expert_ids, gates, w1, w2, layer=0,
+def grouped_expert_ffn(x, expert_ids, gates, w1, w2, layer=0, n_routed=None,
                        force_xla=False, interpret=False):
     """``x`` [T, d_in] (the weights' dtype); ``expert_ids`` [T, K] int32,
     the held expert's local index or -1; ``gates`` [T, K] float32;
     ``w1`` [L, E, d_in, d_ff], ``w2`` [L, E, d_ff, d_out], ``layer`` a
-    static index.  Returns ``(r [T, d_out] float32, experts hit: how many
-    held experts got a token, int32 scalar)``."""
+    static index; ``n_routed`` how many experts the router chose among
+    (default E: every one is held here).  Returns ``(r [T, d_out]
+    float32, experts hit: how many held experts got a token, rows
+    computed: the kernel's real tiles x their rows, or E x T on the XLA
+    path)``, the two counts int32 scalars."""
     n_tok, k = expert_ids.shape
     n_l, n_e, d_in, d_ff = w1.shape
     assert w2.shape[:3] == (n_l, n_e, d_ff) and x.shape == (n_tok, d_in)
@@ -201,11 +235,9 @@ def grouped_expert_ffn(x, expert_ids, gates, w1, w2, layer=0,
     if not take_pallas("grouped_expert_ffn", True, force_xla, interpret):
         hit = jnp.sum(jnp.zeros(n_e + 1, jnp.int32).at[
             jnp.where(held, expert_ids, n_e)].max(1)[:n_e])
-        return _xla(x, jnp.where(held, expert_ids, -1), gates, w1[layer],
-                    w2[layer]), hit
-    # few pairs (a decode step): the smallest tile a bfloat16 operand
-    # fills; many (a prompt): the MXU's own
-    tm = 16 if n_tok * k <= 4096 else 128
+        return (_xla(x, jnp.where(held, expert_ids, -1), gates, w1[layer],
+                     w2[layer]), hit, jnp.int32(n_e * n_tok))
+    tm = row_tile(n_tok * k, n_e, n_routed)
     tile_expert, n_tiles, row_pair, pair_row, counts = _plan(
         expert_ids, n_e, tm)
     rows = jnp.where((row_pair >= 0)[:, None],
@@ -215,4 +247,4 @@ def grouped_expert_ffn(x, expert_ids, gates, w1, w2, layer=0,
     picked = y[jnp.maximum(pair_row, 0)].reshape(n_tok, k, -1)
     gates = jnp.where(held, gates, 0.0)
     return (jnp.sum(gates[..., None] * picked, axis=1),
-            jnp.sum(counts > 0).astype(jnp.int32))
+            jnp.sum(counts > 0).astype(jnp.int32), n_tiles[0] * tm)

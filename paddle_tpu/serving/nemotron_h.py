@@ -56,7 +56,9 @@ step's tokens and read with them, so that dispatch-ahead keeps running):
 ``serve_moe_pairs_total`` (token-expert pairs computed here),
 ``serve_moe_experts_hit_total`` (held experts that got a token, summed
 over the expert layers: what the grouped kernel read),
-``serve_moe_expert_slots_total`` (held experts x expert layers).
+``serve_moe_expert_slots_total`` (held experts x expert layers),
+``serve_moe_tile_rows_total`` (rows the grouped kernel computed in its
+real tiles, padding included: pairs / tile rows is the tiles' fill).
 """
 from __future__ import annotations
 
@@ -81,6 +83,11 @@ _M_SLOTS = _metrics.counter(
     "serve_moe_expert_slots_total",
     "held experts x expert layers, a decode step: hit / slots is the "
     "share of the held experts' weights a step streams")
+_M_TILE_ROWS = _metrics.counter(
+    "serve_moe_tile_rows_total",
+    "rows the grouped expert kernel computed in its real tiles, padding "
+    "included, summed over the expert layers of decode steps: pairs / "
+    "tile rows is the share of the computed rows that were pairs")
 
 _EPS = 1e-5
 _TABLE_STEP = 16       # block-table slots a decode step's gather grows by
@@ -183,14 +190,16 @@ class NemotronHConfig:
         return {k: jax.device_put(v, device) for k, v in params.items()}
 
     def note_decode(self, stats, metrics_on):
-        """Count what a decode step's ``stats`` ([pairs, experts hit])
-        say; returns the ``serve.decode.wait`` span's arguments."""
-        pairs, hit = int(stats[0]), int(stats[1])
+        """Count what a decode step's ``stats`` ([pairs, experts hit,
+        tile rows]) say; returns the ``serve.decode.wait`` span's
+        arguments."""
+        pairs, hit, rows = (int(v) for v in stats)
         if metrics_on:
             _M_PAIRS.inc(pairs)
             _M_HIT.inc(hit)
             _M_SLOTS.inc(len(self.experts_held) * self.pattern.count("E"))
-        return {"experts_hit": hit}
+            _M_TILE_ROWS.inc(rows)
+        return {"experts_hit": hit, "tile_rows": rows}
 
     # -- blocks ---------------------------------------------------------
 
@@ -310,7 +319,8 @@ class NemotronHConfig:
 
     def _moe(self, p, l, u):
         """The expert layer on rows u [T, d]: (out [T, d] float32, pairs
-        computed here, held experts hit)."""
+        computed here, held experts hit, rows the grouped kernel
+        computed)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -326,12 +336,12 @@ class NemotronHConfig:
         local = chosen - first
         held = (local >= 0) & (local < n_held)
         lat = _mm(u, p["E.down"][l]).astype(u.dtype)
-        r, hit = grouped_expert_ffn(
+        r, hit, rows = grouped_expert_ffn(
             lat, jnp.where(held, local, -1), gates, p["E.w1"], p["E.w2"],
-            layer=l)
+            layer=l, n_routed=self.n_experts)
         out = _mm(r, p["E.up"][l]) + _mm(_relu2(_mm(u, p["E.s1"][l])),
                                          p["E.s2"][l])
-        return out, jnp.sum(held).astype(jnp.int32), hit
+        return out, jnp.sum(held).astype(jnp.int32), hit, rows
 
     def _blocks(self, p, h, cache, mix, attend):
         """Every block in order on the residual stream ``h`` [T, d]
@@ -339,11 +349,11 @@ class NemotronHConfig:
         between them is the mode's own: ``mix(l, xbc, dt, cache)`` ->
         (y, x) between a Mamba layer's in_proj and out_proj, ``attend(l,
         q, k, v, cache)`` between ``wqkv`` and ``wo``.  Returns (h,
-        cache, [pairs, experts hit])."""
+        cache, [pairs, experts hit, tile rows])."""
         import jax
         import jax.numpy as jnp
 
-        pairs = hit = jnp.int32(0)
+        pairs = hit = rows = jnp.int32(0)
         for kind, l in zip(self.pattern, self.nth):
             u = _rms(h, p[kind + ".norm"][l]).astype(h.dtype)
             if kind == "M":
@@ -358,10 +368,10 @@ class NemotronHConfig:
                     out = _mm(att.reshape(att.shape[0], -1), p["*.wo"][l])
             else:
                 with jax.named_scope("layer"), jax.named_scope("moe"):
-                    out, n, e = self._moe(p, l, u)
-                    pairs, hit = pairs + n, hit + e
+                    out, n, e, t = self._moe(p, l, u)
+                    pairs, hit, rows = pairs + n, hit + e, rows + t
             h = (h.astype(jnp.float32) + out).astype(h.dtype)
-        return h, cache, jnp.stack([pairs, hit])
+        return h, cache, jnp.stack([pairs, hit, rows])
 
     def _write_kv(self, cache, l, blk, off, k, v):
         """Rows' K/V [T, kv, hd] into the pages at ``(blk, off)``, a

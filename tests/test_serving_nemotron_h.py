@@ -17,7 +17,9 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark.lib import reference_nemotron_h as ref      # noqa: E402
+from paddle_tpu.kernels import moe_grouped  # noqa: E402
 from paddle_tpu.kernels.moe_grouped import grouped_expert_ffn  # noqa: E402
+from paddle_tpu.kernels.moe_grouped import row_tile  # noqa: E402
 from paddle_tpu.kernels.ssm_update import ssm_state_update  # noqa: E402
 from paddle_tpu.observability import metrics               # noqa: E402
 from paddle_tpu.serving import (GenerativeEngine, InferenceServer,  # noqa: E402
@@ -184,7 +186,7 @@ def test_decode_counts_pairs_and_experts_hit(params):
     """The step's own counts ride its tokens back: pairs routed to held
     experts, held experts hit, and the slots they are a share of."""
     names = ("serve_moe_pairs_total", "serve_moe_experts_hit_total",
-             "serve_moe_expert_slots_total")
+             "serve_moe_expert_slots_total", "serve_moe_tile_rows_total")
     before = {k: metrics.snapshot()[k]["value"] for k in names}
     out, _, _ = _generate(params, JOBS[:2], kv_blocks=40)
     after = {k: metrics.snapshot()[k]["value"] - before[k] for k in names}
@@ -194,6 +196,10 @@ def test_decode_counts_pairs_and_experts_hit(params):
         "serve_moe_expert_slots_total"]
     assert after["serve_moe_experts_hit_total"] <= after[
         "serve_moe_pairs_total"] <= steps * 2 * 4 * SIZES["top_k"]
+    # the XLA path here computes every held expert over every row
+    assert after["serve_moe_pairs_total"] <= after[
+        "serve_moe_tile_rows_total"]
+    assert after["serve_moe_tile_rows_total"] % 4 == 0
 
 
 # ---------------------------------------------------------------------------
@@ -232,7 +238,7 @@ def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_layer(who):
         else:
             cfg = config(share)
             p = ref.stacked_params(SEED, share)
-            out, pairs, hit = cfg._moe(p, 0, u[0].astype(jnp.bfloat16))
+            out, pairs, hit, _ = cfg._moe(p, 0, u[0].astype(jnp.bfloat16))
             assert 0 < int(hit) <= 2 and int(hit) <= int(pairs)
             with jax.default_matmul_precision("highest"):
                 routed = out - (ref._relu2(u[0] @ lp["s1"]) @ lp["s2"])
@@ -296,31 +302,75 @@ def test_a_kv_only_model_takes_no_slot_and_no_slot_operand():
 # the two kernels, interpreted, against their XLA paths
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n_tok", [3, 64, 300])
-def test_grouped_expert_kernel_is_its_xla_path(n_tok):
+@pytest.mark.parametrize("n_tok,n_routed,d_ff,tm", [
+    (3, None, 48, 16),
+    (64, None, 48, 64),
+    (300, None, 48, 128),
+    # the rule's other tiles; routed over more experts than are held
+    (16, 12, 48, 16),
+    (40, 12, 48, 32),
+    # d_ff in two chunks, and one expert's pairs over three tiles: each
+    # tile fetches the expert's weights again from chunk 0
+    (96, 24, 256, 32),
+])
+def test_grouped_expert_kernel_is_its_xla_path(n_tok, n_routed, d_ff, tm,
+                                               monkeypatch):
     rng = np.random.default_rng(n_tok)
-    n_l, n_e, k, d_in, d_ff = 2, 6, 3, 32, 48
+    n_l, n_e, k, d_in = 2, 6, 3, 32
     x = jnp.asarray(rng.normal(size=(n_tok, d_in)), jnp.bfloat16)
     ids = rng.integers(-1, n_e + 2, size=(n_tok, k))
-    ids = jnp.asarray(np.where(ids >= n_e, -1, ids), jnp.int32)
+    ids = np.where(ids >= n_e, -1, ids)
+    if d_ff > 48:
+        # a weight block of one 128-lane chunk
+        monkeypatch.setattr(moe_grouped, "_W_BLOCK_BYTES", d_in * 128 * 2)
+        ids[:, 0] = 0
+    ids = jnp.asarray(ids, jnp.int32)
     gates = jnp.asarray(rng.uniform(size=(n_tok, k)), jnp.float32)
     w1 = jnp.asarray(rng.normal(size=(n_l, n_e, d_in, d_ff)) * 0.2,
                      jnp.bfloat16)
     w2 = jnp.asarray(rng.normal(size=(n_l, n_e, d_ff, d_in)) * 0.2,
                      jnp.bfloat16)
-    want, hit = grouped_expert_ffn(x, ids, gates, w1, w2, layer=1)
-    got, hit_k = grouped_expert_ffn(x, ids, gates, w1, w2, layer=1,
-                                    interpret=True)
+    assert row_tile(n_tok * k, n_e, n_routed) == tm
+    counts = np.bincount(np.asarray(ids).ravel() + 1, minlength=n_e + 1)[1:]
+    if d_ff > 48:
+        assert moe_grouped._ff_chunks(d_in, d_ff, 2) == 2
+        assert counts.max() > tm
+    want, hit, rows = grouped_expert_ffn(x, ids, gates, w1, w2, layer=1,
+                                         n_routed=n_routed)
+    got, hit_k, rows_k = grouped_expert_ffn(x, ids, gates, w1, w2, layer=1,
+                                            n_routed=n_routed,
+                                            interpret=True)
     assert int(hit) == int(hit_k) == len(set(np.asarray(ids).ravel()) - {-1})
+    # the XLA path computes every held expert over every row; the kernel
+    # its real tiles
+    assert int(rows) == n_e * n_tok
+    assert int(rows_k) == tm * int(np.sum(-(-counts // tm)))
     np.testing.assert_allclose(got, want, atol=1e-5 * float(
         jnp.abs(want).max()))
     # an expert nobody chose is never read: its weights may be anything
     dead = [e for e in range(n_e) if e not in np.asarray(ids)]
     if dead:
         poisoned = w1.at[1, dead[0]].set(jnp.nan)
-        again, _ = grouped_expert_ffn(x, ids, gates, poisoned, w2, layer=1,
-                                      interpret=True)
+        again, _, _ = grouped_expert_ffn(x, ids, gates, poisoned, w2,
+                                         layer=1, n_routed=n_routed,
+                                         interpret=True)
         np.testing.assert_array_equal(again, got)
+
+
+@pytest.mark.parametrize("rows,tm", [
+    (64, 16),          # a decode step: the program it always ran
+    (64 + 64, 32),     # a decode step carrying a prompt of each bucket
+    (64 + 128, 32),
+    (64 + 256, 32),
+    (64 + 512, 64),
+    (512, 64),         # a 512-token prompt into an empty batch
+    (2048, 128),
+])
+def test_row_tile_follows_the_pairs_a_held_expert_expects(rows, tm):
+    # the hybrid cell: top-22 of 512 routed experts, 128 held here
+    assert row_tile(rows * 22, 128, 512) == tm
+    # where every routed expert is held, n_routed defaults to them
+    assert row_tile(rows * 22, 512) == tm
 
 
 def test_state_update_kernel_is_its_xla_path_and_touches_its_slots_only():

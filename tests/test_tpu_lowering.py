@@ -284,15 +284,17 @@ def test_decode_step_holds_one_mosaic_call_a_layer(monkeypatch):
     assert dispatch.counts().get("paged_attention.pallas", 0) > 0
 
 
-@pytest.mark.parametrize("n_tok", [64, 512])
+@pytest.mark.parametrize("n_tok", [64, 512, 128, 192, 320, 576])
 def test_grouped_expert_matmul_lowers_at_the_hybrid_cells_widths(n_tok):
-    # nemotron3s-ep4-serve-reason-c64: 128 held experts of 1024 x 2688
-    # in 5 layers, top-22; a decode step's 64 rows and a prompt's 512
+    # nemotron3s-ep4-serve-reason-c64: 128 held of 512 routed experts of
+    # 1024 x 2688 in 5 layers, top-22; a decode step's 64 rows, a
+    # prompt's 512 and the decode steps that carry a prompt of 64 to 512
+    # rows: every tile the cell reaches
     from paddle_tpu.kernels.moe_grouped import grouped_expert_ffn
 
     assert mosaic_calls(
         lambda x, ids, g, w1, w2: grouped_expert_ffn(x, ids, g, w1, w2,
-                                                     layer=3),
+                                                     layer=3, n_routed=512),
         sds((n_tok, 1024), bf16), sds((n_tok, 22), i32),
         sds((n_tok, 22), f32), sds((5, 128, 1024, 2688), bf16),
         sds((5, 128, 2688, 1024), bf16)) == ["grouped_expert_ffn"]
