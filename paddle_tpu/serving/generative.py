@@ -219,6 +219,11 @@ _M_DISPATCH_US = _metrics.counter(
     "serve_dispatch_us_total",
     "micros inside the runtime call of the loop's decode steps: the "
     "engine's dispatch bracket, the enqueue included")
+_M_SCAN_TOKENS = _metrics.counter(
+    "serve_scan_tokens_total",
+    "prompt tokens a model with per-sequence state ran through its state "
+    "space into a slot (the real tokens of the prompts its decode steps "
+    "carried or its prefills ran, bucket padding not counted)")
 _M_RIDE_WAIT_US = _metrics.counter(
     "serve_ride_wait_us_total",
     "admission -> the return of the dispatch that carries the prompt, "
@@ -262,7 +267,7 @@ class _LoopTimes:
                ("pages", _M_DECODE_PAGES),
                ("page_slots", _M_DECODE_PAGE_SLOTS),
                ("reads", _M_STEP_READS), ("ride_reads", _M_RIDE_READS),
-               ("tokens", _M_TOKENS))
+               ("tokens", _M_TOKENS), ("scan_tokens", _M_SCAN_TOKENS))
     # samples of a histogram, a step's or a token's
     _SAMPLES = (("occupancy", _M_OCC_PCT), ("itl", _M_ITL))
     __slots__ = tuple(n for n, _ in _NS + _COUNTS + _SAMPLES) + (
@@ -1352,7 +1357,10 @@ class GenerativeEngine:
         first token comes back at index ``row``, a dead row of the
         decode half or the one after the rows.  The spans then say
         ``riding``, the prompt's bucket, and carry ``_cid``, its
-        request's id.
+        request's id; for a model with per-sequence state they say
+        ``scan_tokens``, the prompt's tokens, which are counted
+        (``serve_scan_tokens_total``) into ``_times`` or, without it, at
+        once.
 
         ``_times`` (a ``_LoopTimes``) counts the step: its rows, slots
         and pages, whether it went out ahead and, if so, whether the
@@ -1362,7 +1370,7 @@ class GenerativeEngine:
         sp = (_tr.begin("serve.decode.stage", _cid)
               if _tr is not None else None)
         b = len(blocks_list)
-        riding, carried = 0, ()
+        riding, carried, scanned = 0, (), 0
         if rider is None:
             key, exe, tables, lens, pages = self._stage_rows(
                 self._decode_logits if with_logits else self._decode,
@@ -1374,6 +1382,8 @@ class GenerativeEngine:
             key = (self.config.max_batch, self.nb_top)
             tables, lens, pages = self._pad_rows(key, blocks_list, lens_list)
             carried = (p_toks, np.int32(len(prompt)), p_ids, np.int32(row))
+            # a model with state scans the prompt into its slot
+            scanned = len(prompt) if self.stateful else 0
         bb, nbb = key
         live = int(np.count_nonzero(lens[:b]))
         dead = b - live - (rider is not None and row < b)
@@ -1398,6 +1408,8 @@ class GenerativeEngine:
             args = {"bucket": "%dx%d" % key}
             if riding:
                 args["riding"] = riding
+            if scanned:
+                args["scan_tokens"] = scanned
             _tr.end(sp, args=args)
             sp = _tr.begin("serve.decode", _cid,
                            dict(args, rows=live, dead=dead, pages=pages,
@@ -1421,11 +1433,14 @@ class GenerativeEngine:
                     _times.late += 1
             if riding:
                 _times.rides += 1
+            _times.scan_tokens += scanned
             _times.rows += live
             _times.slots += bb
             _times.pages += pages
             _times.page_slots += bb * nbb
             _times.occupancy.append(100.0 * live / bb)
+        elif scanned and _batcher._METRICS_ON:
+            _M_SCAN_TOKENS.inc(scanned)     # a prefill outside the loop
         if sp is not None:
             _tr.end(sp, args=None if _times is None else {
                 "dispatch_us": spent // 1000, "late": int(late)})

@@ -93,7 +93,7 @@ _EPS = 1e-5
 _TABLE_STEP = 16       # block-table slots a decode step's gather grows by
 
 
-def _rms(x, w, groups=1):
+def _rms(x, w, groups=1, eps=_EPS):
     """RMSNorm with float32 statistics; ``groups`` > 1 normalises each
     group of the last axis on its own.  Returns float32."""
     import jax.numpy as jnp
@@ -102,7 +102,7 @@ def _rms(x, w, groups=1):
     x = x.astype(jnp.float32)
     shape = x.shape
     x = x.reshape(shape[:-1] + (groups, shape[-1] // groups))
-    x = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + _EPS)
+    x = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
     return x.reshape(shape) * w
 
 
@@ -463,16 +463,10 @@ class NemotronHConfig:
         rows'."""
         import jax.numpy as jnp
 
-        from paddle_tpu.kernels.flash_attention import flash_attention
-
         cache = dict(cache)
-        r, s_len = toks.shape[0], prompt.shape[0]
-        at = jnp.arange(s_len, dtype=jnp.int32)
-        blk = jnp.concatenate([
-            tables[jnp.arange(r), pos // self.block_size],
-            jnp.where(at < length, block_ids[at // self.block_size], 0)])
-        off = jnp.concatenate([pos, at]) % self.block_size
-        rep = self.n_heads // self.n_kv_heads
+        r = toks.shape[0]
+        blk, off = self._ride_pages(tables, pos, length, block_ids,
+                                    prompt.shape[0])
 
         def mix(l, xbc, dt, cache):
             stepped = self._mamba_step(p, l, xbc[:r], dt[:r], cache, slots)
@@ -483,18 +477,44 @@ class NemotronHConfig:
 
         def attend(l, q, k, v, cache):
             k, v = self._write_kv(cache, l, blk, off, k, v)
-            # each K/V head serves its `rep` query heads
-            q4 = q[r:].astype(k.dtype).transpose(1, 0, 2)[None]
-            k4 = jnp.repeat(k[r:], rep, axis=1).transpose(1, 0, 2)[None]
-            v4 = jnp.repeat(v[r:], rep, axis=1).transpose(1, 0, 2)[None]
-            fresh = flash_attention(q4, k4, v4, causal=True)[0]
+            fresh = self._attend_fresh(q, k, v, r)
             return jnp.concatenate([
                 self._attend_pages(l, q[:r], cache, tables, pos),
-                fresh.transpose(1, 0, 2).reshape(s_len, -1)])
+                fresh.transpose(1, 0, 2).reshape(fresh.shape[1], -1)])
 
         return self._blocks(
             p, p["embed"][jnp.concatenate([toks, prompt])], cache,
             mix, attend)
+
+    def _ride_pages(self, tables, pos, length, block_ids, s_len):
+        """Where a carrying step writes K/V: ``(block, offset)`` of the
+        R rows (each at ``pos`` through its table) then of the S prompt
+        rows (position ``i`` in ``block_ids[i // bs]``, padding in the
+        scratch block)."""
+        import jax.numpy as jnp
+
+        r = tables.shape[0]
+        at = jnp.arange(s_len, dtype=jnp.int32)
+        blk = jnp.concatenate([
+            tables[jnp.arange(r), pos // self.block_size],
+            jnp.where(at < length, block_ids[at // self.block_size], 0)])
+        off = jnp.concatenate([pos, at]) % self.block_size
+        return blk, off
+
+    def _attend_fresh(self, q, k, v, r):
+        """A fresh prompt's causal attention over its own K/V (in the
+        pages' dtype), flash, each K/V head serving its query heads: the
+        prompt is rows ``r:`` of q [R + S, nh, hd] and k / v [R + S, kv,
+        hd]; returns [nh, S, hd]."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.kernels.flash_attention import flash_attention
+
+        rep = self.n_heads // self.n_kv_heads
+        q4 = q[r:].astype(k.dtype).transpose(1, 0, 2)[None]
+        k4 = jnp.repeat(k[r:], rep, axis=1).transpose(1, 0, 2)[None]
+        v4 = jnp.repeat(v[r:], rep, axis=1).transpose(1, 0, 2)[None]
+        return flash_attention(q4, k4, v4, causal=True)[0]
 
     def head(self, p, h, n_live=None):
         """Final norm and the logit layer: hidden [R, d] to float32

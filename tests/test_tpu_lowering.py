@@ -364,6 +364,157 @@ def test_hybrid_steps_hand_their_kernels_the_whole_stacks(compile_step, key,
     assert dispatch.counts().get("grouped_expert_ffn.pallas", 0) > 0
 
 
+def test_mamba1_state_update_lowers_at_the_jamba_cells_widths():
+    # jamba2-3b-serve-reason-c128: 129 slots of a [16, 5120] state and a
+    # conv window of 3 x 5120 folded to [24, 640], 26 Mamba layers, 128
+    # rows
+    from paddle_tpu.kernels.ssm_update import mamba1_state_update
+
+    assert mosaic_calls(
+        lambda st, cv, sl, x, dt, a, b, c, w: mamba1_state_update(
+            st, cv, sl, x, dt, a, b, c, w, layer=25),
+        sds((26, 129, 16, 5120), f32), sds((26, 129, 24, 640), f32),
+        sds((128,), i32), sds((128, 5120), f32), sds((128, 5120), f32),
+        sds((16, 5120), f32), sds((128, 16), f32), sds((128, 16), f32),
+        sds((128, 24, 640), f32)) == ["mamba1_state_update"]
+
+
+@pytest.mark.parametrize("s_len", [64, 1024, 3072])
+def test_selective_scan_lowers_at_the_jamba_cells_widths(s_len):
+    # the cell's prompt buckets: the least, the longest prompt's and the
+    # longest sequence's
+    from paddle_tpu.kernels.selective_scan import selective_scan
+
+    assert mosaic_calls(
+        lambda st, slot, n, x, dt, a, b, c: selective_scan(
+            st, slot, n, x, dt, a, b, c, layer=3),
+        sds((26, 129, 16, 5120), f32), sds((), i32), sds((), i32),
+        sds((s_len, 5120), f32), sds((s_len, 5120), f32),
+        sds((16, 5120), f32), sds((s_len, 16), f32),
+        sds((s_len, 16), f32)) == ["selective_scan"]
+
+
+@pytest.mark.parametrize("compile_step,key", [("_compile_decode", (4, 4)),
+                                              ("_compile_ride", (16,))])
+def test_jamba_steps_hand_their_kernels_the_whole_state(compile_step, key,
+                                                        monkeypatch):
+    """The served Jamba's two steps lowered for a TPU: a run of Mamba
+    layers is a loop over the layers, whose body holds one decode state
+    update (and, in the step that carries a prompt, one prompt scan), so
+    one call a Mamba layer when it runs (tests/test_serving_jamba.py
+    sees every layer's state move); each on the whole stacked state
+    (the update on the conv windows too) with the layer a scalar:
+    nothing in the module has one layer's state or windows as its
+    shape.  The riding step adds one flash call an attention
+    layer, the prompt's."""
+    import sys
+
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmark.lib import reference_jamba
+    from paddle_tpu.serving import GenerativeEngine
+    from paddle_tpu.serving.jamba import JambaConfig
+
+    cfg = JambaConfig(vocab=64, hidden=256, n_layers=5, attn_offset=2,
+                      attn_period=4, n_heads=2, n_kv_heads=1, d_inner=256,
+                      state=16, dt_rank=16, conv_kernel=4, ff=256, eps=1e-6,
+                      block_size=8, max_blocks=4, max_batch=4)
+    params = reference_jamba.stacked_params(3, dict(
+        vocab=64, hidden=256, pattern=cfg.pattern, n_heads=2, n_kv_heads=1,
+        d_inner=256, state=16, dt_rank=16, conv_kernel=4, ff=256, eps=1e-6,
+        time_step_min=0.001, time_step_max=0.1, time_step_floor=1e-4))
+    eng = GenerativeEngine(cfg, params, kv_blocks=24, warm=False,
+                           prefix_cache=False, spec_k=0)
+    try:
+        monkeypatch.setattr(
+            eng, "_aot", lambda name, step, *specs: (eng._flat(step), specs))
+        step, specs = getattr(eng, compile_step)(key)
+        pspec, cspec = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), (eng._params, eng._cache))
+        text = tpu_module(step, pspec, cspec,
+                          *[sds(shape, dtype) for shape, dtype in specs])
+    finally:
+        eng.close()
+    runs = sum(kind == "M" for kind, _, _ in cfg.runs)
+    assert runs == 2 and cfg.pattern == "MM*MM"
+    names = re.findall(r'@tpu_custom_call\(.*?kernel_name = "(\w+)"', text)
+    # the runs' loop bodies are one function where they trace alike
+    assert 1 <= names.count("mamba1_state_update") <= runs
+    riding = compile_step == "_compile_ride"
+    assert (names.count("selective_scan") > 0) == riding
+    assert names.count("selective_scan") <= runs
+    assert names.count("flash_fwd") == (1 if riding else 0)
+    assert text.count("stablehlo.while") >= runs
+    for ln in text.splitlines():
+        if "@tpu_custom_call" in ln and ("mamba1_state_update" in ln
+                                         or "selective_scan" in ln):
+            assert "tensor<4x5x16x256xf32>" in ln
+        if "@tpu_custom_call" in ln and "mamba1_state_update" in ln:
+            assert "tensor<4x5x24x32xf32>" in ln
+    assert not re.search(r"tensor<(1x)?5x16x256xf32>", text)
+    assert not re.search(r"tensor<(1x)?5x24x32xf32>", text)
+    counts = dispatch.counts()
+    assert counts.get("mamba1_state_update.pallas", 0) > 0
+    if riding:
+        assert counts.get("selective_scan.pallas", 0) > 0
+
+
+# sha256 of stripped_module() of the Mamba-2 state update at the hybrid
+# cell's widths and of the served hybrid's two steps (the shapes of
+# test_hybrid_steps_hand_their_kernels_the_whole_stacks), taken before
+# the Mamba-1 kernels joined that file and the attention helpers of
+# serving/nemotron_h.py were shared: they lower as they did
+NEMOTRON_STEPS = {
+    "ssm_state_update": "92805f6ca5942a967f3e6d928a6317b5"
+                        "3fe003b6c74721c2a761610056e5f14d",
+    "_compile_decode": "4344c9f0e6c110985d102906439485fd"
+                       "f6a654890c92960efce5d33d53e49d85",
+    "_compile_ride": "fce5dd8d7ff93eb9466f8725f57277e1"
+                     "5dcc643c8cc8306239e9268856648e39",
+}
+
+
+@pytest.mark.parametrize("what", sorted(NEMOTRON_STEPS))
+def test_the_mamba2_kernel_and_the_hybrids_steps_lower_as_before(
+        what, monkeypatch):
+    import hashlib
+
+    from paddle_tpu.kernels.ssm_update import ssm_state_update
+    from paddle_tpu.serving import GenerativeEngine
+    from paddle_tpu.serving.nemotron_h import tiny_nemotron_h
+
+    if what == "ssm_state_update":
+        text = tpu_module(
+            lambda st, sl, x, dt, a, b, c: ssm_state_update(
+                st, sl, x, dt, a, b, c, layer=4),
+            sds((5, 65, 128, 64, 128), f32), sds((64,), i32),
+            sds((64, 128, 64), f32), sds((64, 128), f32), sds((128,), f32),
+            sds((64, 8, 128), f32), sds((64, 8, 128), f32))
+    else:
+        cfg, params = tiny_nemotron_h(
+            3, pattern="MEM*E", vocab=64, hidden=256, n_heads=2,
+            n_kv_heads=1, head_dim=128, mamba_heads=4, mamba_head_dim=64,
+            n_groups=2, state=128, latent=128, expert_ff=256,
+            shared_ff=256, n_experts=8, experts_held=(4, 7), top_k=3,
+            chunk=8, block_size=8, max_blocks=4, max_batch=4)
+        eng = GenerativeEngine(cfg, params, kv_blocks=24, warm=False,
+                               prefix_cache=False, spec_k=0)
+        try:
+            monkeypatch.setattr(
+                eng, "_aot",
+                lambda name, step, *specs: (eng._flat(step), specs))
+            step, specs = getattr(eng, what)(
+                (4, 4) if what == "_compile_decode" else (16,))
+            pspec, cspec = jax.tree_util.tree_map(
+                lambda a: sds(a.shape, a.dtype), (eng._params, eng._cache))
+            text = tpu_module(step, pspec, cspec,
+                              *[sds(shape, dtype) for shape, dtype in specs])
+        finally:
+            eng.close()
+    assert hashlib.sha256(
+        stripped_module(text).encode()).hexdigest() == NEMOTRON_STEPS[what]
+
+
 def test_matmul_epilogue_and_add_ln_lower():
     x, w = sds((32768, 1024), bf16), sds((1024, 4096), bf16)
     bias = sds((4096,), f32)
