@@ -10,16 +10,23 @@ For row ``r`` with state slot ``s = slots[r]``, head ``h`` of group
     S[s, h] <- exp(dt[r, h] A[h]) S[s, h] + dt[r, h] x[r, h] (x) B[r, g]
     y[r, h]  = S[s, h] C[r, g]
 
-``state`` is every Mamba layer's state for every slot, ``[L, slots, H,
-P, N]`` float32, handed over WHOLE with ``layer`` the index of the layer
-that runs, and comes back as the same buffer (``input_output_aliases``:
-the engine donates its cache tree through every step, so nothing is
-copied).  On a TPU-placed computation (or under ``interpret``) a Pallas
-kernel walks the rows: row ``r``'s block is ``(layer, slots[r])``, found
-through scalar prefetch, so a step reads and writes exactly the state of
-the slots its rows hold (padding rows aim at slot 0, the scratch slot)
-and never gathers it.  All arithmetic is float32 on the vector unit.
-Elsewhere the identical-math XLA gather / scatter runs.
+``state`` is every Mamba layer's state for every slot, ``[L, slots, N,
+H P]`` float32: the N state indices on the sublanes and the head
+channels (head ``h``'s P at ``h P .. h P + P - 1``) on the lanes, so a
+group's heads are ``H P / G`` contiguous lanes and a slot's state is
+whole (8, 128) tiles.  It is handed over WHOLE with ``layer`` the index
+of the layer that runs, and comes back as the same buffer
+(``input_output_aliases``: the engine donates its cache tree through
+every step, so nothing is copied).  On a TPU-placed computation (or
+under ``interpret``) a Pallas kernel walks the rows: row ``r``'s block
+is ``(layer, slots[r])``, found through scalar prefetch, so a step reads
+and writes exactly the state of the slots its rows hold (padding rows
+aim at slot 0, the scratch slot) and never gathers it.  In that layout
+the decay and ``dt x`` are rows of the lanes, ``B`` and ``C`` a column
+a group, and ``y`` a sum over the sublanes: no step of the update moves
+data across lanes but the one broadcast of each group's columns.  All
+arithmetic is float32 on the vector unit.  Elsewhere the identical-math
+XLA gather / scatter runs.
 
 The ``D x`` skip term, ``softplus`` and the gate are the model's
 (serving/nemotron_h.py): this is the state's own arithmetic.
@@ -38,62 +45,54 @@ from .dispatch import take_pallas
 __all__ = ["ssm_state_update", "mamba1_state_update"]
 
 
-def _kernel(layer_ref, slots_ref, decay_ref, xt_ref, b_ref, c_ref, s_ref,
-            y_ref, o_ref, *, per):
-    """One row: ``s_ref`` / ``o_ref`` [H, P, N] (the same HBM block),
-    ``decay_ref`` [R, H] in SMEM (every row's), ``xt_ref`` [G, P, per] (dt x, heads of
-    a group along the lanes), ``b_ref`` / ``c_ref`` [G, N]; ``y_ref``
-    [G, P, per]."""
-    n_groups = xt_ref.shape[0]
-    row = pl.program_id(0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, xt_ref.shape[1:], 1)
-
-    def group(g, carry):
-        xg = xt_ref[g]                                   # [P, per]
-        brow = b_ref[pl.ds(g, 1), :]                     # [1, N]
-        crow = c_ref[pl.ds(g, 1), :]
-        yg = jnp.zeros_like(xg)
-        for j in range(per):
-            h = g * per + j
-            new = s_ref[h] * decay_ref[row, h] + xg[:, j:j + 1] * brow
-            o_ref[h] = new
-            col = jnp.sum(new * crow, axis=1, keepdims=True)     # [P, 1]
-            yg = jnp.where(lane == j, col, yg)
-        y_ref[g] = yg
-        return carry
-
-    jax.lax.fori_loop(0, n_groups, group, 0)
+def _kernel(layer_ref, slots_ref, decay_ref, dtx_ref, b_ref, c_ref, s_ref,
+            y_ref, o_ref):
+    """One row: ``s_ref`` / ``o_ref`` [N, H P] (the same HBM block),
+    ``decay_ref`` / ``dtx_ref`` / ``y_ref`` [1, H P] (a head's decay
+    repeated over its P channels; dt x), ``b_ref`` / ``c_ref`` [N, G]
+    (a group's B and C a column).  A group's lanes are walked a lane
+    tile at a time."""
+    n, hp = s_ref.shape
+    groups = b_ref.shape[1]
+    width = hp // groups
+    tile = 128 if width % 128 == 0 else width
+    for g in range(groups):
+        bb = jnp.broadcast_to(b_ref[:, g:g + 1], (n, tile))
+        cc = jnp.broadcast_to(c_ref[:, g:g + 1], (n, tile))
+        for t in range(g * width, (g + 1) * width, tile):
+            new = (s_ref[:, t:t + tile] * decay_ref[:, t:t + tile]
+                   + bb * dtx_ref[:, t:t + tile])
+            o_ref[:, t:t + tile] = new
+            y_ref[:, t:t + tile] = jnp.sum(new * cc, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=("interpret",))
-def _update_call(layer, slots, decay, xt, b, c, state, *, interpret):
-    """The kernel's call; ``layer`` [1] and ``slots`` [R] ride scalar
-    prefetch.  Under an inline jit with the layer an operand: a step's
-    Mamba layers share one trace and one Mosaic compile."""
-    r, g, p, per = xt.shape
-    _, _, h, _, n = state.shape
-    row = lambda i, l, s: (i, 0, 0, 0)
+def _update_call(layer, slots, decay, dtx, b, c, state, *, interpret):
+    """The kernel's call: ``decay`` / ``dtx`` [R, 1, H P], ``b`` / ``c``
+    [R, N, G]; ``layer`` [1] and ``slots`` [R] ride scalar prefetch.
+    Under an inline jit with the layer an operand: a step's Mamba layers
+    share one trace and one Mosaic compile."""
+    r, _, hp = dtx.shape
+    n, g = b.shape[1:]
+    row = lambda i, l, s: (i, 0, 0)
+    slab = pl.BlockSpec((None, None, n, hp),
+                        lambda i, l, s: (l[0], s[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(r,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),     # decay, whole
-            pl.BlockSpec((None, g, p, per), row),
-            pl.BlockSpec((None, g, n), lambda i, l, s: (i, 0, 0)),
-            pl.BlockSpec((None, g, n), lambda i, l, s: (i, 0, 0)),
-            pl.BlockSpec((None, None, h, p, n),
-                         lambda i, l, s: (l[0], s[i], 0, 0, 0)),
+            pl.BlockSpec((None, 1, hp), row),
+            pl.BlockSpec((None, 1, hp), row),
+            pl.BlockSpec((None, n, g), row),
+            pl.BlockSpec((None, n, g), row),
+            slab,
         ],
-        out_specs=[
-            pl.BlockSpec((None, g, p, per), row),
-            pl.BlockSpec((None, None, h, p, n),
-                         lambda i, l, s: (l[0], s[i], 0, 0, 0)),
-        ],
+        out_specs=[pl.BlockSpec((None, 1, hp), row), slab],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, per=per),
+        _kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(xt.shape, jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct(dtx.shape, jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # operand 6 (the two prefetched scalars count) is the state
         input_output_aliases={6: 1},
@@ -103,35 +102,37 @@ def _update_call(layer, slots, decay, xt, b, c, state, *, interpret):
         interpret=interpret,
         name="ssm_state_update",
         metadata={"kernel": "ssm_state_update"},
-    )(layer, slots, decay, xt, b, c, state)
+    )(layer, slots, decay, dtx, b, c, state)
 
 
 def ssm_state_update(state, slots, x, dt, a, b, c, layer=0,
                      force_xla=False, interpret=False):
-    """``state`` [L, slots, H, P, N] float32; ``slots`` [R] int32; ``x``
+    """``state`` [L, slots, N, H P] float32; ``slots`` [R] int32; ``x``
     [R, H, P], ``dt`` [R, H], ``a`` [H] (negative), ``b`` / ``c`` [R, G,
     N], all float32; ``layer`` a static index.  Returns ``(y [R, H, P],
     the updated state)``."""
-    n_l, n_slots, h, p, n = state.shape
-    r, g, _ = b.shape
-    per = h // g
-    assert x.shape == (r, h, p) and dt.shape == (r, h) and h % g == 0
+    n_l, _, n, hp = state.shape
+    r, h, p = x.shape
+    g = b.shape[1]
+    assert hp == h * p and dt.shape == (r, h) and h % g == 0
+    assert b.shape == c.shape == (r, g, n), (b.shape, c.shape, state.shape)
     assert 0 <= layer < n_l, (layer, state.shape)
     slots = slots.astype(jnp.int32)
-    decay = jnp.exp(dt * a)                                  # [R, H]
-    dtx = dt[..., None] * x                                  # [R, H, P]
+    # a head's decay repeated over its P lanes, and dt x, as lane rows
+    decay = jnp.repeat(jnp.exp(dt * a), p, axis=1)           # [R, H P]
+    dtx = (dt[..., None] * x).reshape(r, hp)                 # [R, H P]
+    bt, ct = b.transpose(0, 2, 1), c.transpose(0, 2, 1)      # [R, N, G]
     if not take_pallas("ssm_state_update", True, force_xla, interpret):
-        b_h, c_h = jnp.repeat(b, per, axis=1), jnp.repeat(c, per, axis=1)
-        new = (state[layer, slots] * decay[..., None, None]
-               + dtx[..., None] * b_h[:, :, None, :])
-        y = jnp.sum(new * c_h[:, :, None, :], axis=-1)
-        return y, state.at[layer, slots].set(new)
-    # heads of a group along the lanes, the head's P along the sublanes:
-    # a head's column of dt x is then a static lane of its group's tile
-    xt = dtx.reshape(r, g, per, p).transpose(0, 1, 3, 2)
-    yt, state = _update_call(jnp.full((1,), layer, jnp.int32), slots, decay,
-                             xt, b, c, state, interpret=interpret)
-    return yt.transpose(0, 1, 3, 2).reshape(r, h, p), state
+        new = (state[layer, slots].reshape(r, n, g, -1)
+               * decay.reshape(r, 1, g, -1)
+               + bt[..., None] * dtx.reshape(r, 1, g, -1))
+        y = jnp.sum(new * ct[..., None], axis=1)             # [R, G, H P / G]
+        return (y.reshape(r, h, p),
+                state.at[layer, slots].set(new.reshape(r, n, hp)))
+    y, state = _update_call(jnp.full((1,), layer, jnp.int32), slots,
+                            decay[:, None], dtx[:, None], bt, ct, state,
+                            interpret=interpret)
+    return y.reshape(r, h, p), state
 
 
 # ---------------------------------------------------------------------------

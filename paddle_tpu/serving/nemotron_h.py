@@ -10,7 +10,9 @@ cache as a tree (``cache_spec``) and says it keeps per-sequence state
 - ``conv`` [Lm, slots, (K-1) * conv_dim] float32: a sequence's last K-1
   rows of ``xBC`` (the causal conv's window) end to end, per Mamba
   layer;
-- ``ssm`` [Lm, slots, H, P, N] float32: its SSM state, per Mamba layer;
+- ``ssm`` [Lm, slots, N, H * P] float32: its SSM state, per Mamba
+  layer, the state index on the sublanes and the head channels on the
+  lanes (kernels/ssm_update.py);
 - ``k`` / ``v`` [La, blocks, block_size, kv_heads * head_dim] bfloat16:
   paged K/V of the attention layers, a position's heads side by side in
   one row.
@@ -176,8 +178,8 @@ class NemotronHConfig:
         return {"conv": sds((n_m, self.state_slots,
                              (self.conv_kernel - 1) * self.conv_dim),
                             jnp.float32),
-                "ssm": sds((n_m, self.state_slots, self.mamba_heads,
-                            self.mamba_head_dim, self.state), jnp.float32),
+                "ssm": sds((n_m, self.state_slots, self.state,
+                            self.d_inner), jnp.float32),
                 "k": page, "v": page}
 
     def stage(self, params, quant, device):
@@ -306,7 +308,9 @@ class NemotronHConfig:
         y = y + jnp.exp(cum)[..., None] * jnp.einsum(
             "cihn,chpn->cihp", jnp.repeat(cc, per, axis=2), before,
             precision=hi)
-        cache["ssm"] = cache["ssm"].at[l, slot].set(last)
+        # [H, P, N] into the slot's layout: N on the sublanes, H P lanes
+        cache["ssm"] = cache["ssm"].at[l, slot].set(
+            last.transpose(2, 0, 1).reshape(self.state, self.d_inner))
         return y.reshape(s_len, h, self.mamba_head_dim), x
 
     def _qkv(self, p, l, u):

@@ -373,10 +373,17 @@ def test_row_tile_follows_the_pairs_a_held_expert_expects(rows, tm):
     assert row_tile(rows * 22, 512) == tm
 
 
-def test_state_update_kernel_is_its_xla_path_and_touches_its_slots_only():
+@pytest.mark.parametrize("h,p,n,g", [
+    (8, 8, 16, 2),      # a group's 32 lanes
+    (8, 32, 16, 2),     # a group a whole lane tile (per * P = 128)
+    (8, 64, 8, 2),      # a group two lane tiles
+    (4, 8, 16, 2),      # tiny_nemotron_h's widths
+])
+def test_state_update_kernel_is_its_xla_path_and_touches_its_slots_only(
+        h, p, n, g):
     rng = np.random.default_rng(5)
-    n_l, n_s, h, p, n, g, r = 2, 6, 8, 8, 16, 2, 4
-    state = jnp.asarray(rng.normal(size=(n_l, n_s, h, p, n)), jnp.float32)
+    n_l, n_s, r = 2, 6, 4
+    state = jnp.asarray(rng.normal(size=(n_l, n_s, n, h * p)), jnp.float32)
     slots = jnp.asarray([3, 1, 0, 4], jnp.int32)
     x = jnp.asarray(rng.normal(size=(r, h, p)), jnp.float32)
     dt = jnp.asarray(rng.uniform(0.01, 0.2, size=(r, h)), jnp.float32)
@@ -392,16 +399,45 @@ def test_state_update_kernel_is_its_xla_path_and_touches_its_slots_only():
     untouched[1, [3, 1, 0, 4]] = False
     np.testing.assert_array_equal(np.asarray(new_k)[untouched],
                                   np.asarray(state)[untouched])
-    # by hand, one row: S <- exp(dt A) S + dt x (x) B; y = S C
-    s0 = np.asarray(state[1, 3], np.float64)
+    # by hand, one row: S <- exp(dt A) S + dt x (x) B; y = S C, with a
+    # slot's [N, H P] read as [H, P, N]
+    s0 = np.asarray(state[1, 3], np.float64).reshape(n, h, p).transpose(
+        1, 2, 0)
     bh = np.repeat(np.asarray(b[0], np.float64), h // g, axis=0)
     ch = np.repeat(np.asarray(c[0], np.float64), h // g, axis=0)
     s1 = (np.exp(np.asarray(dt[0] * a, np.float64))[:, None, None] * s0
           + (np.asarray(dt[0], np.float64)[:, None] * np.asarray(x[0]))[
               ..., None] * bh[:, None, :])
-    np.testing.assert_allclose(new[1, 3], s1, atol=1e-5)
+    np.testing.assert_allclose(new[1, 3], s1.transpose(2, 0, 1).reshape(
+        n, h * p), atol=1e-5)
     np.testing.assert_allclose(y[0], np.einsum("hpn,hn->hp", s1, ch),
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("length", [7, 11])
+def test_a_scanned_prompt_and_a_decoded_token_leave_the_scan_of_both(length):
+    """The two Mamba-2 forms agree through the slot: a prompt of
+    ``length`` scanned, then its next token through the decode step,
+    leave the slot's window and state that the prompt of ``length + 1``
+    scanned whole leaves (a transposition between the scan's state and
+    the step's layout would not)."""
+    cfg, p = tiny_nemotron_h(3)
+    rng = np.random.default_rng(length)
+    s_len, l, slot = 16, 1, 2
+    xbc = jnp.asarray(rng.normal(size=(s_len, cfg.conv_dim)), jnp.float32)
+    dt = jnp.asarray(rng.normal(size=(s_len, cfg.mamba_heads)), jnp.float32)
+    zero = {k: jnp.zeros(v.shape, v.dtype)
+            for k, v in cfg.cache_spec(4).items() if k in ("conv", "ssm")}
+    stepped = dict(zero)
+    cfg._mamba_scan(p, l, xbc, dt, stepped, length, slot)
+    cfg._mamba_step(p, l, xbc[length:length + 1], dt[length:length + 1],
+                    stepped, jnp.asarray([slot], jnp.int32))
+    whole = dict(zero)
+    cfg._mamba_scan(p, l, xbc, dt, whole, length + 1, slot)
+    assert np.abs(np.asarray(whole["ssm"][l, slot])).max() > 1e-2
+    for k in ("conv", "ssm"):
+        np.testing.assert_allclose(stepped[k], whole[k], atol=1e-5,
+                                   err_msg=k)
 
 
 def test_tiny_family_serves_end_to_end():
